@@ -190,9 +190,9 @@ def grow_tree(
         use_host_hist,
     )
 
-    hm = hist_lowering()
+    hm = hist_lowering(mesh)
     if (
-        use_host_hist()
+        use_host_hist(mesh)
         and not partitioned
         and not _rows_sharded(mesh, shard_axis)
     ):
@@ -273,8 +273,8 @@ def _grow_tree(
         return threshold_l1(Gv, l1)
 
     # per-row (g, h, count) stats; the histogram op picks its lowering
-    # (Pallas one-hot matmul on single-chip TPU, GSPMD-partitioned scatter
-    # under sharded meshes / CPU) — see ops/histogram.py
+    # (Pallas one-hot matmul on TPU — per shard + psum under a mesh —
+    # host bincount / scatter on CPU) — see ops/histogram.py
     from mmlspark_tpu.ops.histogram import plane_histogram
 
     row_stats = jnp.stack([g, h, cnt_w], axis=-1)  # (n, 3)
@@ -721,23 +721,25 @@ def grow_tree_depthwise(
     # tiny dependent ops per split dominates wall clock there) but costs
     # ~30% on CPU (no dispatch-latency problem; full-width scatters per
     # level instead). Default by backend, env-overridable.
+    from mmlspark_tpu.ops.histogram import (
+        _rows_sharded,
+        _target_device,
+        hist_lowering,
+        use_host_hist,
+    )
+
     env_vec = os.environ.get("MMLSPARK_TPU_GBDT_VECTOR_SPLIT")
     if env_vec is not None:
         vector = env_vec not in ("0", "false", "")
     else:
-        try:
-            vector = jax.default_backend() == "tpu"
-        except Exception:
-            vector = False
+        vector = _target_device(mesh).platform == "tpu"
     # CPU lowering: the whole tree grows behind ONE host callback (numpy
     # split scan + pooled bincount histograms) — a per-level histogram
     # callback alone leaves ~9 ms/tree of XLA:CPU glue plus ~1 ms of
     # bridge cost per crossing, which is the difference between losing
     # and beating sklearn's OpenMP grower at bench shapes. TPU and
     # sharded meshes keep the XLA grower below.
-    from mmlspark_tpu.ops.histogram import _rows_sharded, use_host_hist
-
-    if use_host_hist() and not _rows_sharded(mesh, shard_axis):
+    if use_host_hist(mesh) and not _rows_sharded(mesh, shard_axis):
         return _grow_tree_depthwise_hostcall(
             bins, grad, hess, row_weight,
             num_leaves=L, n_levels=n_levels, num_bins=num_bins,
@@ -747,8 +749,6 @@ def grow_tree_depthwise(
             feature_mask=feature_mask, categorical_mask=categorical_mask,
             has_categorical=has_categorical, sibling_subtract=sibling,
         )
-    from mmlspark_tpu.ops.histogram import hist_lowering
-
     return _grow_tree_depthwise(
         bins, grad, hess, row_weight,
         num_leaves=L, lambda_l2=lambda_l2, min_gain=min_gain,
@@ -758,7 +758,7 @@ def grow_tree_depthwise(
         lambda_l1=lambda_l1, min_sum_hessian=min_sum_hessian,
         num_bins=num_bins, mesh=mesh, shard_axis=shard_axis,
         sibling_subtract=sibling, vector_split=vector,
-        hist_mode=hist_lowering(),
+        hist_mode=hist_lowering(mesh),
     )
 
 

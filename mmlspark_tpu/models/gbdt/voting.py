@@ -41,7 +41,6 @@ from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.models.gbdt.treegrow import GrownTree, split_gain_term, threshold_l1
 from mmlspark_tpu.ops.histogram import NUM_BINS, plane_histogram
-from mmlspark_tpu.parallel.compat import shard_map
 from mmlspark_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -117,8 +116,8 @@ def _voting_program(
         row_stats = jnp.stack([g, h, row_weight], axis=-1)
 
         def plane_hist(mask):
-            # LOCAL histogram plane — stays on the shard (scatter lowering;
-            # single-shard shapes, no GSPMD collectives inside shard_map;
+            # LOCAL histogram plane — stays on the shard: a one-device
+            # call inside shard_map (the kernel on TPU, scatter on CPU;
             # allow_host=False: a host callback per shard would serialize
             # the shards on the GIL)
             return plane_histogram(
@@ -352,7 +351,7 @@ def _voting_program(
 
     row = P(axis)
     rep = P()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         program,
         mesh=mesh,
         in_specs=(row, row, row, row, rep, rep, rep, rep, rep, rep, rep),

@@ -126,11 +126,10 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
 
         Double-buffered: the main thread ONLY stages + dispatches (upload of
         batch k+1 streams while batch k computes), and result fetches run on
-        a dedicated thread — over a remote-device link a blocking fetch
-        costs ~70-100 ms that would otherwise serialize with the next
-        dispatch (CNTKModel.scala:515-520 batches for the same
-        keep-the-accelerator-busy reason). The in-flight window bounds live
-        HBM and applies backpressure."""
+        a dedicated thread so a blocking device-to-host copy never
+        serializes with the next dispatch (CNTKModel.scala:515-520 batches
+        for the same keep-the-accelerator-busy reason). The in-flight
+        window bounds live HBM and applies backpressure."""
         import concurrent.futures as _futures
 
         mesh = get_mesh()

@@ -5,9 +5,9 @@ histograms. Here the op is ``plane_histogram(bins, stats, mask)``:
 scatter the (g, h, count) stats of the masked rows into a
 ``(d * NUM_BINS, 3)`` plane.
 
-Two lowerings:
+Lowerings:
 
-- **Pallas (TPU, single chip)**: grid over (feature-blocks, row-chunks);
+- **Pallas (TPU, one device)**: grid over (feature-blocks, row-chunks);
   each step builds a bf16 one-hot (DF, B, rows) block in VMEM (rows on the
   128-lane dim) and accumulates ``one_hot @ stats_hi/lo`` into the output
   block — the scatter becomes an MXU matmul, which is how TPUs like their
@@ -19,15 +19,12 @@ Two lowerings:
   ``psum`` riding ICI — exactly LightGBM's data_parallel per-iteration
   histogram allreduce (lightgbm/TrainUtils.scala:496-512 NetworkInit +
   socket rings), with the MXU kernel intact on every chip.
-- **XLA scatter-add (sharded meshes without a mesh handle)**: GSPMD
-  partitions the scatter across the mesh and inserts the ICI allreduce
-  automatically. When the caller passes the mesh and Pallas is off, the
-  same scatter runs PER SHARD under ``shard_map`` with an explicit
-  ``psum`` instead — the allreduce stays visible (and measurable) in the
-  program rather than implied by the partitioner.
+- **XLA scatter-add (the reference)**: what the kernels are tested
+  against, the local kernel of a sharded CPU mesh, and the lowering when
+  Pallas is switched off. Under a mesh it runs PER SHARD under
+  ``shard_map`` with the same explicit ``psum``.
 - **Host bincount (CPU)**: XLA:CPU lowers scatter-add to an
-  element-by-element update loop (~70 ns/update measured — the reason
-  BENCH r06 *lost* to single-core sklearn by 4-35x); ``np.bincount``
+  element-by-element update loop (~70 ns/update measured); ``np.bincount``
   does the identical accumulation at ~2 ns/update and, because the
   kernel sees the row mask/slot vector instead of pre-zeroed stats, it
   compacts to the selected rows first — per-split cost becomes
@@ -39,9 +36,14 @@ Two lowerings:
   recompile once per process (the ~10x runtime win repays one compile
   within a single 20-iteration fit).
 
-Selection is automatic (see :func:`use_pallas` / :func:`use_host_hist` /
-:func:`hist_lowering`) and overridable with ``MMLSPARK_TPU_PALLAS=0|1``
-and ``MMLSPARK_TPU_HIST_HOST=0|1``.
+Selection follows the device the call is lowered for — the caller's mesh
+when it passes one, the process's default device otherwise (see
+:func:`hist_lowering`) — and is overridable with
+``MMLSPARK_TPU_PALLAS=0|1`` and ``MMLSPARK_TPU_HIST_HOST=0|1``. A call
+with no mesh is a ONE-DEVICE call: it takes the kernel on any TPU host,
+however many chips the host has; a caller whose rows are sharded passes
+its mesh. Every choice is counted at trace time in
+``mmlspark_gbdt_hist_lowerings_total{op,lowering}``.
 """
 
 from __future__ import annotations
@@ -53,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mmlspark_tpu.parallel.compat import shard_map
+from mmlspark_tpu import obs
 
 # The host-kernel pure_callbacks deadlock against XLA:CPU's async
 # dispatch: the callback thread's operand conversion (np.asarray on a
@@ -67,10 +69,20 @@ from mmlspark_tpu.parallel.compat import shard_map
 # before their first dispatch — embedding code that runs jax first must
 # set it itself (tests/conftest.py and bench.py do). No effect on TPU.
 if os.environ.get("MMLSPARK_TPU_CPU_ASYNC_DISPATCH") != "1":
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except Exception:  # pragma: no cover - option absent in this jax
-        pass
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
+
+_M_LOWERINGS = obs.counter(
+    "mmlspark_gbdt_hist_lowerings_total",
+    "Histogram ops traced, by the lowering chosen at trace time (pallas | "
+    "cpu = host bincount | scatter). A scatter count on a TPU means a "
+    "program is running the reference in place of the kernel",
+    labels=("op", "lowering"),
+)
+
+
+def _count_lowering(op: str, lowering: str) -> None:
+    _M_LOWERINGS.labels(op=op, lowering=lowering).inc()
+
 
 NUM_BINS = 256
 
@@ -83,90 +95,97 @@ _DF = int(os.environ.get("MMLSPARK_TPU_HIST_DF", "8"))
 _NC = int(os.environ.get("MMLSPARK_TPU_HIST_NC", "512"))
 
 
-def _tpu_compiler_params():
-    """Mosaic scoped-VMEM ceiling for the histogram kernels.
+# Scoped-VMEM ceiling handed to Mosaic for these kernels, in MB, by
+# ``device_kind``. Mosaic's default 16 MB is too tight for the multi-plane
+# kernel's resident set (one-hot block + packed accumulator: ~16.1 MB at
+# DF=32, B=256, 32 slots — a compile-time scoped-vmem OOM at d=64 on
+# v5e); a v5e core has 128 MB of VMEM. The CPU entry sizes the
+# interpreter's blocks like the v5e's so CPU tests trace the chip's block
+# choices. A kind that is not listed is an error, not a default.
+_VMEM_LIMIT_MB = {"TPU v5 lite": 96, "cpu": 96}
 
-    The default 16 MB limit is too tight for the multi-plane kernel's
-    resident set (one-hot block + packed accumulator: ~16.1 MB at
-    DF=32, B=256, 32 slots — observed as a compile-time scoped-vmem OOM
-    at d=64 on v5e). The chip has 128 MB of VMEM; raise the ceiling so
-    legal block choices aren't rejected 128 KB over the default bound.
-    """
-    if jax.default_backend() != "tpu":
-        return None
+
+def _target_device(mesh=None):
+    """The device a histogram call is lowered for: the first device of the
+    caller's mesh when it passes one, the process's default device
+    otherwise. A backend that failed to initialise raises here — it must
+    not select a lowering silently."""
+    if mesh is not None:
+        return mesh.devices.flat[0]
+    return jax.devices()[0]
+
+
+def _hist_vmem_mb(dev=None) -> int:
+    env = os.environ.get("MMLSPARK_TPU_HIST_VMEM_MB")
+    if env is not None:
+        return int(env)
+    kind = (dev or _target_device()).device_kind
+    if kind not in _VMEM_LIMIT_MB:
+        raise ValueError(
+            f"no scoped-VMEM ceiling known for device kind {kind!r}: add "
+            "its VMEM size to ops/histogram.py _VMEM_LIMIT_MB"
+        )
+    return _VMEM_LIMIT_MB[kind]
+
+
+def _pallas_call_kwargs(dev=None) -> dict:
+    """``interpret=`` / ``compiler_params=`` for a kernel lowered for
+    ``dev``: Mosaic with the device kind's VMEM ceiling on a TPU, the
+    Pallas interpreter anywhere else."""
+    dev = dev or _target_device()
+    if dev.platform != "tpu":
+        return {"interpret": True}
     from jax.experimental.pallas import tpu as pltpu
 
-    # jax renamed TPUCompilerParams -> CompilerParams (0.6); accept both
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return None
-    return cls(vmem_limit_bytes=_hist_vmem_mb() << 20)
+    return {
+        "interpret": False,
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_hist_vmem_mb(dev) << 20
+        ),
+    }
 
 
-def _hist_vmem_mb() -> int:
-    return int(os.environ.get("MMLSPARK_TPU_HIST_VMEM_MB", "96"))
+def _env_flag(name: str) -> "bool | None":
+    env = os.environ.get(name)
+    return None if env is None else env not in ("0", "false", "")
 
 
-def _pallas_enabled() -> bool:
-    """Is the Pallas lowering wanted at all (any device layout)?"""
-    env = os.environ.get("MMLSPARK_TPU_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def use_pallas(mesh=None) -> bool:
+    """Pallas lowering choice: the target device is a TPU, or env-forced."""
+    forced = _env_flag("MMLSPARK_TPU_PALLAS")
+    if forced is not None:
+        return forced
+    return _target_device(mesh).platform == "tpu"
 
 
-def use_pallas() -> bool:
-    """Unsharded-trace lowering choice (single-chip; or env-forced)."""
-    env = os.environ.get("MMLSPARK_TPU_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    try:
-        return jax.default_backend() == "tpu" and jax.device_count() == 1
-    except Exception:
-        return False
+def use_host_hist(mesh=None) -> bool:
+    """Host-bincount lowering choice (CPU target; or env-forced).
+
+    ``MMLSPARK_TPU_HIST_HOST=0`` restores the XLA scatter lowering on CPU
+    (the reference the host kernel is tested against)."""
+    forced = _env_flag("MMLSPARK_TPU_HIST_HOST")
+    if forced is not None:
+        return forced
+    return _target_device(mesh).platform == "cpu" and not use_pallas(mesh)
 
 
-def use_host_hist() -> bool:
-    """Host-bincount lowering choice (CPU backend; or env-forced).
-
-    ``MMLSPARK_TPU_HIST_HOST=0`` restores the XLA scatter lowering (the
-    only pre-host-kernel CPU path — kept for A/B measurement and for the
-    GSPMD-partitioned sharded case, which never takes the host path)."""
-    env = os.environ.get("MMLSPARK_TPU_HIST_HOST")
-    if env is not None:
-        return env not in ("0", "false", "")
-    try:
-        return jax.default_backend() == "cpu" and not use_pallas()
-    except Exception:
-        return False
-
-
-def hist_lowering() -> str:
-    """Name of the unsharded-trace lowering that :func:`plane_histogram`
-    would pick right now: ``pallas`` | ``cpu`` (host bincount) |
-    ``scatter``. Recorded by the bench so the CPU-vs-TPU numbers say
-    which kernel produced them."""
-    if use_pallas():
+def hist_lowering(mesh=None) -> str:
+    """Name of the one-device lowering :func:`plane_histogram` picks for
+    this target right now: ``pallas`` | ``cpu`` (host bincount) |
+    ``scatter``. Threaded into the growers' jit cache keys."""
+    if use_pallas(mesh):
         return "pallas"
-    if use_host_hist():
+    if use_host_hist(mesh):
         return "cpu"
     return "scatter"
 
 
 def _rows_sharded(mesh, shard_axis) -> bool:
-    try:
-        return (
-            mesh is not None
-            and shard_axis is not None
-            and dict(mesh.shape).get(shard_axis, 1) > 1
-        )
-    except Exception:
-        return False
+    return (
+        mesh is not None
+        and shard_axis is not None
+        and dict(mesh.shape).get(shard_axis, 1) > 1
+    )
 
 
 # -- host (numpy bincount) lowering -----------------------------------------
@@ -340,13 +359,10 @@ def _attributed(kernel, stage: str):
 
 
 def _callback(kernel, out_shape, *args) -> jnp.ndarray:
-    """pure_callback with version-portable vmap handling."""
-    try:
-        return jax.pure_callback(
-            kernel, out_shape, *args, vmap_method="sequential"
-        )
-    except TypeError:  # older jax: no vmap_method kwarg
-        return jax.pure_callback(kernel, out_shape, *args, vectorized=False)
+    """pure_callback; a vmapped call runs the host kernel row by row."""
+    return jax.pure_callback(
+        kernel, out_shape, *args, vmap_method="sequential"
+    )
 
 
 def _plane_histogram_host(
@@ -487,10 +503,14 @@ def _use_split(num_bins: int) -> bool:
 
 
 def _plane_histogram_pallas(
-    bins: jnp.ndarray, stats: jnp.ndarray, num_bins: int = NUM_BINS
+    bins: jnp.ndarray, stats: jnp.ndarray, num_bins: int = NUM_BINS,
+    dev=None,
 ) -> jnp.ndarray:
-    """(n, d) int32 bins + (n, 3) stats -> (d * B, 3) plane via Pallas."""
+    """(n, d) int32 bins + (n, 3) stats -> (d * B, 3) plane via Pallas,
+    lowered for ``dev`` (default: the process's default device)."""
     import jax.experimental.pallas as pl
+
+    call_kw = _pallas_call_kwargs(dev)
 
     n, d = bins.shape
     b = num_bins
@@ -523,8 +543,7 @@ def _plane_histogram_pallas(
             ],
             out_specs=pl.BlockSpec((df * bh, bl * 6), lambda f, r: (f, 0)),
             out_shape=jax.ShapeDtypeStruct((d_pad * bh, bl * 6), jnp.float32),
-            interpret=jax.default_backend() == "cpu",
-            compiler_params=_tpu_compiler_params(),
+            **call_kw,
         )(bins.T.astype(jnp.int32), stats.astype(jnp.float32))
         un = packed.reshape(d_pad, bh, bl, 6)
         out = (un[..., :3] + un[..., 3:]).reshape(d_pad * b, 3)
@@ -539,8 +558,7 @@ def _plane_histogram_pallas(
         ],
         out_specs=pl.BlockSpec((df * b, 3), lambda f, r: (f, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad * b, 3), jnp.float32),
-        interpret=jax.default_backend() == "cpu",
-        compiler_params=_tpu_compiler_params(),
+        **call_kw,
     )(bins.T.astype(jnp.int32), stats.astype(jnp.float32))
     return out[: d * b]
 
@@ -590,19 +608,21 @@ def _multi_resident_bytes(df: int, num_slots: int, num_bins: int) -> int:
     return df * num_bins * (_NC * 2 + num_slots * 6 * 4 * 2)
 
 
-def _multi_df(num_slots: int, num_bins: int, d: int = 1 << 30) -> int | None:
+def _multi_df(
+    num_slots: int, num_bins: int, d: int = 1 << 30, dev=None
+) -> int | None:
     """Feature block for the multi-plane kernel: as large as the
     kernel's VMEM-resident set allows (bigger blocks amortize the
     slot-mask rhs; measured +11% at S=32), but never wider than the
     feature count needs (padding a d=4 input to a 32-wide block would
     4x the one-hot work on sentinel rows).
 
-    The budget is 2/3 of the Mosaic ceiling :func:`_tpu_compiler_params`
-    sets (same env knob), leaving headroom for double-buffered input DMA
-    and Mosaic's own scratch. Returns ``None`` when not even the
-    smallest block fits — the caller must use the scatter lowering
-    (e.g. thousands of slots at 256 bins)."""
-    budget = _hist_vmem_mb() * 2 // 3 << 20
+    The budget is 2/3 of the Mosaic ceiling :func:`_pallas_call_kwargs`
+    sets for ``dev`` (same env knob), leaving headroom for
+    double-buffered input DMA and Mosaic's own scratch. Returns ``None``
+    when not even the smallest block fits — the caller must use the
+    scatter lowering (e.g. thousands of slots at 256 bins)."""
+    budget = _hist_vmem_mb(dev) * 2 // 3 << 20
     d_need = max(8, ((d + 7) // 8) * 8)
     best = None
     for df in sorted({32, 16, 8, _DF}, reverse=True):
@@ -619,16 +639,15 @@ def _multi_df(num_slots: int, num_bins: int, d: int = 1 << 30) -> int | None:
 
 def _multi_plane_pallas(
     bins: jnp.ndarray, stats: jnp.ndarray, slot: jnp.ndarray, num_slots: int,
-    num_bins: int = NUM_BINS, df: int | None = None,
+    num_bins: int = NUM_BINS, df: int | None = None, dev=None,
 ) -> jnp.ndarray:
-    import functools as _ft
-
     import jax.experimental.pallas as pl
 
     n, d = bins.shape
     b = num_bins
-    _df_m = df if df is not None else _multi_df(num_slots, b, d)
-    assert _df_m is not None, "no feature block fits VMEM; use scatter"
+    _df_m = df if df is not None else _multi_df(num_slots, b, d, dev)
+    if _df_m is None:
+        raise ValueError("no feature block fits VMEM; use the scatter lowering")
     d_pad = ((d + _df_m - 1) // _df_m) * _df_m
     n_pad = ((n + _NC - 1) // _NC) * _NC
     sentinel = b
@@ -640,7 +659,7 @@ def _multi_plane_pallas(
         stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
         slot = jnp.pad(slot, (0, n_pad - n), constant_values=num_slots)
     packed = pl.pallas_call(
-        _ft.partial(_multi_kernel, num_slots=num_slots, num_bins=b),
+        functools.partial(_multi_kernel, num_slots=num_slots, num_bins=b),
         grid=(d_pad // _df_m, n_pad // _NC),
         in_specs=[
             pl.BlockSpec((_df_m, _NC), lambda f, r: (f, r)),
@@ -649,8 +668,7 @@ def _multi_plane_pallas(
         ],
         out_specs=pl.BlockSpec((_df_m * b, num_slots * 6), lambda f, r: (f, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad * b, num_slots * 6), jnp.float32),
-        interpret=jax.default_backend() == "cpu",
-        compiler_params=_tpu_compiler_params(),
+        **_pallas_call_kwargs(dev),
     )(
         bins.T.astype(jnp.int32),
         stats.astype(jnp.float32),
@@ -704,52 +722,51 @@ def multi_plane_histogram(
 
     When the slot count is so large that no feature block fits the
     kernel's VMEM budget (thousands of planes at 256 bins — see
-    :func:`_multi_df`), the scatter lowering is used regardless of
-    backend: slower, but it compiles instead of tripping Mosaic's
-    scoped-VMEM ceiling."""
-    df_fit = _multi_df(num_slots, num_bins, bins.shape[1])
-    use_pl = df_fit is not None and _pallas_enabled()
+    :func:`_multi_df`), the scatter lowering is used whatever the
+    target: slower, but it compiles instead of tripping Mosaic's
+    scoped-VMEM ceiling. Like every choice here it is counted in
+    ``mmlspark_gbdt_hist_lowerings_total``."""
+    dev = _target_device(mesh)
+    df_fit = _multi_df(num_slots, num_bins, bins.shape[1], dev)
+    use_pl = df_fit is not None and use_pallas(mesh)
+    bins = bins.astype(jnp.int32)
+    slot = slot.astype(jnp.int32)
     if _rows_sharded(mesh, shard_axis):
         from jax.sharding import PartitionSpec as P
+
+        _count_lowering("multi_plane", "pallas" if use_pl else "scatter")
 
         def local(b, s, sl):
             if use_pl:
                 cube = _multi_plane_pallas(
-                    b.astype(jnp.int32), s, sl.astype(jnp.int32), num_slots,
-                    num_bins, df=df_fit,
+                    b, s, sl, num_slots, num_bins, df=df_fit, dev=dev
                 )
             else:
                 # per-shard scatter partials + the same explicit allreduce
                 # (LightGBM data_parallel with the MXU kernel swapped out)
-                cube = _multi_plane_scatter(
-                    b.astype(jnp.int32), s, sl.astype(jnp.int32), num_slots,
-                    num_bins,
-                )
+                cube = _multi_plane_scatter(b, s, sl, num_slots, num_bins)
             return jax.lax.psum(cube, shard_axis)
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(shard_axis, None), P(shard_axis, None), P(shard_axis)),
             out_specs=P(),
             check_vma=False,
         )(bins, stats, slot)
-    if df_fit is not None and use_pallas():
+    if use_pl:
+        _count_lowering("multi_plane", "pallas")
         return _multi_plane_pallas(
-            bins.astype(jnp.int32), stats, slot.astype(jnp.int32), num_slots,
-            num_bins, df=df_fit,
+            bins, stats, slot, num_slots, num_bins, df=df_fit, dev=dev
         )
-    if use_host_hist():
+    if use_host_hist(mesh):
+        _count_lowering("multi_plane", "cpu")
         return _multi_plane_host(
-            bins.astype(jnp.int32), stats, slot.astype(jnp.int32), num_slots,
-            num_bins, assume_in_range=bins_in_range,
+            bins, stats, slot, num_slots, num_bins,
+            assume_in_range=bins_in_range,
         )
-    # scatter path; under a sharded trace GSPMD partitions the scatter
-    # and inserts the allreduce automatically
-    return _multi_plane_scatter(
-        bins.astype(jnp.int32), stats, slot.astype(jnp.int32), num_slots,
-        num_bins,
-    )
+    _count_lowering("multi_plane", "scatter")
+    return _multi_plane_scatter(bins, stats, slot, num_slots, num_bins)
 
 
 def leaf_stat_sums(
@@ -761,8 +778,8 @@ def leaf_stat_sums(
     scatter-add on the XLA path, one bincount pass on the host path (the
     scatters cost ~3 ms/tree at bench shapes on XLA:CPU, ~25x the host
     kernel). ``sharded``: the caller's rows are sharded over a mesh —
-    keep the scatter (GSPMD partitions it; a host callback would force a
-    gather)."""
+    keep the scatter (GSPMD partitions this (n,) scatter; a host callback
+    would force a gather)."""
     if not sharded and use_host_hist():
         # leaf ids are grower outputs, always in [0, num_leaves)
         return _plane_histogram_host(
@@ -794,22 +811,24 @@ def _plane_histogram_shard_map(
 ) -> jnp.ndarray:
     """Per-shard kernel + explicit psum of the planes — LightGBM
     data_parallel's per-iteration histogram allreduce over ICI
-    (TrainUtils.scala:496-512). On TPU the local kernel is the Pallas MXU
-    one-hot; with Pallas off (CPU meshes, forced-device scaling runs) the
-    local kernel is the XLA scatter — either way the allreduce is an
-    explicit ``psum`` in the program, not a GSPMD inference."""
+    (TrainUtils.scala:496-512). On a TPU mesh the local kernel is the
+    Pallas MXU one-hot; with Pallas off (CPU meshes) the local kernel is
+    the XLA scatter — either way the allreduce is an explicit ``psum`` in
+    the program, not a GSPMD inference."""
     from jax.sharding import PartitionSpec as P
 
-    use_pl = _pallas_enabled()
+    use_pl = use_pallas(mesh)
+    dev = _target_device(mesh)
+    _count_lowering("plane", "pallas" if use_pl else "scatter")
 
     def local(b: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
         if use_pl:
-            h = _plane_histogram_pallas(b.astype(jnp.int32), s, num_bins)
+            h = _plane_histogram_pallas(b.astype(jnp.int32), s, num_bins, dev)
         else:
             h = _plane_histogram_scatter(b.astype(jnp.int32), s, num_bins)
         return jax.lax.psum(h, shard_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(shard_axis, None), P(shard_axis, None)),
@@ -822,7 +841,11 @@ def _plane_histogram_shard_map(
 # psum allreduce — the bench's hist scaling rows observe this so the
 # ICI-allreduce claim is a recorded number (in-jit builds fuse into the
 # surrounding program and cannot be timed individually)
-_M_ALLREDUCE_SECONDS = None
+_M_ALLREDUCE_SECONDS = obs.histogram(
+    "mmlspark_gbdt_hist_allreduce_seconds",
+    "Wall time of one sharded histogram build including the "
+    "explicit psum allreduce (observed by eager/bench builds)",
+)
 _SHARDED_BUILD_CACHE: dict = {}
 
 
@@ -832,15 +855,6 @@ def sharded_build_timed(
 ) -> jnp.ndarray:
     """Eagerly run one per-shard histogram + explicit psum and record the
     wall time into ``mmlspark_gbdt_hist_allreduce_seconds``."""
-    global _M_ALLREDUCE_SECONDS
-    if _M_ALLREDUCE_SECONDS is None:
-        from mmlspark_tpu import obs
-
-        _M_ALLREDUCE_SECONDS = obs.histogram(
-            "mmlspark_gbdt_hist_allreduce_seconds",
-            "Wall time of one sharded histogram build including the "
-            "explicit psum allreduce (observed by eager/bench builds)",
-        )
     import time as _t
 
     key = (mesh, shard_axis, num_bins)
@@ -871,7 +885,11 @@ def plane_histogram(
     ``mask``: optional (n,) row selector (0 rows contribute nothing).
     ``mesh``/``shard_axis``: when the rows are sharded over that mesh axis,
     run the local kernel (Pallas on TPU, scatter otherwise) per shard
-    under shard_map and psum the planes.
+    under shard_map and psum the planes. With no mesh the call is a
+    one-device call lowered for the process's default device.
+    ``allow_host=False`` keeps a CPU call off the host callback (a caller
+    already inside ``shard_map``: a callback per shard would serialize the
+    shards on the GIL).
     """
     if _rows_sharded(mesh, shard_axis):
         if mask is not None:
@@ -879,17 +897,22 @@ def plane_histogram(
         return _plane_histogram_shard_map(
             bins, stats, mesh, shard_axis, num_bins
         )
-    if use_pallas():
+    if use_pallas(mesh):
+        _count_lowering("plane", "pallas")
         if mask is not None:
             stats = stats * mask[:, None]
-        return _plane_histogram_pallas(bins.astype(jnp.int32), stats, num_bins)
-    if allow_host and use_host_hist():
+        return _plane_histogram_pallas(
+            bins.astype(jnp.int32), stats, num_bins, _target_device(mesh)
+        )
+    if allow_host and use_host_hist(mesh):
+        _count_lowering("plane", "cpu")
         # the host kernel takes the RAW mask: sparse selections compact
         # to the selected rows instead of scanning zeroed stats
         return _plane_histogram_host(
             bins.astype(jnp.int32), stats, mask, num_bins,
             assume_in_range=bins_in_range,
         )
+    _count_lowering("plane", "scatter")
     if mask is not None:
         stats = stats * mask[:, None]
     return _plane_histogram_scatter(bins.astype(jnp.int32), stats, num_bins)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mmlspark_tpu.parallel.compat import shard_map
 from mmlspark_tpu.parallel.mesh import get_mesh
 
 SEQ_AXIS = "data"  # default: ride the batch axis of the standard mesh
@@ -142,14 +141,14 @@ def ring_attention(
     spec = P(None, axis, None, None)
     mspec = P(None, axis)
     if has_mask:
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec, spec, spec, mspec),
             out_specs=spec,
             check_vma=False,
         )(q, k, v, kv_mask)
-    return shard_map(
+    return jax.shard_map(
         lambda a, b, c: local(a, b, c, None),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mmlspark_tpu.parallel.compat import axis_size, shard_map
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, get_mesh
 
 
@@ -46,7 +45,7 @@ def reduce_scatter(x: Any, axis: str = DATA_AXIS) -> Any:
 def ring_permute(x: Any, axis: str = DATA_AXIS, shift: int = 1) -> Any:
     """Neighbor exchange on the ring (building block for ring attention /
     pipelined allreduce)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name=axis, perm=perm)
 
@@ -65,10 +64,9 @@ def shard_apply(
 
     Replication checking is off (as at every other shard_map site here):
     the pmean-in-scan-carry pattern (vw/learner.py) legitimately moves
-    arrays between replicated and varying, which the old-jax ``check_rep``
-    tracker cannot type."""
+    arrays between replicated and varying."""
     mesh = mesh or get_mesh()
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

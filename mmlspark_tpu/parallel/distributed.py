@@ -154,7 +154,7 @@ def barrier(
     def _run() -> None:
         try:
             _wait()
-        except BaseException as e:  # noqa: BLE001 — relayed to the caller
+        except BaseException as e:  # noqa: BLE001 — re-raised in the caller
             errs.append(e)
         finally:
             done.set()

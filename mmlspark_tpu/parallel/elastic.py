@@ -1301,7 +1301,7 @@ class TcpReducer:
                 continue
             try:
                 pending._set(val=self._allreduce_at(arr, seq))
-            except BaseException as e:  # noqa: BLE001 — relayed to caller
+            except BaseException as e:  # noqa: BLE001 — re-raised in the caller
                 self._failed = e
                 pending._set(exc=e)
 

@@ -81,6 +81,7 @@ def cluster_summary() -> dict:
         hosts.setdefault(d.process_index, []).append(d.id)
     return {
         "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
         "num_devices": len(devs),
         "num_hosts": jax.process_count(),
         "host_devices": {str(k): v for k, v in sorted(hosts.items())},

@@ -276,6 +276,12 @@ def run_worker(
     obs.set_process_label(
         f"{service_name}@{advertise_host or info.host}:{info.port}"
     )
+    # one line BEFORE any model loads: a worker that came up on the CPU
+    # backend (TPU held by another process, platform unpinned) must be
+    # visible in its log, not inferred from its latency
+    from mmlspark_tpu.parallel.mesh import cluster_summary
+
+    print(f"worker: devices {json.dumps(cluster_summary())}", flush=True)
     store = ModelStore(budget_bytes=hbm_budget_bytes)
     specs = [(model_name_from_spec(model), model)] if model else []
     for entry in extra_models or ():
@@ -1913,6 +1919,9 @@ def main(argv: Optional[list] = None) -> None:
         "later versions wait for an explicit swap)",
     )
     args = ap.parse_args(argv)
+    from mmlspark_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.fault_plan:
         from mmlspark_tpu.core.faults import FaultPlan
 

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.parallel.collectives import shard_apply
-from mmlspark_tpu.parallel.compat import pcast
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, get_mesh
 
 LOSS_LOGISTIC = "logistic"
@@ -143,15 +142,15 @@ def _shard_train(
             w = jax.lax.pmean(w, axis)  # <- the per-pass allreduce
             g2 = jax.lax.pmean(g2, axis)
             # pmean output is axis-invariant; keep the carry type stable
-            w = pcast(w, axis, to="varying")
-            g2 = pcast(g2, axis, to="varying")
+            w = jax.lax.pcast(w, axis, to="varying")
+            g2 = jax.lax.pcast(g2, axis, to="varying")
         return (w, g2, t), None
 
     if axis is not None:
         # carry becomes device-varying after the first shard-local update;
         # mark it so from the start (shard_map varying-axis typing)
-        w0 = pcast(w0, axis, to="varying")
-        g20 = pcast(g20, axis, to="varying")
+        w0 = jax.lax.pcast(w0, axis, to="varying")
+        g20 = jax.lax.pcast(g20, axis, to="varying")
     (w, g2, t), _ = jax.lax.scan(
         one_pass, (w0, g20, jnp.float32(t0)), None, length=num_passes
     )

@@ -251,7 +251,38 @@ def test_multi_plane_huge_slots_uses_scatter():
     )
 
 
-def test_tpu_compiler_params_off_device():
-    """On CPU the kernels run in interpret mode: no TPU compiler params
-    (passing Mosaic options to the interpreter would be meaningless)."""
-    assert H._tpu_compiler_params() is None
+def test_pallas_call_kwargs_follow_the_target_device():
+    """Interpreter off-TPU (no Mosaic options); on a TPU target Mosaic with
+    the device kind's VMEM ceiling — and a kind with no known VMEM size is
+    an error, not a default."""
+    from types import SimpleNamespace
+
+    assert H._pallas_call_kwargs() == {"interpret": True}
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    kw = H._pallas_call_kwargs(v5e)
+    assert kw["interpret"] is False
+    assert kw["compiler_params"].vmem_limit_bytes == 96 << 20
+    unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        H._pallas_call_kwargs(unknown)
+
+
+def test_lowering_choice_is_counted_at_trace_time(monkeypatch):
+    """Every histogram op records the lowering it chose — including the
+    VMEM-overflow drop to the scatter — so a program that runs the
+    reference in place of the kernel is visible in /metrics."""
+    def count(op, lowering):
+        return H._M_LOWERINGS.labels(op=op, lowering=lowering).value
+
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "1")
+    bins, stats = _data(64, 2)
+    slot = jnp.zeros((64,), jnp.int32)
+    before = count("plane", "pallas"), count("multi_plane", "pallas")
+    H.plane_histogram(bins, stats)
+    H.multi_plane_histogram(bins, stats, slot, 2)
+    assert count("plane", "pallas") == before[0] + 1
+    assert count("multi_plane", "pallas") == before[1] + 1
+    # no feature block fits the VMEM budget -> scatter, and it is counted
+    before_s = count("multi_plane", "scatter")
+    H.multi_plane_histogram(bins, stats, slot, 4096)
+    assert count("multi_plane", "scatter") == before_s + 1

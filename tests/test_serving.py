@@ -105,8 +105,8 @@ def test_concurrent_clients_and_latency():
     # wall time and must not share a core slice with compile-heavy tests
     #
     # reference claims ~1ms end-to-end on cluster hardware
-    # (docs/mmlspark-serving.md:142-146); measured local p50 is ~0.8 ms
-    # (BENCH_r03), so gate at 2 ms server-side — a regression into
+    # (docs/mmlspark-serving.md:142-146); loopback p50 on this CPU box is
+    # under a millisecond, so gate at 2 ms server-side — a regression into
     # multi-ms territory must fail CI, not hide under a loose bound.
     # Best-of-2: a shared CI box under external load measures 2-3x the
     # quiet p50 through no fault of the serving path, and a REAL

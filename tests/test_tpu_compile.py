@@ -1,0 +1,108 @@
+"""The three Pallas histogram kernels COMPILE for the v5e — checked without one.
+
+libtpu ships a compile-only topology: ``get_topology_desc("v5e:2x2")``
+returns four ``TPU v5 lite`` devices that can be lowered and compiled for
+(XLA:TPU + Mosaic) but not executed on, with ``JAX_PLATFORMS=cpu``. A
+histogram call handed a mesh over those devices lowers for them — kernel
+choice, ``interpret=`` and the Mosaic VMEM ceiling follow the mesh's
+device, not the process's default backend (ops/histogram.py
+``_target_device``) — so tier-1 sees whether a kernel still compiles,
+one-chip and ``shard_map``-sharded, aligned and ragged.
+
+No skip when the topology is unavailable: this installation has libtpu, so
+a missing one is a failure.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from mmlspark_tpu.ops import histogram as H
+
+D = 64
+# rows: a multiple of the 512-row chunk on every shard / nothing of the kind
+ALIGNED, RAGGED = 8192, 5004
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    assert len(devices) == 4 and devices[0].device_kind == "TPU v5 lite"
+    # a compile-only client can serialize an executable but not read one
+    # back, so persistent-cache entries for these programs would only ever
+    # cost a write and a warning: keep them out of the cache
+    key = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, key)
+    jax.config.update(key, 1e9)
+    yield devices
+    jax.config.update(key, before)
+
+
+def _spec(shape, dtype, mesh, *axes):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(*axes))
+    )
+
+
+def _compiled_text(fn, *specs) -> str:
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+@pytest.mark.parametrize("n", [ALIGNED, RAGGED], ids=["aligned", "ragged"])
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "shard_map"])
+@pytest.mark.parametrize(
+    "num_bins,kernel", [(256, "_hist_split_kernel"), (64, "_hist_kernel")]
+)
+def test_plane_kernels_compile_for_v5e(v5e, num_bins, kernel, chips, n):
+    mesh = Mesh(np.array(v5e[:chips]), ("data",))
+    assert H._use_split(num_bins) == (kernel == "_hist_split_kernel")
+    text = _compiled_text(
+        lambda b, s: H.plane_histogram(
+            b, s, num_bins=num_bins, mesh=mesh, shard_axis="data"
+        ),
+        _spec((n, D), jnp.uint8, mesh, "data", None),
+        _spec((n, 3), jnp.float32, mesh, "data", None),
+    )
+    assert "tpu_custom_call" in text
+    # the sharded program sums the per-shard planes with an explicit psum
+    assert ("all-reduce" in text) == (chips > 1)
+
+
+# 32 slots x 64 features is the shape whose resident set overflowed Mosaic's
+# default VMEM ceiling (see _VMEM_LIMIT_MB): it must keep compiling. Two of
+# the four layout x shape pairs — this is the slowest kernel to compile
+@pytest.mark.parametrize(
+    "chips,n", [(1, ALIGNED), (4, RAGGED)],
+    ids=["one_chip-aligned", "shard_map-ragged"],
+)
+def test_multi_plane_kernel_compiles_for_v5e(v5e, chips, n):
+    mesh = Mesh(np.array(v5e[:chips]), ("data",))
+    text = _compiled_text(
+        lambda b, s, sl: H.multi_plane_histogram(
+            b, s, sl, 32, mesh=mesh, shard_axis="data"
+        ),
+        _spec((n, D), jnp.uint8, mesh, "data", None),
+        _spec((n, 3), jnp.float32, mesh, "data", None),
+        _spec((n,), jnp.int32, mesh, "data"),
+    )
+    assert "tpu_custom_call" in text
+    assert ("all-reduce" in text) == (chips > 1)
+
+
+def test_default_backend_stays_cpu_while_lowering_for_tpu(v5e):
+    """The process default is untouched: the same call with no mesh still
+    takes the CPU lowering, and the v5e target gets Mosaic with the device
+    kind's VMEM ceiling rather than the interpreter."""
+    assert jax.default_backend() == "cpu"
+    assert H.hist_lowering() in ("cpu", "scatter")
+    mesh = Mesh(np.array(v5e[:1]), ("data",))
+    assert H.hist_lowering(mesh) == "pallas"
+    kw = H._pallas_call_kwargs(H._target_device(mesh))
+    assert kw["interpret"] is False
+    assert kw["compiler_params"].vmem_limit_bytes == 96 << 20

@@ -67,6 +67,9 @@ def main() -> None:
     ap.add_argument("--model", default="ResNet50")
     ap.add_argument("--batch", type=int, default=256)
     args = ap.parse_args()
+    from mmlspark_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print(run(args.rows, args.chunk, args.size, args.model, args.batch))
 
 

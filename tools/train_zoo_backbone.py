@@ -60,6 +60,9 @@ def digits_to_images(x8: np.ndarray, size: int = IMAGE_SIZE) -> np.ndarray:
 
 
 def main() -> None:
+    from mmlspark_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     x8, y = load_digits()
     imgs = digits_to_images(x8)
     xtr, ytr = imgs[:N_TRAIN], y[:N_TRAIN]
