@@ -66,20 +66,6 @@ _M_FALLBACK = obs.counter(
     labels=("reason",),
 )
 
-_DEVICE_PHASE = None
-
-
-def _device_phase(phase: str, stage: str):
-    """core.profiling.device_phase, imported lazily — that module pulls
-    jax eagerly and this one must stay importable without it."""
-    global _DEVICE_PHASE
-    if _DEVICE_PHASE is None:
-        from mmlspark_tpu.core.profiling import device_phase
-
-        _DEVICE_PHASE = device_phase
-    return _DEVICE_PHASE(phase, stage)
-
-
 class Segment:
     """Base: one schedulable unit of a compiled pipeline."""
 
@@ -291,12 +277,12 @@ class FusedSegment(Segment):
         rest = chunks
         if not entry["compiled"]:
             # first call on this bucket pays trace+compile: block it to
-            # completion so the compile/execute attribution is honest
-            # (dispatching an already-compiled fn never blocks here)
-            with _device_phase("compile", self.name):
-                out0 = entry["fn"](chunks[0])
-                for v in out0.values():
-                    getattr(v, "block_until_ready", lambda: None)()
+            # completion so the compile-seconds histogram below holds the
+            # whole first call (dispatching an already-compiled fn never
+            # blocks here)
+            out0 = entry["fn"](chunks[0])
+            for v in out0.values():
+                getattr(v, "block_until_ready", lambda: None)()
             outs.append(out0)
             rest = chunks[1:]
             dt = time.perf_counter() - t0
@@ -307,9 +293,8 @@ class FusedSegment(Segment):
             mb = _M_BUCKET_COMPILES.labels(segment=self.name)
             if mb._on:
                 mb.inc()
-        with _device_phase("execute", self.name):
-            for chunk in rest:
-                outs.append(entry["fn"](chunk))
+        for chunk in rest:
+            outs.append(entry["fn"](chunk))
         q = dict(part)
         merged: dict = {}
         for c in self.device_outputs:

@@ -1,8 +1,9 @@
 """Profiling/tracing (SURVEY.md §5.1: the reference has StopWatch timers +
 the Timer pipeline stage; the TPU equivalent adds device-level tracing).
 
-- :func:`trace` wraps ``jax.profiler.trace`` — XLA/TPU timeline capture
-  viewable in TensorBoard/Perfetto.
+- :func:`trace` captures the XLA/TPU device timeline (TensorBoard/
+  Perfetto) with the profiler's host tracers off, and writes the ``obs``
+  spans of the capture beside it on the same (epoch) clock.
 - :func:`annotate` marks host spans so stage boundaries show up inside the
   device trace (the log-per-stage analogue of stages/Timer.scala:57-92).
 - :class:`ProfiledRun` collects per-stage wall times for a pipeline the
@@ -17,6 +18,9 @@ the Timer pipeline stage; the TPU equivalent adds device-level tracing).
 from __future__ import annotations
 
 import contextlib
+import json
+import os
+import time as _time
 from typing import Any, Iterator, Optional
 
 import jax
@@ -25,45 +29,36 @@ from mmlspark_tpu import obs
 from mmlspark_tpu.core.dataframe import DataFrame
 
 
-import time as _time
-
-# Device-time attribution: one counter splits where wall time actually
-# goes across the staged-dispatch path — phase=compile (first-call XLA
-# lowering+compile, blocked to completion), phase=execute (compiled
-# computation dispatch+run), phase=host_callback (pure_callback host
-# kernels running INSIDE a device computation — host time the device
-# waits out). Per-stage label = fused segment / pipeline stage name.
-# The first honest compile-vs-run split ahead of the Pallas/TPU arc.
-_M_DEVICE_SECONDS = obs.counter(
-    "mmlspark_device_seconds_total",
-    "Wall seconds at the compile/execute/host_callback boundaries, "
-    "by phase and pipeline stage / fused segment",
-    labels=("phase", "stage"),
-)
-
-
-@contextlib.contextmanager
-def device_phase(phase: str, stage: str) -> Iterator[None]:
-    """Attribute the wall time of a compile/execute/host_callback
-    boundary to ``mmlspark_device_seconds_total{phase,stage}``. Near-free
-    when the registry is disabled (one attribute read + perf_counter)."""
-    if not _M_DEVICE_SECONDS._on:
-        yield
-        return
-    t0 = _time.perf_counter()
-    try:
-        yield
-    finally:
-        _M_DEVICE_SECONDS.labels(phase=phase, stage=stage).inc(
-            _time.perf_counter() - t0
-        )
+SPANS_FILE = "obs_spans.json"
 
 
 @contextlib.contextmanager
 def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
-    """Capture a device+host profiler trace into ``log_dir``."""
-    with jax.profiler.trace(log_dir, create_perfetto_link=create_perfetto_link):
+    """Capture a device profiler trace into ``log_dir`` and write the
+    ``obs`` spans of the capture beside it (``obs_spans.json``).
+
+    The profiler's own host and Python tracers stay OFF: with the host
+    tracer on, the TPU runtime logs one event per transfer chunk — a 16x
+    slower feed and tens of GB of host memory for one chunk of images
+    (PERF.md section 6, PR 24). What the host was doing comes from the
+    program's spans instead: ``Span.to_dict()`` rows whose ``wall_ns`` is
+    epoch nanoseconds, the clock of the trace's ``profile_start_time``, so
+    they lay on the device timeline as they are."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0
+    t0 = _time.time_ns()
+    jax.profiler.start_trace(
+        log_dir, create_perfetto_link=create_perfetto_link,
+        profiler_options=options,
+    )
+    try:
         yield
+    finally:
+        jax.profiler.stop_trace()
+        spans = [s.to_dict() for s in obs.recent_spans() if s.wall_ns >= t0]
+        with open(os.path.join(log_dir, SPANS_FILE), "w") as f:
+            json.dump({"capture_start_ns": t0, "spans": spans}, f)
 
 
 def annotate(name: str) -> Any:
@@ -108,8 +103,7 @@ class ProfiledRun:
             for stage in _pipeline_stages(pipeline_model):
                 name = type(stage).__name__
                 with obs.span(f"pipeline.{name}") as sp:
-                    with device_phase("execute", name):
-                        cur = stage.transform(cur)
+                    cur = stage.transform(cur)
                 self.records.append((name, sp.duration_ns))
         return cur
 

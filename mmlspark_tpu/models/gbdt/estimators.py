@@ -18,6 +18,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from mmlspark_tpu import obs
 from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import (
     ComplexParam,
@@ -192,6 +193,21 @@ class _LightGBMParams(
         out["init"] = df[ic].astype(np.float32) if ic else None
         return out
 
+    def _describe_fit(self, sp: obs.Span, data: dict) -> None:
+        """What a ``gbdt.fit`` root span says about its fit."""
+        import jax
+
+        rows, features = data["x"].shape
+        sp.set_attr("rows", int(rows))
+        sp.set_attr("features", int(features))
+        sp.set_attr("trees", int(self.get("num_iterations")))
+        sp.set_attr("num_leaves", int(self.get("num_leaves")))
+        sp.set_attr("devices", jax.device_count())
+
+    def _model_string(self, booster: Booster) -> str:
+        with obs.span("gbdt.model_string"):
+            return booster.to_model_string()
+
     def _init_booster(self) -> Optional[Booster]:
         s = self.get("model_string")
         return Booster.from_model_string(s) if s else None
@@ -287,33 +303,38 @@ class LightGBMClassifier(Estimator, _LightGBMParams, HasProbabilityCol, HasRawPr
     objective = Param("binary | multiclass", default="binary", type_=str)
 
     def fit(self, df: DataFrame) -> "LightGBMClassificationModel":
-        data = self._gather(df)
-        y = data["y"].astype(np.int64)
-        n_classes = int(y.max()) + 1 if len(y) else 2
-        objective = self.get("objective")
-        if objective == "binary" and n_classes > 2:
-            objective = "multiclass"
-        num_class = n_classes if objective == "multiclass" else 1
-        data["y"] = y.astype(np.float64)
-        base: Any = 0.0
-        if self.get("boost_from_average") and data["init"] is None and len(y):
-            if objective == "binary":
-                p = float(np.clip(y.mean(), 1e-6, 1 - 1e-6))
-                base = float(np.log(p / (1 - p)))
-            else:  # multiclass: per-class log prior
-                priors = np.bincount(y, minlength=num_class) / len(y)
-                base = np.log(np.clip(priors, 1e-6, None)).astype(np.float32)
-        booster = self._fit_batches(
-            data, lambda: self._config(objective, num_class), base_score=base
-        )
-        m = LightGBMClassificationModel(
-            features_col=self.get("features_col"),
-            prediction_col=self.get("prediction_col"),
-            probability_col=self.get("probability_col"),
-            raw_prediction_col=self.get("raw_prediction_col"),
-        )
-        m.set(model_string=booster.to_model_string())
-        return m
+        # one trace per fit: every span below (train()'s binning, upload
+        # and chunks among them) is a descendant of this root
+        with obs.span("gbdt.fit") as sp:
+            with obs.span("gbdt.gather"):
+                data = self._gather(df)
+                y = data["y"].astype(np.int64)
+                n_classes = int(y.max()) + 1 if len(y) else 2
+                objective = self.get("objective")
+                if objective == "binary" and n_classes > 2:
+                    objective = "multiclass"
+                num_class = n_classes if objective == "multiclass" else 1
+                data["y"] = y.astype(np.float64)
+                base: Any = 0.0
+                if self.get("boost_from_average") and data["init"] is None and len(y):
+                    if objective == "binary":
+                        p = float(np.clip(y.mean(), 1e-6, 1 - 1e-6))
+                        base = float(np.log(p / (1 - p)))
+                    else:  # multiclass: per-class log prior
+                        priors = np.bincount(y, minlength=num_class) / len(y)
+                        base = np.log(np.clip(priors, 1e-6, None)).astype(np.float32)
+            self._describe_fit(sp, data)
+            booster = self._fit_batches(
+                data, lambda: self._config(objective, num_class), base_score=base
+            )
+            m = LightGBMClassificationModel(
+                features_col=self.get("features_col"),
+                prediction_col=self.get("prediction_col"),
+                probability_col=self.get("probability_col"),
+                raw_prediction_col=self.get("raw_prediction_col"),
+            )
+            m.set(model_string=self._model_string(booster))
+            return m
 
 
 def _booster_raw_device_fn(booster: Any, features_col: str, raw_key: str) -> Any:
@@ -482,31 +503,34 @@ class LightGBMRegressor(Estimator, _LightGBMParams, HasPredictionCol):
     )
 
     def fit(self, df: DataFrame) -> "LightGBMRegressionModel":
-        data = self._gather(df)
-        obj = objectives.canonical_objective(self.get("objective"))
-        base = 0.0
-        y = data["y"]
-        if self.get("boost_from_average") and data["init"] is None and len(y):
-            # LightGBM's BoostFromScore per objective family: log-link
-            # objectives start at log(mean) (scores live in log space),
-            # quantile at the alpha-percentile, l1/mape at the median
-            if obj in objectives.LOG_LINK_KINDS:
-                base = float(np.log(np.clip(y.mean(), 1e-9, None)))
-            elif obj == "quantile":
-                base = float(np.percentile(y, self.get("alpha") * 100.0))
-            elif obj in ("regression_l1", "mape"):
-                base = float(np.median(y))
-            else:
-                base = float(y.mean())
-        booster = self._fit_batches(
-            data, lambda: self._config(obj), base_score=base
-        )
-        m = LightGBMRegressionModel(
-            features_col=self.get("features_col"),
-            prediction_col=self.get("prediction_col"),
-        )
-        m.set(model_string=booster.to_model_string())
-        return m
+        with obs.span("gbdt.fit") as sp:
+            with obs.span("gbdt.gather"):
+                data = self._gather(df)
+                obj = objectives.canonical_objective(self.get("objective"))
+                base = 0.0
+                y = data["y"]
+                if self.get("boost_from_average") and data["init"] is None and len(y):
+                    # LightGBM's BoostFromScore per objective family: log-link
+                    # objectives start at log(mean) (scores live in log space),
+                    # quantile at the alpha-percentile, l1/mape at the median
+                    if obj in objectives.LOG_LINK_KINDS:
+                        base = float(np.log(np.clip(y.mean(), 1e-9, None)))
+                    elif obj == "quantile":
+                        base = float(np.percentile(y, self.get("alpha") * 100.0))
+                    elif obj in ("regression_l1", "mape"):
+                        base = float(np.median(y))
+                    else:
+                        base = float(y.mean())
+            self._describe_fit(sp, data)
+            booster = self._fit_batches(
+                data, lambda: self._config(obj), base_score=base
+            )
+            m = LightGBMRegressionModel(
+                features_col=self.get("features_col"),
+                prediction_col=self.get("prediction_col"),
+            )
+            m.set(model_string=self._model_string(booster))
+            return m
 
 
 class LightGBMRegressionModel(Model, _NativeModelIO, HasFeaturesCol, HasPredictionCol):
@@ -578,21 +602,24 @@ class LightGBMRanker(Estimator, _LightGBMParams, HasGroupCol, HasPredictionCol):
         gc = self.get("group_col")
         if not gc:
             raise ValueError("LightGBMRanker requires group_col (query column)")
-        data = self._gather(df)
-        groups_raw = df[gc]
-        _, group_ids = np.unique(
-            groups_raw.astype(str) if groups_raw.dtype == object else groups_raw,
-            return_inverse=True,
-        )
-        booster = self._fit_batches(
-            data, lambda: self._config("lambdarank"), group_ids=group_ids
-        )
-        m = LightGBMRankerModel(
-            features_col=self.get("features_col"),
-            prediction_col=self.get("prediction_col"),
-        )
-        m.set(model_string=booster.to_model_string())
-        return m
+        with obs.span("gbdt.fit") as sp:
+            with obs.span("gbdt.gather"):
+                data = self._gather(df)
+                groups_raw = df[gc]
+                _, group_ids = np.unique(
+                    groups_raw.astype(str) if groups_raw.dtype == object else groups_raw,
+                    return_inverse=True,
+                )
+            self._describe_fit(sp, data)
+            booster = self._fit_batches(
+                data, lambda: self._config("lambdarank"), group_ids=group_ids
+            )
+            m = LightGBMRankerModel(
+                features_col=self.get("features_col"),
+                prediction_col=self.get("prediction_col"),
+            )
+            m.set(model_string=self._model_string(booster))
+            return m
 
 
 class LightGBMRankerModel(Model, _NativeModelIO, HasFeaturesCol, HasPredictionCol):

@@ -82,6 +82,17 @@ _M_FUSED_CHUNKS = obs.counter(
     "Scan-fused chunk dispatches: a training run costs O(rounds / chunk) "
     "of these instead of O(rounds) per-round dispatches",
 )
+_M_HIST_ROWS = obs.counter(
+    "mmlspark_gbdt_hist_rows_total",
+    "Rows handed to the masked grower's histogram calls (kind=streamed) and "
+    "rows their masks selected (kind=selected): the useful share of a "
+    "pass. With row weights (sample weights, bagging, GOSS) selected is "
+    "the weighted count, rounded per call. Added when a tree's record "
+    "reaches the host; dart's per-tree fetch does not carry it",
+    labels=("kind",),
+)
+_M_HIST_STREAMED = _M_HIST_ROWS.labels(kind="streamed")
+_M_HIST_SELECTED = _M_HIST_ROWS.labels(kind="selected")
 _M_DEVICE_EVAL_ROUNDS = obs.counter(
     "mmlspark_gbdt_device_eval_rounds_total",
     "Boosting rounds whose eval metric was computed on device inside the "
@@ -169,10 +180,24 @@ def _trees_from_device_batched(pending: list, mapper: BinMapper) -> list:
         f: np.asarray(jnp.stack([getattr(g, f) for g in pending]))
         for f in _TREE_FIELDS
     }
+    counted = [g.hist_rows for g in pending if g.hist_rows is not None]
+    if counted:
+        _count_hist_rows(np.asarray(jnp.stack(counted)))
     return [
         _tree_from_host_records({f: stacked[f][i] for f in _TREE_FIELDS}, mapper)
         for i in range(len(pending))
     ]
+
+
+def _count_hist_rows(rows: np.ndarray) -> tuple:
+    """Add the growers' (..., 4) hist_rows records (GrownTree.hist_rows:
+    streamed and selected, each as // 4096 and % 4096) to
+    ``mmlspark_gbdt_hist_rows_total``; returns (streamed, selected)."""
+    r = np.asarray(rows).reshape(-1, 4).astype(np.int64).sum(axis=0)
+    streamed, selected = int(r[0] * 4096 + r[1]), int(r[2] * 4096 + r[3])
+    _M_HIST_STREAMED.inc(streamed)
+    _M_HIST_SELECTED.inc(selected)
+    return streamed, selected
 
 
 def _pad_catmask(cm: np.ndarray) -> np.ndarray:
@@ -691,6 +716,8 @@ def _scan_chunk(
         )
         recs = tuple(
             tuple(a for grp in r for a in grp)
+            + ((jnp.zeros((4,), jnp.int32)
+                if g.hist_rows is None else g.hist_rows),)
             + ((g.rec_catmask,) if has_cat else ())
             for r, g in zip(recs, grown_list)
         )
@@ -736,10 +763,11 @@ def _scan_chunk(
 def _unpack_chunk_trees(
     packed: np.ndarray, keep: int, k: int, L: int, has_cat: bool,
     num_bins: int, mapper: BinMapper,
-) -> list:
-    """Split the chunk's packed f32 record buffer back into host Trees."""
+) -> tuple:
+    """Split the chunk's packed f32 record buffer back into host Trees and
+    the growers' (C, k, 4) hist_rows records."""
     widths = (
-        [L - 1] * 5 + [L, L, L, L - 1]
+        [L - 1] * 5 + [L, L, L, L - 1, 4]
         + ([(L - 1) * num_bins // 16] if has_cat else [])
     )
     offs = np.cumsum([0] + widths)
@@ -765,7 +793,7 @@ def _unpack_chunk_trees(
                 "rec_catmask": (
                     (
                         (
-                            parts[9].astype(np.int64)[:, None]
+                            parts[10].astype(np.int64)[:, None]
                             >> np.arange(16)
                         ) & 1
                     ).astype(bool).reshape(L - 1, num_bins)
@@ -774,7 +802,9 @@ def _unpack_chunk_trees(
                 ),
             }
             trees.append(_tree_from_host_records(rec, mapper))
-    return trees
+    # the hist_rows of every tree of the chunk, kept or not: each made its
+    # histogram passes
+    return trees, packed[:, :, offs[9]: offs[10]]
 
 
 @jax.jit
@@ -996,25 +1026,33 @@ def train(
             for j, f in enumerate(cat_features):
                 samp[0, f] = gmax[j]
         global_sample = np.asarray(mhu.process_allgather(samp)).reshape(-1, d)
-        mapper = BinMapper.fit(
-            global_sample, max_bin=cfg.max_bin, seed=cfg.seed,
-            categorical_features=cat_features,
-        )
+        with obs.span("gbdt.bin_fit"):
+            mapper = BinMapper.fit(
+                global_sample, max_bin=cfg.max_bin, seed=cfg.seed,
+                categorical_features=cat_features,
+            )
     elif gang is not None:
         # bin bounds must be identical on every gang member AND invariant
         # across world sizes (a resumed shrunk-world run must interpret
         # bins exactly like a fresh run from the same checkpoint): fit on
         # the gang-gathered GLOBAL rows, not this member's slice
-        mapper = BinMapper.fit(
-            gang.binning_rows(np.asarray(x, np.float32)),
-            max_bin=cfg.max_bin, seed=cfg.seed,
-            categorical_features=cat_features,
-        )
+        with obs.span("gbdt.bin_fit"):
+            mapper = BinMapper.fit(
+                gang.binning_rows(np.asarray(x, np.float32)),
+                max_bin=cfg.max_bin, seed=cfg.seed,
+                categorical_features=cat_features,
+            )
     else:
-        mapper = BinMapper.fit(
-            x, max_bin=cfg.max_bin, seed=cfg.seed, categorical_features=cat_features
-        )
-    bins_host = x.bins if pre_binned else mapper.transform(x)
+        with obs.span("gbdt.bin_fit"):
+            mapper = BinMapper.fit(
+                x, max_bin=cfg.max_bin, seed=cfg.seed,
+                categorical_features=cat_features,
+            )
+    if pre_binned:
+        bins_host = x.bins
+    else:
+        with obs.span("gbdt.bin_transform", attrs={"cells": int(n) * int(d)}):
+            bins_host = mapper.transform(x)
     # histogram bin space: the smallest MXU-tile-aligned width covering
     # every bin code (codes live in [0, max_bin-1]). At the default
     # max_bin=255 this is the full uint8 space (256); smaller max_bin
@@ -1047,47 +1085,50 @@ def train(
     # device placement: rows sharded over the data axis when a mesh exists
     mesh = None
     use_voting = False
-    if multihost:
-        from mmlspark_tpu.parallel.mesh import get_mesh
-        from mmlspark_tpu.parallel.sharding import (
-            multihost_pad_target,
-            shard_batch_multihost,
-        )
+    with obs.span("gbdt.upload", attrs={
+        "what": "bins,weights", "bytes": int(bins_host.nbytes + w.nbytes),
+    }):
+        if multihost:
+            from mmlspark_tpu.parallel.mesh import get_mesh
+            from mmlspark_tpu.parallel.sharding import (
+                multihost_pad_target,
+                shard_batch_multihost,
+            )
 
-        mesh = get_mesh()
-        share = multihost_pad_target(n)  # equal local block per process
-        pad = share - n
-        bins_dev = shard_batch_multihost(
-            np.pad(bins_host, ((0, pad), (0, 0))), mesh
-        )
-        w_dev = shard_batch_multihost(np.pad(w, (0, pad)), mesh)
-        n_pad = share * jax.process_count()  # GLOBAL padded row count
-        if cfg.parallelism == "voting_parallel":
-            use_voting = True
-    elif shard:
-        from mmlspark_tpu.parallel.mesh import DATA_AXIS, get_mesh
-        from mmlspark_tpu.parallel.sharding import pad_batch, shard_batch
-
-        mesh = get_mesh()
-        n_dev = mesh.devices.size
-        bins_p, n_real = pad_batch(bins_host, n_dev)
-        pad = bins_p.shape[0] - n
-        bins_dev = shard_batch(bins_p, mesh)
-        w_dev = shard_batch(np.pad(w, (0, pad)), mesh)
-        n_pad = n + pad
-        if cfg.parallelism == "voting_parallel":
-            if dict(mesh.shape).get(DATA_AXIS, 1) > 1:
+            mesh = get_mesh()
+            share = multihost_pad_target(n)  # equal local block per process
+            pad = share - n
+            bins_dev = shard_batch_multihost(
+                np.pad(bins_host, ((0, pad), (0, 0))), mesh
+            )
+            w_dev = shard_batch_multihost(np.pad(w, (0, pad)), mesh)
+            n_pad = share * jax.process_count()  # GLOBAL padded row count
+            if cfg.parallelism == "voting_parallel":
                 use_voting = True
-            else:
-                log.info(
-                    "voting_parallel needs >1 data shard; "
-                    "falling back to data_parallel"
-                )
-    else:
-        pad = 0
-        bins_dev = jnp.asarray(bins_host)
-        w_dev = jnp.asarray(w)
-        n_pad = n
+        elif shard:
+            from mmlspark_tpu.parallel.mesh import DATA_AXIS, get_mesh
+            from mmlspark_tpu.parallel.sharding import pad_batch, shard_batch
+
+            mesh = get_mesh()
+            n_dev = mesh.devices.size
+            bins_p, n_real = pad_batch(bins_host, n_dev)
+            pad = bins_p.shape[0] - n
+            bins_dev = shard_batch(bins_p, mesh)
+            w_dev = shard_batch(np.pad(w, (0, pad)), mesh)
+            n_pad = n + pad
+            if cfg.parallelism == "voting_parallel":
+                if dict(mesh.shape).get(DATA_AXIS, 1) > 1:
+                    use_voting = True
+                else:
+                    log.info(
+                        "voting_parallel needs >1 data shard; "
+                        "falling back to data_parallel"
+                    )
+        else:
+            pad = 0
+            bins_dev = jnp.asarray(bins_host)
+            w_dev = jnp.asarray(w)
+            n_pad = n
 
     def padded(a: np.ndarray) -> jnp.ndarray:
         if pad:
@@ -1138,25 +1179,27 @@ def train(
     # -- device-resident loop state -----------------------------------------
     # scores, labels and per-iteration gradients stay sharded on device for
     # the whole loop; the host receives only split records + eval scalars.
-    if k > 1:
-        scores0 = np.zeros((n, k), np.float32)
-        y_onehot_dev = padded(np.eye(k, dtype=np.float32)[y.astype(np.int64)])
-    else:
-        scores0 = np.zeros(n, np.float32)
-        y_dev = padded(y.astype(np.float32))
-    scores0 = scores0 + np.asarray(base_score, np.float32)
-    if init_score is not None:
-        scores0 = scores0 + init_score.astype(scores0.dtype)
-    if init_booster is not None and init_booster.trees:
-        # score with ALL trees (not the best_iteration prefix predict_raw
-        # would default to): merge() replays every init tree, so residuals
-        # must be fit against exactly that
-        all_iters = len(init_booster.trees) // init_booster.num_class
-        prev = init_booster.predict_raw(
-            _densify(x) if sparse_input else x, num_iteration=all_iters
-        )
-        scores0 = scores0 + prev.astype(scores0.dtype)
-    scores = padded(scores0)
+    with obs.span("gbdt.upload", attrs={"what": "labels,scores"}) as _up:
+        if k > 1:
+            scores0 = np.zeros((n, k), np.float32)
+            y_onehot_dev = padded(np.eye(k, dtype=np.float32)[y.astype(np.int64)])
+        else:
+            scores0 = np.zeros(n, np.float32)
+            y_dev = padded(y.astype(np.float32))
+        scores0 = scores0 + np.asarray(base_score, np.float32)
+        if init_score is not None:
+            scores0 = scores0 + init_score.astype(scores0.dtype)
+        if init_booster is not None and init_booster.trees:
+            # score with ALL trees (not the best_iteration prefix predict_raw
+            # would default to): merge() replays every init tree, so residuals
+            # must be fit against exactly that
+            all_iters = len(init_booster.trees) // init_booster.num_class
+            prev = init_booster.predict_raw(
+                _densify(x) if sparse_input else x, num_iteration=all_iters
+            )
+            scores0 = scores0 + prev.astype(scores0.dtype)
+        scores = padded(scores0)
+        _up.set_attr("bytes", int(scores0.nbytes) * (1 + max(k, 1)))
 
     is_rf = cfg.boosting_type == "rf"
     is_dart = cfg.boosting_type == "dart"
@@ -1425,87 +1468,95 @@ def train(
                 # elastic gang boundary: straggler EWMA, loss detection,
                 # grow-back — raises to abort when the world changed
                 gang.on_round(it0)
-            t_chunk_ns = _time.perf_counter_ns()
-            C = min(C_full, cfg.num_iterations - it0)
-            if cfg.feature_fraction < 1.0:
-                fms = np.empty((C, d), np.float32)
-                for i in range(C):
-                    fm = (rng.random(d) < cfg.feature_fraction).astype(np.float32)
-                    if fm.sum() == 0:
-                        fm[rng.integers(d)] = 1.0
-                    fms[i] = fm
-            else:
-                fms = np.ones((C, d), np.float32)
-            scores, bag_dev, packed, metrics = _scan_chunk(
-                bins_dev, scores, y_enc_f, w_dev, bag_dev, base_key,
-                jnp.arange(it0, it0 + C, dtype=jnp.int32), jnp.asarray(fms),
-                cat_mask_dev, g_pre_f, h_pre_f,
-                rank_idx_dev, rank_valid_dev,
-                rank_idx_eval_dev, rank_valid_eval_dev,
-                y_eval, valid_w, rf_base_dev,
-                float(_objective_p1(cfg)),
-                float(bagging_fraction),
-                float(cfg.top_rate), float(cfg.other_rate),
-                float(cfg.lambda_l2), float(cfg.lambda_l1),
-                float(cfg.min_sum_hessian_in_leaf),
-                float(cfg.min_gain_to_split),
-                1.0 if is_rf else lr_cur,
-                objective=cfg.objective, k=k, grad_pre=grad_pre_f,
-                is_goss=is_goss, use_voting=use_voting,
-                has_cat=cat_mask_dev is not None,
-                num_leaves=int(cfg.num_leaves), max_depth=int(cfg.max_depth),
-                min_data_in_leaf=int(cfg.min_data_in_leaf),
-                top_k=int(cfg.top_k),
-                mesh=mesh if (use_voting or hist_sharded) else None,
-                depthwise=cfg.growth_policy == "depthwise",
-                partitioned=partitioned,
-                bagging_freq=int(bagging_freq) if use_bag else 0,
-                eval_kind=eval_kind, is_rf=is_rf, num_bins=hist_bins,
-                eval_k=int(eval_k), hist_mode=_hist_lowering(),
-            )
-            keep = C
-            if eval_on:
-                higher = eval_kind in _HIGHER_METRICS
-                mvals = np.asarray(metrics)
-                for i in range(C):
-                    val = float(mvals[i])
-                    if cfg.verbosity > 0:
-                        log.info("iter %d %s=%.6f", it0 + i, eval_kind, val)
-                    if best_val is None or (
-                        val > best_val if higher else val < best_val
-                    ):
-                        best_val, best_iter = val, it0 + i + 1
-                        rounds_no_improve = 0
+            with obs.span("gbdt.chunk") as chunk_sp:
+                with obs.span("gbdt.chunk.dispatch"):
+                    C = min(C_full, cfg.num_iterations - it0)
+                    if cfg.feature_fraction < 1.0:
+                        fms = np.empty((C, d), np.float32)
+                        for i in range(C):
+                            fm = (rng.random(d) < cfg.feature_fraction).astype(np.float32)
+                            if fm.sum() == 0:
+                                fm[rng.integers(d)] = 1.0
+                            fms[i] = fm
                     else:
-                        rounds_no_improve += 1
-                        if (
-                            early_stopping_round > 0
-                            and rounds_no_improve >= early_stopping_round
+                        fms = np.ones((C, d), np.float32)
+                    scores, bag_dev, packed, metrics = _scan_chunk(
+                        bins_dev, scores, y_enc_f, w_dev, bag_dev, base_key,
+                        jnp.arange(it0, it0 + C, dtype=jnp.int32), jnp.asarray(fms),
+                        cat_mask_dev, g_pre_f, h_pre_f,
+                        rank_idx_dev, rank_valid_dev,
+                        rank_idx_eval_dev, rank_valid_eval_dev,
+                        y_eval, valid_w, rf_base_dev,
+                        float(_objective_p1(cfg)),
+                        float(bagging_fraction),
+                        float(cfg.top_rate), float(cfg.other_rate),
+                        float(cfg.lambda_l2), float(cfg.lambda_l1),
+                        float(cfg.min_sum_hessian_in_leaf),
+                        float(cfg.min_gain_to_split),
+                        1.0 if is_rf else lr_cur,
+                        objective=cfg.objective, k=k, grad_pre=grad_pre_f,
+                        is_goss=is_goss, use_voting=use_voting,
+                        has_cat=cat_mask_dev is not None,
+                        num_leaves=int(cfg.num_leaves), max_depth=int(cfg.max_depth),
+                        min_data_in_leaf=int(cfg.min_data_in_leaf),
+                        top_k=int(cfg.top_k),
+                        mesh=mesh if (use_voting or hist_sharded) else None,
+                        depthwise=cfg.growth_policy == "depthwise",
+                        partitioned=partitioned,
+                        bagging_freq=int(bagging_freq) if use_bag else 0,
+                        eval_kind=eval_kind, is_rf=is_rf, num_bins=hist_bins,
+                        eval_k=int(eval_k), hist_mode=_hist_lowering(),
+                    )
+                # the one blocking fetch of the chunk: the packed tree
+                # records (and the (C,) eval metrics with them)
+                with obs.span("gbdt.chunk.wait"):
+                    mvals = np.asarray(metrics) if eval_on else None
+                    packed_host = np.asarray(packed)
+                keep = C
+                if eval_on:
+                    higher = eval_kind in _HIGHER_METRICS
+                    for i in range(C):
+                        val = float(mvals[i])
+                        if cfg.verbosity > 0:
+                            log.info("iter %d %s=%.6f", it0 + i, eval_kind, val)
+                        if best_val is None or (
+                            val > best_val if higher else val < best_val
                         ):
-                            log.info(
-                                "early stop at iter %d (best %d)",
-                                it0 + i, best_iter,
-                            )
-                            booster.best_iteration = best_iter
-                            stopped = True
-                            keep = i + 1
-                            break
-            booster.trees.extend(
-                _unpack_chunk_trees(
-                    np.asarray(packed), keep, k, int(cfg.num_leaves),
-                    cat_mask_dev is not None, hist_bins, mapper,
-                )
-            )
-            done_ns = _time.perf_counter_ns()
-            obs.record_span("gbdt.chunk", t_chunk_ns, done_ns)
-            _M_CHUNK_SECONDS.observe((done_ns - t_chunk_ns) / 1e9)
+                            best_val, best_iter = val, it0 + i + 1
+                            rounds_no_improve = 0
+                        else:
+                            rounds_no_improve += 1
+                            if (
+                                early_stopping_round > 0
+                                and rounds_no_improve >= early_stopping_round
+                            ):
+                                log.info(
+                                    "early stop at iter %d (best %d)",
+                                    it0 + i, best_iter,
+                                )
+                                booster.best_iteration = best_iter
+                                stopped = True
+                                keep = i + 1
+                                break
+                with obs.span("gbdt.chunk.unpack") as unpack_sp:
+                    trees, hist_rows = _unpack_chunk_trees(
+                        packed_host, keep, k, int(cfg.num_leaves),
+                        cat_mask_dev is not None, hist_bins, mapper,
+                    )
+                    booster.trees.extend(trees)
+                    streamed, selected = _count_hist_rows(hist_rows)
+                    unpack_sp.set_attr("hist_rows_streamed", streamed)
+                    unpack_sp.set_attr("hist_rows_selected", selected)
+                chunk_sp.set_attr("rounds", int(C))
+            chunk_s = chunk_sp.duration_s
+            _M_CHUNK_SECONDS.observe(chunk_s)
             _M_FUSED_CHUNKS.inc()
             if eval_on:
                 _M_DEVICE_EVAL_ROUNDS.inc(keep)
             _M_ROUNDS.inc(keep)
             # one observation per completed round at the amortized cost —
             # sum and count stay exact for scrape-side mean/rate math
-            per_round = (done_ns - t_chunk_ns) / 1e9 / max(keep, 1)
+            per_round = chunk_s / max(keep, 1)
             for _ in range(keep):
                 _M_ROUND_SECONDS.observe(per_round)
             it0 += C

@@ -47,6 +47,10 @@ class GrownTree(NamedTuple):
     row_leaf: jnp.ndarray      # (n,) int32 final leaf of every row
     rec_is_cat: jnp.ndarray    # (L-1,) bool: categorical subset split
     rec_catmask: jnp.ndarray   # (L-1, B) bool: bins going LEFT (cat splits)
+    # (4,) int32, or None from a grower that does not count: the rows its
+    # histogram calls were handed and the rows their masks selected, each
+    # as (// 4096, % 4096) so an f32 record carries them exactly
+    hist_rows: Optional[jnp.ndarray] = None
 
 
 def threshold_l1(G: jnp.ndarray, l1: Any) -> jnp.ndarray:
@@ -260,7 +264,8 @@ def _grow_tree(
     n, d = bins.shape
     L = num_leaves
     B = num_bins
-    bins = bins.astype(jnp.int32)
+    with jax.named_scope("gbdt.hist.widen"):
+        bins = bins.astype(jnp.int32)
     cat_f = categorical_mask.astype(bool)
     lam = lambda_l2
     l1 = lambda_l1
@@ -286,6 +291,12 @@ def _grow_tree(
             shard_axis=shard_axis, bins_in_range=True,
         )
 
+    def selected_rows(plane: jnp.ndarray) -> jnp.ndarray:
+        """The rows a histogram call's mask selected, read off its plane:
+        every row falls in one bin of feature 0, so that feature's count
+        channel sums to the (weighted) count. No pass over the rows."""
+        return jnp.round(plane[:B, 2].sum()).astype(jnp.int32)
+
     # best split of ONE leaf from its plane. Only state-free validity
     # (min_data, feature_fraction) is applied there; per-leaf state
     # (activity, depth) is applied at selection time, so cached results
@@ -299,81 +310,91 @@ def _grow_tree(
         (hist, row_leaf, leaf_depth, done,
          cache_gain, cache_feat, cache_bin, cache_catmask, prev_pair,
          rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-         rec_is_cat, rec_catmask) = state
+         rec_is_cat, rec_catmask, sel_hi, sel_lo) = state
 
-        # hist is carried incrementally: (L, d*B, 3) cube, only the two
-        # children of the previous split changed (LightGBM's
-        # parent-minus-child trick). The split-search cache mirrors that:
-        # re-evaluate ONLY those two leaves' planes, keep every other
-        # leaf's cached best split (their histograms are untouched).
-        pg, pf, pb, pcm = jax.vmap(leaf_best)(hist[prev_pair])
-        cache_gain = cache_gain.at[prev_pair].set(pg)
-        cache_feat = cache_feat.at[prev_pair].set(pf)
-        cache_bin = cache_bin.at[prev_pair].set(pb)
-        cache_catmask = cache_catmask.at[prev_pair].set(pcm)
+        with jax.named_scope("gbdt.best_split"):
+            # hist is carried incrementally: (L, d*B, 3) cube, only the two
+            # children of the previous split changed (LightGBM's
+            # parent-minus-child trick). The split-search cache mirrors
+            # that: re-evaluate ONLY those two leaves' planes, keep every
+            # other leaf's cached best split (their histograms are
+            # untouched).
+            pg, pf, pb, pcm = jax.vmap(leaf_best)(hist[prev_pair])
+            cache_gain = cache_gain.at[prev_pair].set(pg)
+            cache_feat = cache_feat.at[prev_pair].set(pf)
+            cache_bin = cache_bin.at[prev_pair].set(pb)
+            cache_catmask = cache_catmask.at[prev_pair].set(pcm)
 
-        # selection: apply the per-leaf state masks to the cached gains
-        num_active = k + 1
-        leaf_ids = jnp.arange(L, dtype=jnp.int32)
-        leaf_ok = leaf_ids < num_active
-        if max_depth > 0:
-            leaf_ok = leaf_ok & (leaf_depth < max_depth)
-        sel = jnp.where(leaf_ok, cache_gain, -jnp.inf)
-        bl = jnp.argmax(sel).astype(jnp.int32)
-        best_gain = sel[bl]
-        bf = cache_feat[bl]
-        bb = cache_bin[bl]
-        catmask = cache_catmask[bl]
-
-        do_split = (~done) & (best_gain > min_gain) & jnp.isfinite(best_gain)
-        new_id = jnp.int32(k + 1)
-        in_leaf = row_leaf == bl
-        row_bins = bins[:, bf]
-        if has_categorical:
-            is_cat_split = cat_f[bf]
-            goes_right = in_leaf & jnp.where(
-                is_cat_split, ~catmask[row_bins], row_bins > bb
+            # selection: apply the per-leaf state masks to the cached gains
+            num_active = k + 1
+            leaf_ids = jnp.arange(L, dtype=jnp.int32)
+            leaf_ok = leaf_ids < num_active
+            if max_depth > 0:
+                leaf_ok = leaf_ok & (leaf_depth < max_depth)
+            sel = jnp.where(leaf_ok, cache_gain, -jnp.inf)
+            bl = jnp.argmax(sel).astype(jnp.int32)
+            best_gain = sel[bl]
+            bf = cache_feat[bl]
+            bb = cache_bin[bl]
+            catmask = cache_catmask[bl]
+            do_split = (
+                (~done) & (best_gain > min_gain) & jnp.isfinite(best_gain)
             )
-        else:
-            is_cat_split = jnp.asarray(False)
-            goes_right = in_leaf & (row_bins > bb)
-        moved = do_split & goes_right
-        row_leaf = jnp.where(moved, new_id, row_leaf)
+
+        new_id = jnp.int32(k + 1)
+        with jax.named_scope("gbdt.apply_split"):
+            in_leaf = row_leaf == bl
+            row_bins = bins[:, bf]
+            if has_categorical:
+                is_cat_split = cat_f[bf]
+                goes_right = in_leaf & jnp.where(
+                    is_cat_split, ~catmask[row_bins], row_bins > bb
+                )
+            else:
+                is_cat_split = jnp.asarray(False)
+                goes_right = in_leaf & (row_bins > bb)
+            moved = do_split & goes_right
+            row_leaf = jnp.where(moved, new_id, row_leaf)
+            moved_f = moved.astype(jnp.float32)
         # incremental histogram update: scatter only the moved rows into the
         # right child's plane; the parent keeps (old - right)
-        right_plane = plane_hist(moved.astype(jnp.float32))
-        hist = hist.at[new_id].set(right_plane).at[bl].add(
-            jnp.where(do_split, -right_plane, 0.0)
-        )
-        child_depth = leaf_depth[bl] + 1
-        leaf_depth = jnp.where(
-            do_split,
-            leaf_depth.at[bl].set(child_depth).at[new_id].set(child_depth),
-            leaf_depth,
-        )
-        rec_leaf = rec_leaf.at[k].set(jnp.where(do_split, bl, -1))
-        rec_feature = rec_feature.at[k].set(jnp.where(do_split, bf, -1))
-        rec_bin = rec_bin.at[k].set(jnp.where(do_split, bb, -1))
-        rec_active = rec_active.at[k].set(do_split)
-        rec_gain = rec_gain.at[k].set(jnp.where(do_split, best_gain, 0.0))
-        rec_is_cat = rec_is_cat.at[k].set(do_split & is_cat_split)
-        rec_catmask = rec_catmask.at[k].set(
-            jnp.where(do_split & is_cat_split, catmask, False)
-        )
-        done = done | ~do_split
-        # the two leaves whose planes changed — next step refreshes them
-        prev_pair = jnp.stack([bl, new_id])
+        right_plane = plane_hist(moved_f)
+        picked = selected_rows(right_plane)
+        sel_hi = sel_hi + picked // 4096
+        sel_lo = sel_lo + picked % 4096
+        with jax.named_scope("gbdt.apply_split"):
+            hist = hist.at[new_id].set(right_plane).at[bl].add(
+                jnp.where(do_split, -right_plane, 0.0)
+            )
+            child_depth = leaf_depth[bl] + 1
+            leaf_depth = jnp.where(
+                do_split,
+                leaf_depth.at[bl].set(child_depth).at[new_id].set(child_depth),
+                leaf_depth,
+            )
+            rec_leaf = rec_leaf.at[k].set(jnp.where(do_split, bl, -1))
+            rec_feature = rec_feature.at[k].set(jnp.where(do_split, bf, -1))
+            rec_bin = rec_bin.at[k].set(jnp.where(do_split, bb, -1))
+            rec_active = rec_active.at[k].set(do_split)
+            rec_gain = rec_gain.at[k].set(
+                jnp.where(do_split, best_gain, 0.0)
+            )
+            rec_is_cat = rec_is_cat.at[k].set(do_split & is_cat_split)
+            rec_catmask = rec_catmask.at[k].set(
+                jnp.where(do_split & is_cat_split, catmask, False)
+            )
+            done = done | ~do_split
+            # the two leaves whose planes changed — next step refreshes them
+            prev_pair = jnp.stack([bl, new_id])
         return (hist, row_leaf, leaf_depth, done,
                 cache_gain, cache_feat, cache_bin, cache_catmask, prev_pair,
                 rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-                rec_is_cat, rec_catmask)
+                rec_is_cat, rec_catmask, sel_hi, sel_lo)
 
     # root histogram: the only full-data cube write of the whole tree
-    hist0 = (
-        jnp.zeros((L, d * B, 3), jnp.float32)
-        .at[0]
-        .set(plane_hist(jnp.ones((n,), jnp.float32)))
-    )
+    root_plane = plane_hist(jnp.ones((n,), jnp.float32))
+    root_rows = selected_rows(root_plane)
+    hist0 = jnp.zeros((L, d * B, 3), jnp.float32).at[0].set(root_plane)
     init = (
         hist0,
         jnp.zeros((n,), jnp.int32),
@@ -391,10 +412,12 @@ def _grow_tree(
         jnp.zeros((L - 1,), jnp.float32),
         jnp.zeros((L - 1,), bool),
         jnp.zeros((L - 1, B), bool),
+        root_rows // 4096,                       # sel_hi
+        root_rows % 4096,                        # sel_lo
     )
     (_, row_leaf, _, _, _, _, _, _, _,
      rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-     rec_is_cat, rec_catmask) = (
+     rec_is_cat, rec_catmask, sel_hi, sel_lo) = (
         jax.lax.fori_loop(0, L - 1, step, init)
     )
 
@@ -407,10 +430,16 @@ def _grow_tree(
     Gl, Hl, Cl = sums[:, 0], sums[:, 1], sums[:, 2]
     leaf_values = -soft(Gl) / (Hl + lambda_l2) * learning_rate
     leaf_values = jnp.where(Cl > 0, leaf_values, 0.0)
+    # one histogram call per step (a finished tree's steps still stream the
+    # rows, under an empty mask) and one for the root: L calls of n rows
+    streamed = n * L
+    hist_rows = jnp.stack([
+        jnp.int32(streamed // 4096), jnp.int32(streamed % 4096), sel_hi, sel_lo,
+    ])
     return GrownTree(
         rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
         leaf_values, Cl.astype(jnp.int32), row_leaf,
-        rec_is_cat, rec_catmask,
+        rec_is_cat, rec_catmask, hist_rows,
     )
 
 

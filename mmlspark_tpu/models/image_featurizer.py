@@ -16,9 +16,11 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mmlspark_tpu import obs
 from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import (
     ComplexParam,
@@ -90,10 +92,12 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol, HasBatchSize):
         def full_fn(vs: Any, x: Any) -> Any:
             # x: (N,H,W,C) float32 raw pixels 0..255; entire preprocess is
             # inside the jitted program so it fuses with the backbone
-            if bgr:
-                x = image_ops.bgr_to_rgb(x)
-            x = image_ops.resize(x, size, size)
-            x = image_ops.normalize(x)
+            # named for the device trace (Flax names the backbone's modules)
+            with jax.named_scope("featurize.preprocess"):
+                if bgr:
+                    x = image_ops.bgr_to_rgb(x)
+                x = image_ops.resize(x, size, size)
+                x = image_ops.normalize(x)
             out = apply_fn(vs, x)
             return out[node] if isinstance(out, dict) else out
 
@@ -198,12 +202,15 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol, HasBatchSize):
         inner = self._build()
 
         def fn(p: Partition) -> Partition:
-            x, keep = self._coerce_images(p[ic])
-            feats = inner.apply_batch(x) if len(x) else np.zeros((0, 1), np.float32)
-            q = dict(p)
-            if not keep.all():  # undecodable rows dropped from every column
-                q = {k: v[keep] for k, v in p.items()}
-            q[self.get_or_fail("output_col")] = feats
-            return q
+            # one trace per partition; apply_batch's spans are its children
+            with obs.span("featurize.partition", attrs={"rows": len(p[ic])}):
+                with obs.span("featurize.coerce"):
+                    x, keep = self._coerce_images(p[ic])
+                feats = inner.apply_batch(x) if len(x) else np.zeros((0, 1), np.float32)
+                q = dict(p)
+                if not keep.all():  # undecodable rows dropped from every column
+                    q = {k: v[keep] for k, v in p.items()}
+                q[self.get_or_fail("output_col")] = feats
+                return q
 
         return df.map_partitions(fn, parallel=False)

@@ -24,6 +24,7 @@ from typing import Any, Callable, Optional
 import jax
 import numpy as np
 
+from mmlspark_tpu import obs
 from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import (
     ComplexParam,
@@ -132,26 +133,38 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
         window bounds live HBM and applies backpressure."""
         import concurrent.futures as _futures
 
-        mesh = get_mesh()
-        vs = self._device_variables(mesh)
-        bs = self._effective_batch(mesh)
-        dt = self.get("input_dtype")
-        x = np.asarray(x, dtype=dt) if dt else np.asarray(x)
-        padded, n = pad_batch(x, bs)
-        fn = self._compiled(padded[:bs].shape, mesh)
-        outs: list = []
-        pending: list = []
-        # one fetcher thread keeps results ordered; np.asarray releases the
-        # GIL while it waits on the transfer, so dispatch continues
-        with _futures.ThreadPoolExecutor(max_workers=1) as fetcher:
-            for i in range(0, padded.shape[0], bs):
-                chunk = shard_batch(padded[i: i + bs], mesh)
-                y = fn(vs, chunk)  # async dispatch, no host sync
-                pending.append(fetcher.submit(np.asarray, y))
-                if len(pending) >= self._MAX_IN_FLIGHT:
-                    outs.append(pending.pop(0).result())
-            outs.extend(f.result() for f in pending)
-        return np.concatenate(outs, axis=0)[:n]
+        # spans time the host in each call and add no synchronisation: when
+        # the device started is the device trace's to say
+        with obs.span("xla_model.apply_batch") as sp:
+            with obs.span("xla_model.prepare"):
+                mesh = get_mesh()
+                vs = self._device_variables(mesh)
+                bs = self._effective_batch(mesh)
+                dt = self.get("input_dtype")
+                x = np.asarray(x, dtype=dt) if dt else np.asarray(x)
+                padded, n = pad_batch(x, bs)
+                fn = self._compiled(padded[:bs].shape, mesh)
+            sp.set_attr("rows", int(n))
+            sp.set_attr("batches", padded.shape[0] // bs)
+            outs: list = []
+            pending: list = []
+            # one fetcher thread keeps results ordered; np.asarray releases
+            # the GIL while it waits on the transfer, so dispatch continues
+            with _futures.ThreadPoolExecutor(max_workers=1) as fetcher:
+                for i in range(0, padded.shape[0], bs):
+                    batch = padded[i: i + bs]
+                    with obs.span("xla_model.stage", attrs={"bytes": batch.nbytes}):
+                        chunk = shard_batch(batch, mesh)
+                    with obs.span("xla_model.dispatch"):
+                        y = fn(vs, chunk)  # async dispatch, no host sync
+                        pending.append(fetcher.submit(np.asarray, y))
+                    if len(pending) >= self._MAX_IN_FLIGHT:
+                        with obs.span("xla_model.backpressure"):
+                            outs.append(pending.pop(0).result())
+                with obs.span("xla_model.drain"):
+                    outs.extend(f.result() for f in pending)
+            with obs.span("xla_model.concat"):
+                return np.concatenate(outs, axis=0)[:n]
 
     # -- stage interface ----------------------------------------------------
 

@@ -45,6 +45,7 @@ from mmlspark_tpu.obs.tracing import (
     SpanBuffer,
     TRACE_HEADER,
     clear_recent_spans,
+    current_span_id,
     current_trace_id,
     new_span_id,
     new_trace_id,
@@ -104,6 +105,7 @@ __all__ = [
     "TRACE_HEADER",
     "clear_recent_spans",
     "counter",
+    "current_span_id",
     "current_trace_id",
     "enabled",
     "gauge",

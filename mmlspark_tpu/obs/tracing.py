@@ -132,6 +132,13 @@ def current_trace_id() -> Optional[str]:
     return s[-1].trace_id if s else None
 
 
+def current_span_id() -> Optional[str]:
+    """The innermost open span's id on this thread, if any: what a
+    retroactive recorder (``record_span``) names as its parent."""
+    s = _stack()
+    return s[-1].span_id if s else None
+
+
 class Span:
     """One named interval in a trace. Slotted plain class, not a
     dataclass: spans are created per request on the serving hot path and

@@ -339,25 +339,6 @@ def _host_multi_kernel(
     return out.reshape(ns, b.shape[1] * nb, 3)
 
 
-_DEVICE_PHASE = None
-
-
-def _attributed(kernel, stage: str):
-    """Wrap a pure_callback host kernel so its wall time lands in
-    ``mmlspark_device_seconds_total{phase="host_callback"}`` — host time
-    the device computation sits waiting out (core/profiling.py)."""
-    def run(*args):
-        global _DEVICE_PHASE
-        if _DEVICE_PHASE is None:
-            from mmlspark_tpu.core.profiling import device_phase
-
-            _DEVICE_PHASE = device_phase
-        with _DEVICE_PHASE("host_callback", stage):
-            return kernel(*args)
-
-    return run
-
-
 def _callback(kernel, out_shape, *args) -> jnp.ndarray:
     """pure_callback; a vmapped call runs the host kernel row by row."""
     return jax.pure_callback(
@@ -374,10 +355,7 @@ def _plane_histogram_host(
 ) -> jnp.ndarray:
     d = bins.shape[1]
     out = jax.ShapeDtypeStruct((d * num_bins, 3), jnp.float32)
-    kern = _attributed(
-        functools.partial(_host_plane_kernel, num_bins, assume_in_range),
-        "histogram_plane",
-    )
+    kern = functools.partial(_host_plane_kernel, num_bins, assume_in_range)
     if mask is None:
         return _callback(kern, out, bins, stats)
     return _callback(kern, out, bins, stats, mask)
@@ -393,11 +371,8 @@ def _multi_plane_host(
 ) -> jnp.ndarray:
     d = bins.shape[1]
     out = jax.ShapeDtypeStruct((num_slots, d * num_bins, 3), jnp.float32)
-    kern = _attributed(
-        functools.partial(
-            _host_multi_kernel, num_slots, num_bins, assume_in_range
-        ),
-        "histogram_multi",
+    kern = functools.partial(
+        _host_multi_kernel, num_slots, num_bins, assume_in_range
     )
     return _callback(kern, out, bins, stats, slot)
 
@@ -524,12 +499,15 @@ def _plane_histogram_pallas(
     # the scatter lowering drops those (mode='drop') and the lowerings
     # must agree exactly.
     sentinel = b
-    bins = jnp.where((bins >= 0) & (bins < b), bins, sentinel)
-    if d_pad != d:
-        bins = jnp.pad(bins, ((0, 0), (0, d_pad - d)), constant_values=sentinel)
-    if n_pad != n:
-        bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)), constant_values=sentinel)
-        stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
+    with jax.named_scope("gbdt.hist.pad"):
+        bins = jnp.where((bins >= 0) & (bins < b), bins, sentinel)
+        if d_pad != d:
+            bins = jnp.pad(bins, ((0, 0), (0, d_pad - d)), constant_values=sentinel)
+        if n_pad != n:
+            bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)), constant_values=sentinel)
+            stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
+        bins_t = bins.T.astype(jnp.int32)
+        stats = stats.astype(jnp.float32)
 
     if split:
         bl = _BL_SPLIT
@@ -543,8 +521,9 @@ def _plane_histogram_pallas(
             ],
             out_specs=pl.BlockSpec((df * bh, bl * 6), lambda f, r: (f, 0)),
             out_shape=jax.ShapeDtypeStruct((d_pad * bh, bl * 6), jnp.float32),
+            name="plane_histogram",
             **call_kw,
-        )(bins.T.astype(jnp.int32), stats.astype(jnp.float32))
+        )(bins_t, stats)
         un = packed.reshape(d_pad, bh, bl, 6)
         out = (un[..., :3] + un[..., 3:]).reshape(d_pad * b, 3)
         return out[: d * b]
@@ -558,8 +537,9 @@ def _plane_histogram_pallas(
         ],
         out_specs=pl.BlockSpec((df * b, 3), lambda f, r: (f, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad * b, 3), jnp.float32),
+        name="plane_histogram",
         **call_kw,
-    )(bins.T.astype(jnp.int32), stats.astype(jnp.float32))
+    )(bins_t, stats)
     return out[: d * b]
 
 
@@ -651,13 +631,17 @@ def _multi_plane_pallas(
     d_pad = ((d + _df_m - 1) // _df_m) * _df_m
     n_pad = ((n + _NC - 1) // _NC) * _NC
     sentinel = b
-    bins = jnp.where((bins >= 0) & (bins < b), bins, sentinel)
-    if d_pad != d:
-        bins = jnp.pad(bins, ((0, 0), (0, d_pad - d)), constant_values=sentinel)
-    if n_pad != n:
-        bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)), constant_values=sentinel)
-        stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
-        slot = jnp.pad(slot, (0, n_pad - n), constant_values=num_slots)
+    with jax.named_scope("gbdt.hist.pad"):
+        bins = jnp.where((bins >= 0) & (bins < b), bins, sentinel)
+        if d_pad != d:
+            bins = jnp.pad(bins, ((0, 0), (0, d_pad - d)), constant_values=sentinel)
+        if n_pad != n:
+            bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)), constant_values=sentinel)
+            stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
+            slot = jnp.pad(slot, (0, n_pad - n), constant_values=num_slots)
+        bins_t = bins.T.astype(jnp.int32)
+        stats = stats.astype(jnp.float32)
+        slot = slot.astype(jnp.int32)[None, :]
     packed = pl.pallas_call(
         functools.partial(_multi_kernel, num_slots=num_slots, num_bins=b),
         grid=(d_pad // _df_m, n_pad // _NC),
@@ -668,12 +652,9 @@ def _multi_plane_pallas(
         ],
         out_specs=pl.BlockSpec((_df_m * b, num_slots * 6), lambda f, r: (f, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad * b, num_slots * 6), jnp.float32),
+        name="multi_plane_histogram",
         **_pallas_call_kwargs(dev),
-    )(
-        bins.T.astype(jnp.int32),
-        stats.astype(jnp.float32),
-        slot.astype(jnp.int32)[None, :],
-    )
+    )(bins_t, stats, slot)
     # (f*B+v, s*6+j) -> (s, f*B+v, j), summing hi/lo halves
     un = packed.reshape(d_pad * b, num_slots, 6)
     out = jnp.transpose(un[..., :3] + un[..., 3:], (1, 0, 2))
@@ -729,7 +710,7 @@ def multi_plane_histogram(
     dev = _target_device(mesh)
     df_fit = _multi_df(num_slots, num_bins, bins.shape[1], dev)
     use_pl = df_fit is not None and use_pallas(mesh)
-    bins = bins.astype(jnp.int32)
+    bins = _widened(bins)
     slot = slot.astype(jnp.int32)
     if _rows_sharded(mesh, shard_axis):
         from jax.sharding import PartitionSpec as P
@@ -823,9 +804,9 @@ def _plane_histogram_shard_map(
 
     def local(b: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
         if use_pl:
-            h = _plane_histogram_pallas(b.astype(jnp.int32), s, num_bins, dev)
+            h = _plane_histogram_pallas(_widened(b), s, num_bins, dev)
         else:
-            h = _plane_histogram_scatter(b.astype(jnp.int32), s, num_bins)
+            h = _plane_histogram_scatter(_widened(b), s, num_bins)
         return jax.lax.psum(h, shard_axis)
 
     return jax.shard_map(
@@ -874,6 +855,20 @@ def sharded_build_timed(
     return out
 
 
+def _masked(stats: jnp.ndarray, mask: "jnp.ndarray | None") -> jnp.ndarray:
+    """The stats of the selected rows: a full f32 pass over the rows per
+    call, named so the device trace can tell it from the kernel."""
+    if mask is None:
+        return stats
+    with jax.named_scope("gbdt.hist.mask"):
+        return stats * mask[:, None]
+
+
+def _widened(bins: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("gbdt.hist.widen"):
+        return bins.astype(jnp.int32)
+
+
 def plane_histogram(
     bins: jnp.ndarray, stats: jnp.ndarray, mask: jnp.ndarray | None = None,
     num_bins: int = NUM_BINS, mesh=None, shard_axis: str | None = None,
@@ -892,27 +887,24 @@ def plane_histogram(
     shards on the GIL).
     """
     if _rows_sharded(mesh, shard_axis):
-        if mask is not None:
-            stats = stats * mask[:, None]
+        stats = _masked(stats, mask)
         return _plane_histogram_shard_map(
             bins, stats, mesh, shard_axis, num_bins
         )
     if use_pallas(mesh):
         _count_lowering("plane", "pallas")
-        if mask is not None:
-            stats = stats * mask[:, None]
+        stats = _masked(stats, mask)
         return _plane_histogram_pallas(
-            bins.astype(jnp.int32), stats, num_bins, _target_device(mesh)
+            _widened(bins), stats, num_bins, _target_device(mesh)
         )
     if allow_host and use_host_hist(mesh):
         _count_lowering("plane", "cpu")
         # the host kernel takes the RAW mask: sparse selections compact
         # to the selected rows instead of scanning zeroed stats
         return _plane_histogram_host(
-            bins.astype(jnp.int32), stats, mask, num_bins,
+            _widened(bins), stats, mask, num_bins,
             assume_in_range=bins_in_range,
         )
     _count_lowering("plane", "scatter")
-    if mask is not None:
-        stats = stats * mask[:, None]
-    return _plane_histogram_scatter(bins.astype(jnp.int32), stats, num_bins)
+    stats = _masked(stats, mask)
+    return _plane_histogram_scatter(_widened(bins), stats, num_bins)

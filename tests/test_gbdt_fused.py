@@ -224,6 +224,10 @@ def _grown(bins, g, h, w, monkeypatch, host: bool, cat=None, **over):
 
 def _tree_fields_equal(a, b):
     for f in a._fields:
+        if f == "hist_rows":
+            # how the masked grower's histogram calls went, not the tree:
+            # only that grower counts them
+            continue
         av, bv = getattr(a, f), getattr(b, f)
         if av.dtype.kind == "f":
             np.testing.assert_allclose(av, bv, atol=2e-4, rtol=2e-4,

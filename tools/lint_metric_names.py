@@ -47,10 +47,11 @@ SUBSYSTEMS = (
     # family prefix (the singular "artifact" covers the pull-side
     # fetch/verify instruments that predate it)
     "artifacts",
-    # stall forensics (obs/prof.py, obs/watchdog.py, core/profiling.py):
-    # sampling profiler, hang watchdog, compile/execute/host_callback
-    # device-time attribution
-    "prof", "watchdog", "device",
+    # stall forensics (obs/prof.py, obs/watchdog.py): sampling profiler,
+    # hang watchdog
+    "prof", "watchdog",
+    # compile requests by cache hit / miss (core/compile_cache.py)
+    "xla",
 )
 # "state" is for enum-valued gauges (e.g. the circuit-breaker gauge
 # mmlspark_gateway_breaker_state: 0=closed 1=open 2=half-open)
