@@ -308,23 +308,6 @@ def _seg_gbdt(on_accel: bool, n_dev: int) -> dict:
             out["gbdt_rounds_per_dispatch"] = round(
                 reps / max(out["gbdt_fused_dispatch_count"], 1), 1
             )
-    if on_accel:
-        # attribution: the same lossguide run with the data-partitioned
-        # grower forced ON (LightGBM's DataPartition cost model, default
-        # OFF after TPU measurement showed the masked full-pass grower 3x
-        # faster — see train.py) so the choice stays visible in one line
-        import os as _os
-
-        _os.environ["MMLSPARK_TPU_GBDT_PARTITION"] = "1"
-        try:
-            cfg = TrainConfig(objective="binary", num_iterations=reps,
-                              num_leaves=63, min_data_in_leaf=20, seed=0)
-            train(x, y, cfg)
-            out["gbdt_partitioned_trees_per_sec"] = round(
-                reps / _best_of(lambda: train(x, y, cfg)), 2
-            )
-        finally:
-            _os.environ.pop("MMLSPARK_TPU_GBDT_PARTITION", None)
     return out
 
 

@@ -57,6 +57,7 @@ from mmlspark_tpu.parallel.mesh import DATA_AXIS as _DATA_AXIS
 from mmlspark_tpu.models.gbdt.binning import BinMapper
 from mmlspark_tpu.ops.histogram import NUM_BINS, hist_lowering as _hist_lowering
 from mmlspark_tpu.models.gbdt.booster import Booster, Tree, per_tree_raw
+from mmlspark_tpu.models.gbdt import treegrow
 from mmlspark_tpu.models.gbdt.treegrow import grow_tree
 
 log = logging.getLogger("mmlspark_tpu.gbdt")
@@ -84,10 +85,11 @@ _M_FUSED_CHUNKS = obs.counter(
 )
 _M_HIST_ROWS = obs.counter(
     "mmlspark_gbdt_hist_rows_total",
-    "Rows handed to the masked grower's histogram calls (kind=streamed) and "
-    "rows their masks selected (kind=selected): the useful share of a "
-    "pass. With row weights (sample weights, bagging, GOSS) selected is "
-    "the weighted count, rounded per call. Added when a tree's record "
+    "Rows handed to the leaf-wise growers' histogram calls (kind=streamed) "
+    "and the rows of them that counted (kind=selected): the useful share "
+    "of a pass. Masked grower: all rows a call, and what its mask selected "
+    "(with row weights the weighted count, rounded per call); partitioned "
+    "grower: a call's bucket, and the smaller child's rows. Added when a tree's record "
     "reaches the host; dart's per-tree fetch does not carry it",
     labels=("kind",),
 )
@@ -1143,31 +1145,22 @@ def train(
             return shard_batch(a)
         return jnp.asarray(a)
 
-    # data-partitioned leaf-wise growth (LightGBM's DataPartition +
-    # histogram subtraction, treegrow._grow_tree_partitioned): single-device
-    # layouts only — the per-split global row permutation would become
-    # cross-device traffic on a sharded mesh, where the masked scatter +
-    # GSPMD allreduce path is the right cost model
-    import os as _os
-
-    # default OFF on every backend: measured on TPU v5e (ROADMAP.md "Open
-    # items" table; 100k x 32, 50 iters, 63 leaves) the partitioned
-    # grower runs 9.15 s vs the masked grower's 3.0 s — the MXU one-hot
-    # histogram amortizes the full pass so well that the per-split
-    # permutation gathers + bucketed re-histogram cost more than they
-    # save, inverting the CPU cost model the partition was designed
-    # around. Env forces either way (tests force on to cover the path).
-    _part_env = _os.environ.get("MMLSPARK_TPU_GBDT_PARTITION")
-    _part_default = False
+    # leaf-wise growth partitioned by leaf (LightGBM's DataPartition +
+    # histogram subtraction, treegrow._grow_tree_partitioned) where
+    # treegrow.lossguide_grower says so: one device with the Pallas
+    # lowering. Decided here, before the round program is traced, because
+    # the choice is part of that program's cache key. On one v5e at
+    # 2,625,000 x 28 and 255 leaves it grows a tree in 479 device-ms where
+    # the masked grower takes 4,733 (PERF.md section 6, PR 26); a sharded
+    # mesh keeps the masked grower, whose per-shard pass + plane psum has
+    # no global permutation in it.
     partitioned = (
         cfg.growth_policy == "lossguide"
         and not multihost
         and not use_voting
-        and (mesh is None or mesh.devices.size == 1)
-        and (
-            _part_env not in ("0", "false") if _part_env is not None
-            else _part_default
-        )
+        and treegrow.lossguide_grower(
+            mesh, _DATA_AXIS if mesh is not None else None
+        ) == "partitioned"
     )
     # rows sharded over the mesh data axis: hand the mesh to the growers so
     # the histogram op can run its Pallas kernel per shard + psum the planes

@@ -48,8 +48,10 @@ class GrownTree(NamedTuple):
     rec_is_cat: jnp.ndarray    # (L-1,) bool: categorical subset split
     rec_catmask: jnp.ndarray   # (L-1, B) bool: bins going LEFT (cat splits)
     # (4,) int32, or None from a grower that does not count: the rows its
-    # histogram calls were handed and the rows their masks selected, each
-    # as (// 4096, % 4096) so an f32 record carries them exactly
+    # histogram calls were handed and the rows of them that counted (the
+    # masked grower: what the masks selected; the partitioned one: the
+    # smaller children), each as (// 4096, % 4096) so an f32 record carries
+    # them exactly
     hist_rows: Optional[jnp.ndarray] = None
 
 
@@ -147,6 +149,34 @@ def make_leaf_best(
     return leaf_best
 
 
+def lossguide_grower(mesh: Any = None, shard_axis: Optional[str] = None) -> str:
+    """Which leaf-wise grower a layout gets — the one rule, read from what
+    the code can see (the mesh and the histogram lowering of its device):
+
+    - ``"masked"`` (:func:`_grow_tree`) where the rows are sharded over the
+      mesh: a split is one masked pass per shard plus a plane ``psum``; the
+      partitioned grower's global row permutation would cross chips;
+    - ``"partitioned"`` (:func:`_grow_tree_partitioned`) on one device with
+      the Pallas lowering, i.e. one TPU chip: a split costs its leaf's
+      rows. ``higgs_gbdt_fit`` on one v5e (PERF.md section 6, builder's
+      chip runs, PR 26; 2,625,000 x 28, 255 leaves): 479 device-ms a
+      tree and 1.147 trees/s, against the masked grower's 4,733 and
+      0.195;
+    - ``"hostcall"`` (:func:`_grow_tree_lossguide_hostcall`) on one CPU
+      device: the whole tree behind one host callback;
+    - ``"masked"`` for what is left (one device, XLA scatter lowering).
+
+    Tests force a grower by replacing this function."""
+    from mmlspark_tpu.ops.histogram import _rows_sharded, hist_lowering
+
+    if _rows_sharded(mesh, shard_axis):
+        return "masked"
+    lowering = hist_lowering(mesh)
+    if lowering == "pallas" and (mesh is None or mesh.devices.size == 1):
+        return "partitioned"
+    return "hostcall" if lowering == "cpu" else "masked"
+
+
 def grow_tree(
     bins: jnp.ndarray,            # (n, d) uint8/int32
     grad: jnp.ndarray,            # (n,) f32
@@ -163,7 +193,7 @@ def grow_tree(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    partitioned: bool = False,
+    partitioned: Optional[bool] = None,
     mesh: Any = None,
     shard_axis: Optional[str] = None,
 ) -> GrownTree:
@@ -176,11 +206,10 @@ def grow_tree(
     hessian mass is below it (LightGBM lambda_l1 /
     min_sum_hessian_in_leaf semantics).
 
-    ``partitioned=True`` selects the data-partitioned grower
-    (:func:`_grow_tree_partitioned`): rows kept physically grouped by leaf
-    so each split histograms only the smaller child's contiguous range —
-    LightGBM's DataPartition + sibling-subtraction design. Single-device
-    layouts only (the global row permutation would thrash a sharded mesh)."""
+    :func:`lossguide_grower` picks the grower from the layout.
+    ``partitioned`` is what the trainer decided with it before it traced
+    its round program (True: :func:`_grow_tree_partitioned`; False: any
+    other); None asks the rule here."""
     has_categorical = categorical_mask is not None
     if not has_categorical:
         categorical_mask = jnp.zeros((bins.shape[1],), bool)
@@ -188,18 +217,15 @@ def grow_tree(
     # cache key — thread it as a static arg so flipping
     # MMLSPARK_TPU_HIST_HOST / MMLSPARK_TPU_PALLAS between calls with
     # identical shapes can never reuse a stale-lowering program
-    from mmlspark_tpu.ops.histogram import (
-        _rows_sharded,
-        hist_lowering,
-        use_host_hist,
-    )
+    from mmlspark_tpu.ops.histogram import hist_lowering
 
     hm = hist_lowering(mesh)
-    if (
-        use_host_hist(mesh)
-        and not partitioned
-        and not _rows_sharded(mesh, shard_axis)
-    ):
+    grower = lossguide_grower(mesh, shard_axis)
+    if partitioned:
+        grower = "partitioned"
+    elif partitioned is not None and grower == "partitioned":
+        grower = "masked"
+    if grower == "hostcall":
         # CPU lowering: the whole leaf-wise tree behind ONE host callback
         # (see _grow_tree_depthwise_hostcall for the cost argument)
         return _grow_tree_lossguide_hostcall(
@@ -211,7 +237,7 @@ def grow_tree(
             feature_mask=feature_mask, categorical_mask=categorical_mask,
             has_categorical=has_categorical,
         )
-    if partitioned:
+    if grower == "partitioned":
         return _grow_tree_partitioned(
             bins, grad, hess, row_weight,
             num_leaves=num_leaves, lambda_l2=lambda_l2, min_gain=min_gain,
@@ -219,7 +245,7 @@ def grow_tree(
             max_depth=max_depth, min_data_in_leaf=min_data_in_leaf,
             categorical_mask=categorical_mask, has_categorical=has_categorical,
             lambda_l1=lambda_l1, min_sum_hessian=min_sum_hessian,
-            num_bins=num_bins, hist_mode=hm,
+            num_bins=num_bins, mesh=mesh, hist_mode=hm,
         )
     return _grow_tree(
         bins, grad, hess, row_weight,
@@ -444,8 +470,8 @@ def _grow_tree(
 
 
 def _range_sizes(n: int, min_size: int = 512) -> tuple:
-    """Static power-of-2 row-bucket sizes for the range histogram: the
-    smallest bucket covering a child's row count bounds overshoot at 2x."""
+    """Static power-of-2 row-bucket sizes for the partitioned grower: the
+    smallest bucket covering a range's row count bounds overshoot at 2x."""
     sizes = []
     s = min(min_size, n)
     while s < n:
@@ -459,7 +485,7 @@ def _range_sizes(n: int, min_size: int = 512) -> tuple:
     jax.jit,
     static_argnames=(
         "num_leaves", "max_depth", "min_data_in_leaf", "has_categorical",
-        "num_bins", "hist_mode",
+        "num_bins", "mesh", "hist_mode",
     ),
 )
 def _grow_tree_partitioned(
@@ -479,178 +505,262 @@ def _grow_tree_partitioned(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
+    mesh: Any = None,
     hist_mode: str = "",
 ) -> GrownTree:
-    """Leaf-wise growth over data kept PARTITIONED by leaf — the TPU
+    """Leaf-wise growth over rows kept PARTITIONED by leaf — the TPU
     expression of LightGBM's DataPartition + histogram-subtraction core
     (the reason native LightGBM's per-split cost is O(leaf rows), not
     O(dataset rows); TrainUtils.scala:220-315 drives that C++ engine).
 
     Identical split semantics to :func:`_grow_tree` (same ``make_leaf_best``,
-    same records); only the histogram COST model changes:
+    same records); only the COST model changes: a split does device work
+    proportional to the rows of the leaf it splits, never to n.
 
-    - rows live in a permuted layout (``order``) where every leaf owns a
-      contiguous [start, start+count) range; each split stable-partitions
-      the parent's range in O(n) elementwise work;
-    - the new histogram pass covers ONLY the smaller child's range, sliced
-      to the smallest static power-of-2 bucket (``lax.switch`` keeps every
-      shape static for XLA) — the larger sibling is parent - smaller
-      (LightGBM's subtraction trick);
-    - per tree the histogram work sums to O(n * avg_depth) cells instead
-      of the masked full-pass grower's O(n * num_leaves).
+    - the loop carries ``rows``, (1 + ceil(d/4), n) int32 with the
+      128-lane dimension on the positions: a position's row id and its
+      uint8 bins packed four to a word. Every leaf owns a contiguous
+      [start, start+count) range of positions. The stats stay in row
+      order and are fetched through the row ids;
+    - a split takes the smallest static power-of-2 bucket that covers the
+      PARENT's range, slices it out of ``rows``, partitions it stably
+      (left block, right block; positions of the bucket outside the
+      range stay put) with ONE sort of all its rows on a key that is the
+      side first and the position second, and writes it back with
+      ``dynamic_update_slice``. On the v5e a sort moves a row's nine
+      words for 3-6 ns, where fetching one word of it through a
+      permutation costs 7-15 ns (PERF.md section 6, PR 26);
+    - the new histogram pass covers ONLY the smaller child's bucket, a
+      slice — the larger sibling is parent - smaller (LightGBM's
+      subtraction trick). The root is the one full pass of a tree;
+    - every shape is static, one branch a bucket size. The histogram,
+      which only reads ``rows``, picks its branch with a ``lax.switch``.
+      The partition, which hands ``rows`` on, runs every bucket's branch
+      as a loop of one turn or none: XLA:TPU updates a loop's carry in
+      place and copies all of what a ``switch`` returns, twice a split
+      (84 MB each at Higgs' size). The reverse holds for what a branch
+      only reads: as loops, the histogram's branches cost a copy of
+      ``rows`` each, 20x the whole tree (PERF.md section 6, PR 26). A
+      step that makes no split (a finished tree) runs no branch: no
+      bucket, no kernel call.
 
-    Single-device layouts only: the per-split global permutation gathers
-    would become cross-device traffic under a sharded mesh (the caller
-    gates on mesh size; sharded meshes keep :func:`_grow_tree`, whose
-    scatter lowering GSPMD partitions + allreduces)."""
-    from mmlspark_tpu.ops.histogram import plane_histogram
+    One-device layouts only: the partition is a global permutation, which
+    would become cross-device traffic under a sharded mesh
+    (:func:`lossguide_grower` keeps those on :func:`_grow_tree`). ``mesh``
+    only names the device the kernels are lowered for."""
+    del hist_mode  # jit cache key only (see grow_tree)
+    from mmlspark_tpu.ops.histogram import leaf_stat_sums, plane_histogram
 
     n, d = bins.shape
     L = num_leaves
     B = num_bins
-    bins = bins.astype(jnp.int32)
+    if B > 256:
+        raise ValueError(f"bins are packed as bytes: num_bins {B} > 256")
     cat_f = categorical_mask.astype(bool)
     lam = lambda_l2
     l1 = lambda_l1
     msh = min_sum_hessian
     g = grad * row_weight
     h = hess * row_weight
-    cnt_w = row_weight
-    row_stats = jnp.stack([g, h, cnt_w], axis=-1)  # (n, 3) original order
+    row_stats = jnp.stack([g, h, row_weight], axis=-1)  # (n, 3), row order
     sizes = _range_sizes(n)
     sizes_arr = jnp.asarray(sizes, jnp.int32)
+    branch_rows = jnp.asarray((0,) + sizes, jnp.int32)  # by bucket_branch
+    W = -(-d // 4)  # words of four bins
 
     leaf_best = make_leaf_best(
         d, feature_mask, min_data_in_leaf, msh, lam, l1, cat_f,
         has_categorical, num_bins=B,
     )
 
+    def bucket_branch(count: jnp.ndarray, live: jnp.ndarray) -> jnp.ndarray:
+        """1 + the index in ``sizes`` of the smallest bucket covering
+        ``count`` rows; 0, no bucket, for a step that splits nothing."""
+        covering = 1 + jnp.sum(count > sizes_arr).astype(jnp.int32)
+        return jnp.where(live, covering, 0)
+
+    def bucket_of(rows: jnp.ndarray, start: jnp.ndarray, sz: int) -> tuple:
+        """The ``sz`` positions from (about) ``start`` on: their slice of
+        ``rows``, where the bucket begins, each position's offset in it
+        and the offset at which the range starts."""
+        st = jnp.clip(start, 0, n - sz)
+        part = jax.lax.dynamic_slice_in_dim(rows, st, sz, 1)
+        return part, st, jnp.arange(sz, dtype=jnp.int32), start - st
+
     def step(k: int, state: tuple) -> tuple:
-        (hist, order, bins_ord, stats_ord, leaf_start, leaf_count,
-         leaf_depth, done,
+        (hist, rows, leaf_start, leaf_count, leaf_depth, done,
          cache_gain, cache_feat, cache_bin, cache_catmask, prev_pair,
          rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-         rec_is_cat, rec_catmask) = state
+         rec_is_cat, rec_catmask, hist_hi, hist_lo) = state
 
-        # refresh the two planes the previous split changed (all other
-        # leaves' cached best splits are still exact)
-        pg, pf, pb, pcm = jax.vmap(leaf_best)(hist[prev_pair])
-        cache_gain = cache_gain.at[prev_pair].set(pg)
-        cache_feat = cache_feat.at[prev_pair].set(pf)
-        cache_bin = cache_bin.at[prev_pair].set(pb)
-        cache_catmask = cache_catmask.at[prev_pair].set(pcm)
+        with jax.named_scope("gbdt.best_split"):
+            # refresh the two planes the previous split changed (all other
+            # leaves' cached best splits are still exact)
+            pg, pf, pb, pcm = jax.vmap(leaf_best)(hist[prev_pair])
+            cache_gain = cache_gain.at[prev_pair].set(pg)
+            cache_feat = cache_feat.at[prev_pair].set(pf)
+            cache_bin = cache_bin.at[prev_pair].set(pb)
+            cache_catmask = cache_catmask.at[prev_pair].set(pcm)
 
-        num_active = k + 1
-        leaf_ids = jnp.arange(L, dtype=jnp.int32)
-        leaf_ok = leaf_ids < num_active
-        if max_depth > 0:
-            leaf_ok = leaf_ok & (leaf_depth < max_depth)
-        sel = jnp.where(leaf_ok, cache_gain, -jnp.inf)
-        bl = jnp.argmax(sel).astype(jnp.int32)
-        best_gain = sel[bl]
-        bf = cache_feat[bl]
-        bb = cache_bin[bl]
-        catmask = cache_catmask[bl]
-        do_split = (~done) & (best_gain > min_gain) & jnp.isfinite(best_gain)
-        new_id = jnp.int32(k + 1)
+            num_active = k + 1
+            leaf_ids = jnp.arange(L, dtype=jnp.int32)
+            leaf_ok = leaf_ids < num_active
+            if max_depth > 0:
+                leaf_ok = leaf_ok & (leaf_depth < max_depth)
+            sel = jnp.where(leaf_ok, cache_gain, -jnp.inf)
+            bl = jnp.argmax(sel).astype(jnp.int32)
+            best_gain = sel[bl]
+            bf = cache_feat[bl]
+            bb = cache_bin[bl]
+            catmask = cache_catmask[bl]
+            do_split = (
+                (~done) & (best_gain > min_gain) & jnp.isfinite(best_gain)
+            )
+            is_cat_split = cat_f[bf] if has_categorical else jnp.asarray(False)
+            new_id = jnp.int32(k + 1)
+            s = leaf_start[bl]
+            c = leaf_count[bl]
 
-        s = leaf_start[bl]
-        c = leaf_count[bl]
-        pos = jnp.arange(n, dtype=jnp.int32)
-        in_range = (pos >= s) & (pos < s + c)
-        row_bins = bins_ord[:, bf]
-        if has_categorical:
-            is_cat_split = cat_f[bf]
-            decide = jnp.where(is_cat_split, ~catmask[row_bins], row_bins > bb)
-        else:
-            is_cat_split = jnp.asarray(False)
-            decide = row_bins > bb
-        right_m = in_range & decide & do_split
-        left_m = in_range & ~right_m & do_split
-        c_right = right_m.sum().astype(jnp.int32)
-        c_left = c - c_right
-
-        # stable partition of the parent's range: left block then right
-        # block; everything outside the range (and no-op steps) stays put
-        destL = s + jnp.cumsum(left_m.astype(jnp.int32)) - 1
-        destR = s + c_left + jnp.cumsum(right_m.astype(jnp.int32)) - 1
-        dest = jnp.where(left_m, destL, jnp.where(right_m, destR, pos))
-        inv = jnp.zeros((n,), jnp.int32).at[dest].set(pos)
-        order = jnp.take(order, inv)
-        bins_ord = jnp.take(bins_ord, inv, axis=0)
-        stats_ord = jnp.take(stats_ord, inv, axis=0)
-
-        # smaller child's histogram from its (now contiguous) range; the
-        # switch picks the smallest static bucket covering the count
-        small_left = c_left <= c_right
-        s_small = jnp.where(small_left, s, s + c_left)
-        c_small = jnp.where(do_split, jnp.minimum(c_left, c_right), 0)
-
-        def mk(sz: int):
-            def f(_arg: None) -> jnp.ndarray:
-                st = jnp.clip(s_small, 0, n - sz)
-                bsl = jax.lax.dynamic_slice_in_dim(bins_ord, st, sz, 0)
-                ssl = jax.lax.dynamic_slice_in_dim(stats_ord, st, sz, 0)
-                p = st + jnp.arange(sz, dtype=jnp.int32)
-                m = ((p >= s_small) & (p < s_small + c_small)).astype(
-                    jnp.float32
+        def partition(sz: int):
+            def f(_: int, carry: tuple) -> tuple:
+                rows, _ = carry
+                part, st, local, first = bucket_of(rows, s, sz)
+                word = jax.lax.dynamic_index_in_dim(part, 1 + bf // 4, 0, False)
+                col = (word >> (8 * (bf % 4))) & 255
+                if has_categorical:
+                    right = jnp.where(is_cat_split, ~catmask[col], col > bb)
+                else:
+                    right = col > bb
+                # side first, position second: before the range, its left
+                # rows, its right rows, after it. Sorting on it IS the
+                # stable partition, and no two keys are equal
+                side = jnp.where(
+                    local < first, 0,
+                    jnp.where(local >= first + c, 3, jnp.where(right, 2, 1)),
                 )
-                return plane_histogram(bsl, ssl, m, num_bins=B, bins_in_range=True)
+                moved = jax.lax.sort(
+                    (side * sz + local,) + tuple(part), num_keys=1
+                )[1:]
+                return (
+                    jax.lax.dynamic_update_slice_in_dim(
+                        rows, jnp.stack(moved), st, 1
+                    ),
+                    jnp.sum(side == 1).astype(jnp.int32),
+                )
             return f
 
-        idx = jnp.sum(c_small > sizes_arr).astype(jnp.int32)
-        small_plane = jax.lax.switch(idx, [mk(sz) for sz in sizes], None)
-        parent_plane = hist[bl]
-        big_plane = parent_plane - small_plane
-        left_plane = jnp.where(small_left, small_plane, big_plane)
-        right_plane = jnp.where(small_left, big_plane, small_plane)
-        hist = hist.at[bl].set(
-            jnp.where(do_split, left_plane, parent_plane)
-        ).at[new_id].set(
-            jnp.where(do_split, right_plane, hist[new_id])
-        )
+        with jax.named_scope("gbdt.partition"):
+            # the bucket's branch as a loop of one turn, every other
+            # bucket's as a loop of none (see the docstring)
+            part_branch = bucket_branch(c, do_split)
+            c_left = jnp.int32(0)
+            for i, sz in enumerate(sizes):
+                rows, c_left = jax.lax.fori_loop(
+                    0, (part_branch == i + 1).astype(jnp.int32),
+                    partition(sz), (rows, c_left),
+                )
+        c_right = c - c_left
+        small_left = c_left <= c_right
+        s_small = jnp.where(small_left, s, s + c_left)
+        c_small = jnp.minimum(c_left, c_right)
 
-        leaf_start = jnp.where(
-            do_split, leaf_start.at[new_id].set(s + c_left), leaf_start
+        def smaller_child(sz: int):
+            def f(rows: jnp.ndarray) -> jnp.ndarray:
+                part, _, local, first = bucket_of(rows, s_small, sz)
+                # or XLA:TPU lays ALL of ``rows`` out anew for the
+                # transposition below, in every branch
+                part = jax.lax.optimization_barrier(part)
+                with jax.named_scope("gbdt.partition"):
+                    ssl = jnp.take(row_stats, part[0], axis=0)  # (sz, 3)
+                with jax.named_scope("gbdt.hist.widen"):
+                    packed = part[1:]
+                    bsl = jnp.stack(
+                        [(packed >> (8 * j)) & 255 for j in range(4)], axis=1
+                    ).reshape(4 * W, sz)[:d].T
+                m = ((local >= first) & (local < first + c_small)).astype(
+                    jnp.float32
+                )
+                return plane_histogram(
+                    bsl, ssl, m, num_bins=B, mesh=mesh, bins_in_range=True
+                )
+            return f
+
+        # smaller child's histogram from its (now contiguous) range
+        hist_branch = bucket_branch(c_small, do_split)
+        small_plane = jax.lax.switch(
+            hist_branch,
+            [lambda rows: jnp.zeros((d * B, 3), jnp.float32)]
+            + [smaller_child(sz) for sz in sizes],
+            rows,
         )
-        leaf_count = jnp.where(
-            do_split,
-            leaf_count.at[bl].set(c_left).at[new_id].set(c_right),
-            leaf_count,
-        )
-        child_depth = leaf_depth[bl] + 1
-        leaf_depth = jnp.where(
-            do_split,
-            leaf_depth.at[bl].set(child_depth).at[new_id].set(child_depth),
-            leaf_depth,
-        )
-        rec_leaf = rec_leaf.at[k].set(jnp.where(do_split, bl, -1))
-        rec_feature = rec_feature.at[k].set(jnp.where(do_split, bf, -1))
-        rec_bin = rec_bin.at[k].set(jnp.where(do_split, bb, -1))
-        rec_active = rec_active.at[k].set(do_split)
-        rec_gain = rec_gain.at[k].set(jnp.where(do_split, best_gain, 0.0))
-        rec_is_cat = rec_is_cat.at[k].set(do_split & is_cat_split)
-        rec_catmask = rec_catmask.at[k].set(
-            jnp.where(do_split & is_cat_split, catmask, False)
-        )
-        done = done | ~do_split
-        prev_pair = jnp.stack([bl, new_id])
-        return (hist, order, bins_ord, stats_ord, leaf_start, leaf_count,
-                leaf_depth, done,
+        with jax.named_scope("gbdt.apply_split"):
+            # the rows this step's histogram call was handed, and those
+            # of them in the smaller child
+            counted = jnp.stack(
+                [branch_rows[hist_branch], jnp.where(do_split, c_small, 0)]
+            )
+            hist_hi = hist_hi + counted // 4096
+            hist_lo = hist_lo + counted % 4096
+            parent_plane = hist[bl]
+            big_plane = parent_plane - small_plane
+            left_plane = jnp.where(small_left, small_plane, big_plane)
+            right_plane = jnp.where(small_left, big_plane, small_plane)
+            hist = hist.at[bl].set(
+                jnp.where(do_split, left_plane, parent_plane)
+            ).at[new_id].set(
+                jnp.where(do_split, right_plane, hist[new_id])
+            )
+            leaf_start = jnp.where(
+                do_split, leaf_start.at[new_id].set(s + c_left), leaf_start
+            )
+            leaf_count = jnp.where(
+                do_split,
+                leaf_count.at[bl].set(c_left).at[new_id].set(c_right),
+                leaf_count,
+            )
+            child_depth = leaf_depth[bl] + 1
+            leaf_depth = jnp.where(
+                do_split,
+                leaf_depth.at[bl].set(child_depth).at[new_id].set(child_depth),
+                leaf_depth,
+            )
+            rec_leaf = rec_leaf.at[k].set(jnp.where(do_split, bl, -1))
+            rec_feature = rec_feature.at[k].set(jnp.where(do_split, bf, -1))
+            rec_bin = rec_bin.at[k].set(jnp.where(do_split, bb, -1))
+            rec_active = rec_active.at[k].set(do_split)
+            rec_gain = rec_gain.at[k].set(
+                jnp.where(do_split, best_gain, 0.0)
+            )
+            rec_is_cat = rec_is_cat.at[k].set(do_split & is_cat_split)
+            rec_catmask = rec_catmask.at[k].set(
+                jnp.where(do_split & is_cat_split, catmask, False)
+            )
+            done = done | ~do_split
+            prev_pair = jnp.stack([bl, new_id])
+        return (hist, rows, leaf_start, leaf_count, leaf_depth, done,
                 cache_gain, cache_feat, cache_bin, cache_catmask, prev_pair,
                 rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-                rec_is_cat, rec_catmask)
+                rec_is_cat, rec_catmask, hist_hi, hist_lo)
 
+    # root histogram: the one full pass of a tree
     hist0 = (
         jnp.zeros((L, d * B, 3), jnp.float32)
         .at[0]
-        .set(plane_histogram(bins, row_stats, num_bins=B, bins_in_range=True))
+        .set(plane_histogram(
+            bins, row_stats, num_bins=B, mesh=mesh, bins_in_range=True
+        ))
     )
+    with jax.named_scope("gbdt.partition"):
+        # once a tree: the rows as the loop moves them (bins of value
+        # < 256 by the caller's word, four to an int32)
+        quads = jnp.pad(bins.astype(jnp.int32), ((0, 0), (0, 4 * W - d)))
+        quads = quads.reshape(n, W, 4)
+        words = (quads[..., 0] | (quads[..., 1] << 8)
+                 | (quads[..., 2] << 16) | (quads[..., 3] << 24)).T
+        rows0 = jnp.concatenate([jnp.arange(n, dtype=jnp.int32)[None], words])
     init = (
         hist0,
-        jnp.arange(n, dtype=jnp.int32),          # order: position -> row id
-        bins,                                     # bins_ord (starts unpermuted)
-        row_stats,                                # stats_ord
+        rows0,                                    # row ids and packed bins, by position
         jnp.zeros((L,), jnp.int32),               # leaf_start
         jnp.zeros((L,), jnp.int32).at[0].set(n),  # leaf_count
         jnp.zeros((L,), jnp.int32),               # leaf_depth
@@ -667,32 +777,39 @@ def _grow_tree_partitioned(
         jnp.zeros((L - 1,), jnp.float32),
         jnp.zeros((L - 1,), bool),
         jnp.zeros((L - 1, B), bool),
+        jnp.asarray([n // 4096, n // 4096], jnp.int32),  # hist_hi: handed, picked
+        jnp.asarray([n % 4096, n % 4096], jnp.int32),    # hist_lo
     )
-    (_, order, _, _, leaf_start, leaf_count, _, _,
+    (_, rows, leaf_start, leaf_count, _, _,
      _, _, _, _, _,
      rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
-     rec_is_cat, rec_catmask) = jax.lax.fori_loop(0, L - 1, step, init)
-
-    # position -> leaf from the final ranges (ranges tile [0, n) exactly:
-    # each position lies in exactly one active leaf), then back to the
-    # original row order through the permutation
-    pos = jnp.arange(n, dtype=jnp.int32)[:, None]
-    in_leaf = (pos >= leaf_start[None, :]) & (
-        pos < (leaf_start + leaf_count)[None, :]
+     rec_is_cat, rec_catmask, hist_hi, hist_lo) = (
+        jax.lax.fori_loop(0, L - 1, step, init)
     )
-    row_leaf_ord = jnp.argmax(in_leaf, axis=1).astype(jnp.int32)
-    row_leaf = jnp.zeros((n,), jnp.int32).at[order].set(row_leaf_ord)
 
-    from mmlspark_tpu.ops.histogram import leaf_stat_sums
+    with jax.named_scope("gbdt.partition"):
+        # position -> leaf from the final ranges (they tile [0, n): each
+        # position lies in exactly one of them; an unused leaf's is empty),
+        # then back to row order through the row ids: the one n-long
+        # scatter of a tree
+        pos = jnp.arange(n, dtype=jnp.int32)[:, None]
+        in_leaf = (pos >= leaf_start[None, :]) & (
+            pos < (leaf_start + leaf_count)[None, :]
+        )
+        leaf_of_pos = jnp.argmax(in_leaf, axis=1).astype(jnp.int32)
+        row_leaf = jnp.zeros((n,), jnp.int32).at[rows[0]].set(
+            leaf_of_pos, unique_indices=True
+        )
 
     sums = leaf_stat_sums(row_leaf, row_stats, L)
     Gl, Hl, Cl = sums[:, 0], sums[:, 1], sums[:, 2]
     leaf_values = -threshold_l1(Gl, lambda_l1) / (Hl + lambda_l2) * learning_rate
     leaf_values = jnp.where(Cl > 0, leaf_values, 0.0)
+    hist_rows = jnp.stack([hist_hi[0], hist_lo[0], hist_hi[1], hist_lo[1]])
     return GrownTree(
         rec_leaf, rec_feature, rec_bin, rec_active, rec_gain,
         leaf_values, Cl.astype(jnp.int32), row_leaf,
-        rec_is_cat, rec_catmask,
+        rec_is_cat, rec_catmask, hist_rows,
     )
 
 

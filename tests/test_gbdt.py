@@ -1005,6 +1005,24 @@ class TestDepthwise:
         assert np.mean(np.abs(ps - pp)) < 0.01
 
 
+def _force_lossguide_grower(monkeypatch, name):
+    """Make ``treegrow.lossguide_grower`` — the one rule that chooses the
+    leaf-wise grower — answer ``name`` whatever the layout."""
+    from mmlspark_tpu.models.gbdt import treegrow
+
+    monkeypatch.setattr(
+        treegrow, "lossguide_grower", lambda mesh=None, shard_axis=None: name
+    )
+
+
+def _hist_rows_streamed():
+    from mmlspark_tpu import obs
+
+    fam = obs.REGISTRY.snapshot().get("mmlspark_gbdt_hist_rows_total") or {}
+    return sum(v for labels, v in fam.get("samples", [])
+               if labels.get("kind") == "streamed")
+
+
 class TestPartitionedGrower:
     """The data-partitioned leaf-wise grower (treegrow._grow_tree_partitioned
     — LightGBM's DataPartition + sibling subtraction, TrainUtils.scala's
@@ -1082,6 +1100,232 @@ class TestPartitionedGrower:
             np.asarray(a.leaf_values), np.asarray(b.leaf_values), atol=1e-5
         )
 
+    @staticmethod
+    def _signal_rows(n, d, seed, bins_hi=200):
+        """Rows whose gradients follow their bins, so that no split is a
+        near-tie the two growers' summation orders could break apart."""
+        rng = np.random.default_rng(seed)
+        bins = rng.integers(0, bins_hi, size=(n, d)).astype(np.int32)
+        z = (bins[:, 0] / bins_hi - 0.5) + np.sin(bins[:, 1] / 17.0) \
+            + 0.5 * (bins[:, 2] > bins_hi * 0.6) * (bins[:, 3] / bins_hi)
+        g = (z + 0.05 * rng.normal(size=n)).astype(np.float32)
+        h = (np.abs(rng.normal(size=n)) + 0.5).astype(np.float32)
+        return bins, g, h
+
+    @staticmethod
+    def _children_sizes(t):
+        """(left, right) rows of every split made, from the final
+        ``row_leaf`` alone: split k moved its right child's rows out of
+        leaf ``rec_leaf[k]`` into the new leaf k+1, and every later split
+        of either child stays inside its subtree."""
+        L = len(np.asarray(t.rec_leaf)) + 1
+        size = np.bincount(np.asarray(t.row_leaf), minlength=L).astype(np.int64)
+        out = []
+        for k in reversed(range(L - 1)):
+            if np.asarray(t.rec_active)[k]:
+                parent = int(np.asarray(t.rec_leaf)[k])
+                out.append((int(size[parent]), int(size[k + 1])))
+                size[parent] += size[k + 1]
+        return out[::-1]
+
+    def test_tree_identical_across_buckets_and_below_the_smallest(self):
+        from mmlspark_tpu.models.gbdt.treegrow import _range_sizes
+
+        n = 9001   # buckets 512 .. 8192 and 9001: the deep leaves cross most
+        assert _range_sizes(n) == (512, 1024, 2048, 4096, 8192, 9001)
+        bins, g, h = self._signal_rows(n, 6, seed=21)
+        w = np.ones(n, np.float32)
+        a, b = self._grown_pair(bins, g, h, w, num_leaves=63,
+                                min_data_in_leaf=3)
+        for f in ("rec_leaf", "rec_feature", "rec_bin", "rec_active",
+                  "rec_is_cat", "row_leaf", "leaf_counts"):
+            assert np.array_equal(
+                np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
+            ), f
+        assert np.allclose(np.asarray(a.rec_gain), np.asarray(b.rec_gain),
+                           rtol=1e-3, atol=1e-4)
+        assert np.allclose(np.asarray(a.leaf_values),
+                           np.asarray(b.leaf_values), atol=1e-5)
+        assert int(np.asarray(b.rec_active).sum()) == 62
+        sizes = self._children_sizes(b)
+        parents = [l + r for l, r in sizes]
+        # parents in every bucket, and children under the smallest one
+        for lo, hi in ((8192, 9001), (4096, 8192), (2048, 4096),
+                       (1024, 2048), (512, 1024), (0, 512)):
+            assert any(lo < c <= hi for c in parents), (lo, hi)
+        assert min(min(l, r) for l, r in sizes) < 64
+
+    def test_hist_rows_counts_the_buckets_handed_and_the_smaller_children(self):
+        n = 9001
+        bins, g, h = self._signal_rows(n, 6, seed=22)
+        # rows of weight 0 are partitioned and counted like any other
+        w = (np.random.default_rng(1).random(n) > 0.2).astype(np.float32)
+        for leaves, min_gain in ((40, 0.0), (63, 50.0)):   # one tree ends early
+            a, b = self._grown_pair(bins, g, h, w, num_leaves=leaves,
+                                    min_data_in_leaf=3, min_gain=min_gain)
+            r = np.asarray(b.hist_rows).astype(np.int64)
+            streamed, selected = r[0] * 4096 + r[1], r[2] * 4096 + r[3]
+            sizes = self._children_sizes(b)
+            assert len(sizes) == int(np.asarray(b.rec_active).sum())
+            if min_gain:
+                assert 0 < len(sizes) < leaves - 1
+            assert selected == n + sum(min(l, r) for l, r in sizes)
+            assert streamed <= n + sum(max(512, 2 * min(l, r)) for l, r in sizes)
+            assert selected <= streamed
+            # the masked grower's, for scale: every call streams all rows
+            m = np.asarray(a.hist_rows).astype(np.int64)
+            assert m[0] * 4096 + m[1] == n * leaves
+
+    def test_one_rule_chooses_the_grower_from_the_layout(self, monkeypatch):
+        import jax
+        from jax.sharding import Mesh
+
+        from mmlspark_tpu.models.gbdt import treegrow
+        from mmlspark_tpu.parallel.mesh import DATA_AXIS
+
+        for var in ("MMLSPARK_TPU_PALLAS", "MMLSPARK_TPU_HIST_HOST"):
+            monkeypatch.delenv(var, raising=False)
+        devs = np.array(jax.devices())
+        assert devs.size >= 2 and devs[0].platform == "cpu"
+        sharded = Mesh(devs.reshape(-1, 1), (DATA_AXIS, "model"))
+        one = Mesh(devs[:1].reshape(1, 1), (DATA_AXIS, "model"))
+        unsharded = Mesh(devs.reshape(1, -1), (DATA_AXIS, "model"))
+        rule = treegrow.lossguide_grower
+        # the CPU as it comes: the whole tree behind one host callback
+        assert rule() == "hostcall"
+        assert rule(one, DATA_AXIS) == "hostcall"
+        assert rule(sharded, DATA_AXIS) == "masked"
+        # the Pallas lowering, as on a TPU: partitioned on one device only
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "1")
+        assert rule() == "partitioned"
+        assert rule(one, DATA_AXIS) == "partitioned"
+        assert rule(sharded, DATA_AXIS) == "masked"
+        assert rule(unsharded, DATA_AXIS) == "masked"   # several devices
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "0")
+        monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
+        assert rule() == "masked"                        # XLA scatter
+
+    def test_training_takes_the_rules_grower(self, monkeypatch):
+        """The trainer asks the rule: on one device with the Pallas
+        lowering (here the interpreter) a fit streams buckets, not n rows
+        a split; the same fit sharded over the mesh streams n a split."""
+        rng = np.random.default_rng(6)
+        n, leaves, trees = 1600, 7, 2
+        x = rng.normal(size=(n, 5)).astype(np.float32)
+        y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float64)
+        cfg = TrainConfig(objective="binary", num_iterations=trees,
+                          num_leaves=leaves, min_data_in_leaf=5, seed=0,
+                          max_bin=15)
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "1")
+        s0 = _hist_rows_streamed()
+        b_one = train(x, y, cfg, shard=False)
+        s1 = _hist_rows_streamed()
+        b_mesh = train(x, y, cfg, shard=True)
+        s2 = _hist_rows_streamed()
+        assert s2 - s1 == trees * leaves * n
+        assert trees * n < s1 - s0 <= trees * (n + (leaves - 1) * 1024)
+        assert np.allclose(b_one.predict_raw(x), b_mesh.predict_raw(x), atol=1e-3)
+
+    @pytest.mark.parametrize("lowering", ["pallas", "scatter"])
+    def test_no_split_does_work_as_long_as_the_dataset(self, monkeypatch, lowering):
+        """The regression this grower exists to prevent, read off the
+        traced program: in the loop body every gather, scatter, cumulative
+        op, sort and kernel call sits in the branch of one bucket — a
+        branch of the histogram's ``switch``, or one of the partition's
+        loops of one turn or none — and is no longer than that bucket. (A
+        gather's length is that of its indices and its result: it fetches
+        a bucket's stats out of the n-long array, which never moves.)"""
+        import jax
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.models.gbdt import treegrow
+
+        if lowering == "pallas":
+            monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "1")
+        else:
+            monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "0")
+            monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
+        n, d, B, L = 5003, 4, 16, 8
+        sizes = treegrow._range_sizes(n)
+        bins = jnp.zeros((n, d), jnp.uint8)
+        v = jnp.ones((n,), jnp.float32)
+
+        def grow(b, g):
+            return treegrow.grow_tree(
+                b, g, v, v, num_leaves=L, lambda_l2=1.0, min_gain=0.0,
+                learning_rate=0.1, feature_mask=jnp.ones((d,), jnp.float32),
+                min_data_in_leaf=1, num_bins=B, partitioned=True,
+                categorical_mask=jnp.asarray([True, False, False, False]),
+            )
+
+        jaxpr = jax.make_jaxpr(grow)(bins, v).jaxpr
+        heavy = ("gather", "scatter", "cum", "sort", "pallas_call",
+                 "reduce_window", "argsort")
+
+        def subjaxprs(eqn):
+            for p in eqn.params.values():
+                for q in (p if isinstance(p, (list, tuple)) else [p]):
+                    inner = getattr(q, "jaxpr", q)
+                    if hasattr(inner, "eqns"):
+                        yield inner
+
+        def length(eqn):
+            """The longest dimension the op works through."""
+            if eqn.primitive.name == "gather":
+                arrays = [eqn.invars[1].aval, eqn.outvars[0].aval]
+            else:
+                arrays = [x.aval for x in list(eqn.invars) + list(eqn.outvars)]
+            return max([max(a.shape) for a in arrays
+                        if getattr(a, "shape", ())] or [0])
+
+        found = []
+        bucket_loops = []
+
+        def walk(jx, limit):
+            for eqn in jx.eqns:
+                name = eqn.primitive.name
+                if name == "cond" and len(eqn.params["branches"]) == len(sizes) + 1:
+                    for i, br in enumerate(eqn.params["branches"]):
+                        # branch 0 is the empty one; a kernel pads its
+                        # bucket to whole 512-row blocks
+                        walk(br.jaxpr, 0 if i == 0 else -(-sizes[i - 1] // 512) * 512)
+                    continue
+                if name == "while" and jx is loops[0]:
+                    # the partition's loops, one a bucket, smallest first
+                    bucket_loops.append(eqn)
+                    walk(eqn.params["body_jaxpr"].jaxpr, sizes[len(bucket_loops) - 1])
+                    continue
+                if any(name.startswith(hv) or name == hv for hv in heavy):
+                    found.append((name, length(eqn), limit))
+                for inner in subjaxprs(eqn):
+                    walk(inner, limit)
+
+        loops = []
+
+        def find_loops(jx):
+            for eqn in jx.eqns:
+                if eqn.primitive.name in ("while", "scan"):   # a fori_loop is either
+                    body = eqn.params.get("body_jaxpr", eqn.params.get("jaxpr"))
+                    loops.append(body.jaxpr)
+                else:
+                    for inner in subjaxprs(eqn):
+                        find_loops(inner)
+
+        find_loops(jaxpr)
+        assert len(loops) == 1
+        # outside every switch the loop body may hold none that reaches
+        # the smallest bucket (the planes and the records are shorter)
+        walk(loops[0], sizes[0] - 1)
+        assert len(bucket_loops) == len(sizes)
+        names = {name for name, _, _ in found}
+        assert {"gather", "sort"} <= names   # the fetch through order, the partition
+        if lowering == "pallas":
+            assert "pallas_call" in names
+        long = [f for f in found if f[1] > f[2]]
+        assert not long, long
+        in_buckets = [f for f in found if f[2] >= sizes[0]]
+        assert max(f[1] for f in in_buckets) >= n   # the root's split is n long
+
     def test_e2e_training_uses_partitioned_and_matches(self, monkeypatch):
         # compare partitioned-XLA against the masked-XLA
         # reference (the host lowering's f64 gains flip
@@ -1094,13 +1338,20 @@ class TestPartitionedGrower:
         y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float64)
         cfg = TrainConfig(objective="binary", num_iterations=5, num_leaves=15,
                           min_data_in_leaf=5, seed=0)
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "1")
+        s0 = _hist_rows_streamed()
+        _force_lossguide_grower(monkeypatch, "partitioned")
         b_part = train(x, y, cfg, shard=False)
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "0")
+        s1 = _hist_rows_streamed()
+        _force_lossguide_grower(monkeypatch, "masked")
         b_mask = train(x, y, cfg, shard=False)
+        s2 = _hist_rows_streamed()
         pa = sigmoid(b_part.predict_raw(x))
         pb = sigmoid(b_mask.predict_raw(x))
         assert np.mean(np.abs(pa - pb)) < 1e-3
+        # the two fits did run different growers: the masked one hands
+        # every histogram call all the rows, the partitioned one a bucket
+        assert s2 - s1 == 5 * 15 * 2000
+        assert 5 * 2000 < s1 - s0 < (s2 - s1) // 2
 
 
 class TestDeviceLambdaRank:
@@ -1205,9 +1456,9 @@ class TestPartitionedInteractions:
         x, y = self._xy()
         cfg = TrainConfig(objective="binary", num_iterations=6, num_leaves=15,
                           min_data_in_leaf=5, seed=0, boosting_type="goss")
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "1")
+        _force_lossguide_grower(monkeypatch, "partitioned")
         b_part = train(x, y, cfg, shard=False)
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "0")
+        _force_lossguide_grower(monkeypatch, "masked")
         b_mask = train(x, y, cfg, shard=False)
         pa = sigmoid(b_part.predict_raw(x))
         pb = sigmoid(b_mask.predict_raw(x))
@@ -1223,9 +1474,9 @@ class TestPartitionedInteractions:
         cfg = TrainConfig(objective="regression", num_iterations=6,
                           num_leaves=15, min_data_in_leaf=5, seed=0,
                           bagging_fraction=0.7, bagging_freq=1)
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "1")
+        _force_lossguide_grower(monkeypatch, "partitioned")
         b_part = train(x, yr, cfg, shard=False)
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "0")
+        _force_lossguide_grower(monkeypatch, "masked")
         b_mask = train(x, yr, cfg, shard=False)
         pa, pb = b_part.predict_raw(x), b_mask.predict_raw(x)
         assert np.mean(np.abs(pa - pb)) < 1e-3 * max(1.0, np.abs(pb).mean())
@@ -1240,7 +1491,7 @@ class TestPartitionedInteractions:
         rng = np.random.default_rng(11)
         x = rng.normal(size=(4000, 6)).astype(np.float32)
         y = x[:, 0] * 3.0 + rng.normal(size=4000) * (1.0 + np.abs(x[:, 1]))
-        monkeypatch.setenv("MMLSPARK_TPU_GBDT_PARTITION", "1")
+        _force_lossguide_grower(monkeypatch, "partitioned")
         cfg = TrainConfig(objective="quantile", alpha=0.8, num_iterations=40,
                           num_leaves=15, min_data_in_leaf=10, seed=0)
         b = train(x, y, cfg, shard=False)

@@ -106,3 +106,34 @@ def test_default_backend_stays_cpu_while_lowering_for_tpu(v5e):
     kw = H._pallas_call_kwargs(H._target_device(mesh))
     assert kw["interpret"] is False
     assert kw["compiler_params"].vmem_limit_bytes == 96 << 20
+
+
+def test_partitioned_grower_compiles_for_one_v5e_chip(v5e, monkeypatch):
+    """The one-chip leaf-wise grower as the chip gets it: uint8 bins, every
+    bucket of both switches with its own Mosaic kernel, the gathers through
+    ``order`` and the scatter that partitions a bucket (XLA:TPU refuses
+    what the interpreter lets through). One kernel for the root and one
+    per bucket."""
+    from mmlspark_tpu.models.gbdt import treegrow
+
+    # the leaf sums of an unsharded call ask the process's default device
+    # (the CPU here) for their lowering: keep the host callback out
+    monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
+    mesh = Mesh(np.array(v5e[:1]), ("data",))
+    assert treegrow.lossguide_grower(mesh, "data") == "partitioned"
+    assert treegrow.lossguide_grower(Mesh(np.array(v5e), ("data",)), "data") == "masked"
+    n, d = RAGGED, 28
+    sizes = treegrow._range_sizes(n)
+
+    def grow(b, g, h, w):
+        return treegrow.grow_tree(
+            b, g, h, w, num_leaves=15, lambda_l2=0.0, min_gain=0.0,
+            learning_rate=0.1, feature_mask=jnp.ones((d,), jnp.float32),
+            min_data_in_leaf=0, min_sum_hessian=100.0, mesh=mesh,
+            shard_axis="data",
+        )
+
+    rows = _spec((n,), jnp.float32, mesh)
+    text = _compiled_text(grow, _spec((n, d), jnp.uint8, mesh), rows, rows, rows)
+    assert text.count("tpu_custom_call") == 1 + len(sizes)
+    assert "all-reduce" not in text
