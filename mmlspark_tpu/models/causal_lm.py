@@ -1,0 +1,348 @@
+"""Causal language model as a corpus scorer — the LFM2-MoE family.
+
+A DataFrame column of token-id arrays of unequal length in, per row the
+log-probability of every next token out
+(``logprob[t] = log p(x[t+1] | x[0..t])``, ``t < L - 1``): perplexity
+filtering, per-token likelihood features for a downstream learner. The
+reference's deep-learning stage evaluates any network over DataFrame rows
+(cntk/CNTKModel.scala:86-138); this is the stage for the networks people
+score text with today.
+
+The model (``model_type: lfm2_moe``; docs/models.md has the equations):
+pre-norm residual layers, each a sequence mixer — a gated short convolution
+(``"conv"``) or grouped-query attention with per-head QK RMSNorm and RoPE
+(``"full_attention"``) — and a feed-forward network, dense in the leading
+``num_dense_layers`` layers and a sparse expert layer
+(:mod:`mmlspark_tpu.ops.moe`) in the rest; the head is the embedding
+transposed. Weights and activations bfloat16 with float32 accumulation;
+the residual stream, router scores, the norms' statistics, softmax and the
+head's log-sum-exp in float32 (every product reads bfloat16: the norm that
+feeds a sub-layer casts; a bfloat16 stream would round the whole sum at
+every add, and a rounding error in the stream is what flips a near-tie in
+a router). Causal attention is blockwise (a block of queries against the keys
+up to its end, never a whole ``L x L`` score matrix) and the head folds the
+vocabulary into a log-sum-exp a block of tokens at a time, never the whole
+``tokens x vocabulary`` logits.
+
+:class:`CausalLMScorer` sorts a partition's rows into the length buckets it
+was given, pads on the right and drives ``XLAModel.apply_batch`` once per
+bucket with that bucket's batch size. Right padding needs no mask inside a
+causal model: no real position sees a pad (attention and the convolution
+look back only; norms, FFNs and the router are per token). Padded positions
+are dropped from the output and counted. A batch travels as one int32 array
+``(rows, L + 1)`` — the ids and, as the trailing column, the row's length
+(:func:`mmlspark_tpu.models.sequence.pack_lengths`' convention) — and comes
+back as one float32 array ``(rows, L - 1 + E)``: the log-probabilities and
+the row's real tokens routed to each expert, summed over the expert layers.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from mmlspark_tpu import obs
+from mmlspark_tpu.core.dataframe import DataFrame, Partition
+from mmlspark_tpu.core.params import ComplexParam, HasInputCol, HasOutputCol
+from mmlspark_tpu.core.pipeline import Model
+from mmlspark_tpu.models.xla_model import XLAModel
+from mmlspark_tpu.ops import moe
+
+_M_TOKENS = obs.counter(
+    "mmlspark_lm_tokens_total",
+    "Token positions the language-model scorer sent to the device: kind=real "
+    "are the rows' own tokens, kind=padded the right padding of rows to "
+    "their bucket's length and of batches to their size",
+    labels=("kind",),
+)
+_M_ROUTED = obs.counter(
+    "mmlspark_moe_tokens_routed_total",
+    "Real tokens routed to each expert, summed over the expert layers; "
+    "carried out of the program with each batch's output",
+    labels=("expert",),
+)
+
+
+# queries per block of the blockwise attention (the largest score tensor is
+# batch x heads x Q_BLOCK x length in float32: 1.07 GB at 32,768 tokens a
+# batch) and tokens per block of the head's log-sum-exp (0.5 GB of logits)
+Q_BLOCK = 256
+HEAD_BLOCK = 2048
+
+
+def layer_kinds(config: dict) -> list:
+    """``[(mixer, ffn)]`` per layer: ("conv" | "full_attention", "dense" | "moe")."""
+    return [(config["layer_types"][i], "dense" if i < config["num_dense_layers"] else "moe")
+            for i in range(config["num_hidden_layers"])]
+
+
+# -- the layers ----------------------------------------------------------------
+
+def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float,
+            dtype: Any = jnp.bfloat16) -> jnp.ndarray:
+    """Statistics in float32, result in ``dtype`` (what the products read)."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(dtype)
+
+
+def _mm(eq: str, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """bfloat16 in, float32 accumulation, bfloat16 out."""
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32).astype(a.dtype)
+
+
+def conv_taps(kernel: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """Depthwise causal convolution along a row: ``c[t] = sum_j k[:, j] *
+    z[t - (taps - 1) + j]``, zero before the row's start. (B, L, h)."""
+    taps, length = kernel.shape[1], z.shape[1]
+    zp = jnp.pad(z.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    k32 = kernel.astype(jnp.float32)
+    return sum(k32[:, j] * zp[:, j:j + length] for j in range(taps))
+
+
+def conv_mixer(w: dict, u: jnp.ndarray) -> jnp.ndarray:
+    """Gated short convolution: ``W_out (C * conv(B * X))``. (B, L, h)."""
+    with jax.named_scope("lm.mixer.conv"):
+        b, c, x = jnp.split(_mm("blh,hk->blk", u, w["conv_in"]), 3, axis=-1)
+        z = b.astype(jnp.float32) * x.astype(jnp.float32)
+        gated = (c.astype(jnp.float32) * conv_taps(w["conv_k"], z)).astype(u.dtype)
+        return _mm("blh,hk->blk", gated, w["conv_out"])
+
+
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Half rotation (as Llama), positions from 0 in every row.
+    (B, L, heads, d), float32 in and out."""
+    length, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None, :]
+    rot = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], -1)
+    return x * cos + rot * sin
+
+
+def qk_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """RMSNorm over the head width with a learned scale; float32."""
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     q_block: int) -> jnp.ndarray:
+    """Blockwise causal grouped-query attention. ``q`` (B, L, nkv, g, d),
+    ``k`` / ``v`` (B, L, nkv, d) -> (B, L, nkv, g, d).
+
+    A block of ``q_block`` queries meets the keys up to its own end, so the
+    largest score tensor is ``B x heads x q_block x L`` in float32 and the
+    work is the causal half plus the diagonal blocks."""
+    length, d = q.shape[1], q.shape[-1]
+    qb = min(q_block, length)
+    if length % qb:
+        raise ValueError(f"row length {length} is no multiple of the query block {qb}")
+    out = []
+    for lo in range(0, length, qb):
+        hi = lo + qb
+        s = jnp.einsum("bqngd,bknd->bngqk", q[:, lo:hi], k[:, :hi],
+                       preferred_element_type=jnp.float32) * d ** -0.5
+        seen = (lo + jnp.arange(qb))[:, None] >= jnp.arange(hi)[None, :]
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("bngqk,bknd->bqngd", p.astype(v.dtype), v[:, :hi],
+                              preferred_element_type=jnp.float32).astype(v.dtype))
+    return jnp.concatenate(out, axis=1)
+
+
+def attn_mixer(w: dict, u: jnp.ndarray, config: dict, q_block: int) -> jnp.ndarray:
+    """GQA with per-head QK RMSNorm before RoPE. (B, L, h)."""
+    with jax.named_scope("lm.mixer.attn"):
+        nq, nkv = config["num_attention_heads"], config["num_key_value_heads"]
+        rows, length, h = u.shape
+        d = h // nq
+        eps, theta = config["norm_eps"], config["rope_theta"]
+        q = _mm("blh,hk->blk", u, w["wq"]).reshape(rows, length, nq, d)
+        k = _mm("blh,hk->blk", u, w["wk"]).reshape(rows, length, nkv, d)
+        v = _mm("blh,hk->blk", u, w["wv"]).reshape(rows, length, nkv, d)
+        q = rope(qk_norm(q, w["q_norm"], eps), theta).astype(u.dtype)
+        k = rope(qk_norm(k, w["k_norm"], eps), theta).astype(u.dtype)
+        o = causal_attention(q.reshape(rows, length, nkv, nq // nkv, d), k, v, q_block)
+        return _mm("blk,kh->blh", o.reshape(rows, length, nq * d), w["wo"])
+
+
+def dense_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
+    """``W_2 (silu(W_1 u) * W_3 u)``. (T, h)."""
+    with jax.named_scope("lm.ffn.dense"):
+        a = jnp.einsum("th,hf->tf", u, w["w1"], preferred_element_type=jnp.float32)
+        g = jnp.einsum("th,hf->tf", u, w["w3"], preferred_element_type=jnp.float32)
+        return _mm("tf,fh->th", (jax.nn.silu(a) * g).astype(u.dtype), w["w2"])
+
+
+def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> tuple:
+    """The sparse expert layer over (T, h) tokens -> (its part of the result
+    for the experts held, the (T, k) expert ids the router chose)."""
+    idx, weights = moe.route(u, w["router"], w["expert_bias"], config["num_experts_per_tok"],
+                             float(config["routed_scaling_factor"]))
+    out = moe.expert_ffn(u, idx, weights, w["w1"], w["w3"], w["w2"],
+                         config["num_experts"], experts)
+    return out, idx
+
+
+def head_logprobs(embed: jnp.ndarray, u: jnp.ndarray, targets: jnp.ndarray,
+                  block: int) -> jnp.ndarray:
+    """(T, h) normed final states and (T,) target ids -> (T,) float32
+    ``log softmax(u E^T)[target]``, ``block`` tokens at a time: the logits of
+    a block in float32, their log-sum-exp, the target's logit, and on."""
+    tokens = u.shape[0]
+    block = min(block, tokens)
+    if tokens % block:
+        raise ValueError(f"{tokens} tokens are no multiple of the head's block {block}")
+
+    def one(args: tuple) -> jnp.ndarray:
+        ub, tb = args
+        logits = jnp.einsum("th,vh->tv", ub, embed, preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
+        return picked - jax.nn.logsumexp(logits, axis=-1)
+
+    out = jax.lax.map(one, (u.reshape(tokens // block, block, -1),
+                            targets.reshape(tokens // block, block)))
+    return out.reshape(tokens)
+
+
+def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q_BLOCK,
+            head_block: int = HEAD_BLOCK, experts: Optional[tuple] = None) -> jnp.ndarray:
+    """(B, L + 1) int32 — ids, then each row's length — to (B, L - 1 + E)
+    float32: next-token log-probabilities (0 from the row's last real token
+    on) and the row's real tokens routed to each expert over all layers."""
+    ids, lengths = packed[:, :-1], packed[:, -1]
+    rows, length = ids.shape
+    num_experts = config["num_experts"]
+    eps = config["norm_eps"]
+    real = jnp.arange(length)[None, :] < lengths[:, None]
+    with jax.named_scope("lm.embed"):
+        x = variables["embed"][ids].astype(jnp.float32)
+    load = jnp.zeros((rows, num_experts), jnp.float32)
+    for (mixer, ffn), w in zip(layer_kinds(config), variables["layers"]):
+        u = rmsnorm(x, w["norm_op"], eps)
+        y = conv_mixer(w, u) if mixer == "conv" else attn_mixer(w, u, config, q_block)
+        x = x + y.astype(jnp.float32)
+        u = rmsnorm(x, w["norm_ffn"], eps).reshape(rows * length, -1)
+        if ffn == "dense":
+            y = dense_ffn(w, u)
+        else:
+            y, idx = moe_ffn(w, u, config, experts)
+            with jax.named_scope("lm.moe.route"):
+                load = load + moe.expert_load(idx.reshape(rows, length, -1), real, num_experts)
+        x = x + y.reshape(rows, length, -1).astype(jnp.float32)
+    with jax.named_scope("lm.head"):
+        u = rmsnorm(x, variables["norm"], eps).reshape(rows * length, -1)
+        targets = jnp.concatenate([ids[:, 1:], jnp.zeros((rows, 1), ids.dtype)], axis=1)
+        logp = head_logprobs(variables["embed"], u, targets.reshape(-1), head_block)
+        logp = logp.reshape(rows, length)[:, :-1]
+        logp = jnp.where(real[:, 1:], logp, 0.0)
+    return jnp.concatenate([logp, load], axis=1)
+
+
+# -- the stage -----------------------------------------------------------------
+
+class CausalLMScorer(Model, HasInputCol, HasOutputCol):
+    """Per-row next-token log-probabilities of a token-id column.
+
+    ``config`` holds the model's published keys (``hidden_size``,
+    ``layer_types``, ``num_experts`` ...: the model's own ``config.json``);
+    ``variables`` is ``{"embed": (V, h), "norm": (h,), "layers": [per-layer
+    dict]}`` (docs/models.md names every array). Hand it arrays that already
+    live on the device and they are used where they are: 9 GB of weights are
+    not copied. ``buckets`` are ``[length, rows per batch]`` pairs; a row
+    goes to the shortest bucket that holds it, and every bucket is one
+    compiled shape (warm them all: :meth:`warm_up`)."""
+
+    config = ComplexParam("the model's config.json keys as a dict")
+    variables = ComplexParam("model variables: embed, norm, layers (see docs/models.md)")
+    buckets = ComplexParam(
+        "length buckets as [[length, rows_per_batch], ...]; each is one compiled shape",
+        default=[[512, 8]],
+    )
+
+    def __init__(self, **kw: Any):
+        super().__init__(**kw)
+        self._inner: Optional[XLAModel] = None
+
+    def _build(self) -> XLAModel:
+        if self._inner is None:
+            config = dict(self.get_or_fail("config"))
+
+            def apply_fn(vs: Any, packed: Any) -> Any:
+                return forward(vs, packed, config)
+
+            self._inner = XLAModel(
+                input_col="__tokens__", output_col=self.get_or_fail("output_col"),
+                input_dtype=None,  # int32 ids stay int32
+            )
+            self._inner.set(apply_fn=apply_fn, variables=self.get_or_fail("variables"))
+        return self._inner
+
+    def _buckets(self) -> list:
+        buckets = sorted((int(length), int(rows)) for length, rows in self.get("buckets"))
+        if not buckets:
+            raise ValueError("CausalLMScorer needs at least one length bucket")
+        return buckets
+
+    def warm_up(self) -> None:
+        """Compile and run every bucket's shape once (a two-token row each)."""
+        inner = self._build()
+        for length, rows in self._buckets():
+            packed = np.zeros((1, length + 1), np.int32)
+            packed[0, -1] = 2
+            inner.apply_batch(packed, batch_size=rows)
+
+    def transform(self, df: DataFrame) -> DataFrame:
+        ic = self.get_or_fail("input_col")
+        oc = self.get_or_fail("output_col")
+        inner = self._build()
+        buckets = self._buckets()
+        edges = np.array([length for length, _ in buckets])
+        num_experts = int(self.get_or_fail("config")["num_experts"])
+
+        def fn(p: Partition) -> Partition:
+            rows = [np.asarray(r, np.int32) for r in p[ic]]
+            lens = np.array([len(r) for r in rows], np.int64)
+            if len(rows) and (lens.min() < 2 or lens.max() > edges[-1]):
+                raise ValueError(
+                    f"CausalLMScorer: rows of {lens.min()}..{lens.max()} tokens; a row needs "
+                    f"2 tokens at least and {edges[-1]} (the longest bucket) at most")
+            bucket_of = np.searchsorted(edges, lens, side="left")
+            out = np.empty(len(rows), dtype=object)
+            routed = np.zeros(num_experts, np.float64)
+            real = padded = 0
+            # one trace per partition; every bucket's apply_batch is a child
+            with obs.span("lm.score", attrs={"rows": len(rows)}) as sp:
+                for b, (length, batch) in enumerate(buckets):
+                    at = np.nonzero(bucket_of == b)[0]
+                    if not len(at):
+                        continue
+                    packed = np.zeros((len(at), length + 1), np.int32)
+                    for j, i in enumerate(at):
+                        packed[j, :lens[i]] = rows[i]
+                    packed[:, -1] = lens[at]
+                    batches = -(-len(at) // batch)
+                    with obs.span("lm.bucket", attrs={
+                            "length": length, "rows": len(at), "batches": batches}):
+                        res = inner.apply_batch(packed, batch_size=batch)
+                    for j, i in enumerate(at):
+                        out[i] = res[j, :lens[i] - 1].copy()
+                    routed += res[:, length - 1:].sum(0, dtype=np.float64)
+                    real += int(lens[at].sum())
+                    padded += batches * batch * length - int(lens[at].sum())
+                sp.set_attr("tokens_real", real)
+                sp.set_attr("tokens_padded", padded)
+            _M_TOKENS.labels(kind="real").inc(real)
+            _M_TOKENS.labels(kind="padded").inc(padded)
+            for e, n in enumerate(routed):
+                _M_ROUTED.labels(expert=str(e)).inc(float(n))
+            q = dict(p)
+            q[oc] = out
+            return q
+
+        return df.map_partitions(fn, parallel=False)

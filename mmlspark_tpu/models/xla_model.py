@@ -84,8 +84,8 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
 
     # -- device-side plumbing ----------------------------------------------
 
-    def _effective_batch(self, mesh: Any) -> int:
-        bs = self.get("batch_size")
+    def _effective_batch(self, mesh: Any, batch_size: Optional[int] = None) -> int:
+        bs = batch_size or self.get("batch_size")
         n_dev = mesh.devices.size
         return ((bs + n_dev - 1) // n_dev) * n_dev
 
@@ -122,8 +122,16 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
     # batch i, while bounding live HBM for inputs+outputs
     _MAX_IN_FLIGHT = 4
 
-    def apply_batch(self, x: np.ndarray) -> np.ndarray:
+    def apply_batch(self, x: np.ndarray, batch_size: Optional[int] = None) -> np.ndarray:
         """Evaluate one host batch (used by transform and by serving).
+
+        ``batch_size`` overrides the stage's for this call: a stage whose
+        rows come in several shapes (length buckets of token ids: long rows
+        in small batches, short rows in large ones) drives one call per
+        shape, each a compiled program of its own (``_compiled`` keys on the
+        batch's shape). Integer inputs pass as they are with
+        ``input_dtype=None``; what a row carries beside its data (its
+        length) rides as a trailing column.
 
         Double-buffered: the main thread ONLY stages + dispatches (upload of
         batch k+1 streams while batch k computes), and result fetches run on
@@ -139,7 +147,7 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
             with obs.span("xla_model.prepare"):
                 mesh = get_mesh()
                 vs = self._device_variables(mesh)
-                bs = self._effective_batch(mesh)
+                bs = self._effective_batch(mesh, batch_size)
                 dt = self.get("input_dtype")
                 x = np.asarray(x, dtype=dt) if dt else np.asarray(x)
                 padded, n = pad_batch(x, bs)

@@ -1,0 +1,101 @@
+"""Sparse expert layer: route, dispatch, grouped product, combine.
+
+One layer of a sparse-expert feed-forward network as XLA operations. The
+layer is *told which experts it holds* — a contiguous range ``[lo, hi)`` of
+the ``num_experts`` the router scores, with their weights — and computes
+those experts' part of the result for the tokens routed to them; what the
+other experts would add is left out (zero), so the parts of disjoint ranges
+add up to the whole layer (tests/test_causal_lm.py ties that to the plain
+reference). On one chip the range is everything; a mesh of expert ranges
+adds an exchange around :func:`expert_ffn`, not a change inside it.
+
+No token is dropped and there is no capacity: the ``T * k`` (token, expert)
+pairs are sorted by expert — held experts first — and the three products of
+the gated FFN run as ``jax.lax.ragged_dot`` over the sorted rows, one group
+per held expert (on the TPU a grouped-matmul kernel of XLA's own, tiled over
+the rows; elsewhere a masked dense product). The combine gathers the rows
+back into token order and weights them in float32.
+
+Router (LFM2-MoE / DeepSeek-V3 style): ``s = sigmoid(W_g u)`` in float32;
+the top-k is taken over ``s + bias`` (the load-balancing expert bias) while
+the combine weights come from ``s`` alone, normalised over the selection.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+ROUTE_EPS = 1e-6
+
+
+def select(scores: jnp.ndarray, bias: jnp.ndarray, top_k: int) -> jnp.ndarray:
+    """(T, E) scores -> (T, k) expert ids: the top-k of ``scores + bias``."""
+    return jax.lax.top_k(scores + bias, top_k)[1]
+
+
+def combine_weights(scores: jnp.ndarray, idx: jnp.ndarray, scaling: float) -> jnp.ndarray:
+    """(T, k) weights of the selected experts: from the scores alone (the
+    bias only steers the selection), normalised to sum to ``scaling``."""
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    return picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS) * scaling
+
+
+def router_scores(u: jnp.ndarray, router: jnp.ndarray) -> jnp.ndarray:
+    """(T, E) sigmoid scores in float32 at full matmul precision: a near-tie
+    decides which experts run, and a bfloat16 product would flip many more."""
+    logits = jnp.einsum("th,he->te", u.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    return jax.nn.sigmoid(logits)
+
+
+def route(u: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, top_k: int,
+          scaling: float = 1.0) -> tuple:
+    """(T, h) tokens -> ((T, k) int32 expert ids, (T, k) float32 weights)."""
+    with jax.named_scope("lm.moe.route"):
+        scores = router_scores(u, router)
+        idx = select(scores, bias.astype(jnp.float32), top_k)
+        return idx, combine_weights(scores, idx, scaling)
+
+
+def expert_ffn(u: jnp.ndarray, idx: jnp.ndarray, weights: jnp.ndarray,
+               w1: jnp.ndarray, w3: jnp.ndarray, w2: jnp.ndarray, num_experts: int,
+               experts: Optional[tuple] = None) -> jnp.ndarray:
+    """The held experts' part of ``sum_e w_e W2_e (silu(W1_e u) * W3_e u)``.
+
+    ``u`` (T, h); ``idx`` / ``weights`` (T, k) from :func:`route` over all
+    ``num_experts``; ``w1`` / ``w3`` (n_held, h, f) and ``w2`` (n_held, f, h)
+    are the weights of experts ``experts = (lo, hi)`` (default: all). Tokens
+    routed to an expert outside the range get nothing from it here."""
+    lo, hi = experts or (0, num_experts)
+    held = hi - lo
+    if w1.shape[0] != held:
+        raise ValueError(f"experts [{lo}, {hi}) need {held} experts' weights, got {w1.shape[0]}")
+    tokens, k = idx.shape
+    with jax.named_scope("lm.moe.dispatch"):
+        flat = idx.reshape(-1)
+        # held experts sort first, in order; the rest behind them, in no group
+        rank = (flat - lo) % num_experts
+        order = jnp.argsort(rank, stable=True)
+        rows = u[order // k]
+        sizes = (rank[:, None] == jnp.arange(held)[None, :]).sum(0, dtype=jnp.int32)
+    with jax.named_scope("lm.moe.experts"):
+        def gmm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+            return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=u.dtype)
+
+        out = gmm(jax.nn.silu(gmm(rows, w1)) * gmm(rows, w3), w2)
+    with jax.named_scope("lm.moe.combine"):
+        if held < num_experts:  # rows past the groups hold nothing defined
+            out = jnp.where((rank[order] < held)[:, None], out, 0)
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
+        picked = out[back].reshape(tokens, k, -1)
+        return jnp.einsum("tk,tkh->th", weights, picked.astype(jnp.float32)).astype(u.dtype)
+
+
+def expert_load(idx: jnp.ndarray, real: jnp.ndarray, num_experts: int) -> jnp.ndarray:
+    """(B, L, k) expert ids, (B, L) bool -> (B, E) float32: per row of the
+    batch, the real tokens routed to each expert."""
+    hot = idx[..., None] == jnp.arange(num_experts)
+    return (hot & real[:, :, None, None]).sum((1, 2), dtype=jnp.float32)

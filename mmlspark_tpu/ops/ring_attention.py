@@ -162,7 +162,10 @@ def dense_attention(
     causal: bool = False, scale: Optional[float] = None,
     kv_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Reference single-device attention (the golden for ring tests)."""
+    """Reference single-device attention (the golden for ring tests).
+
+    A query whose keys are all masked gets 0, as the ring gives it (a plain
+    softmax over a row of ``-inf`` is NaN)."""
     sc = scale if scale is not None else q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bqhk", q, k) * sc
     if causal:
@@ -171,5 +174,8 @@ def dense_attention(
         s = jnp.where(mask[None, :, None, :], s, -jnp.inf)
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0))
+    total = e.sum(axis=-1, keepdims=True)
+    p = e / jnp.where(total > 0, total, 1.0)
     return jnp.einsum("bqhk,bkhd->bqhd", p, v)

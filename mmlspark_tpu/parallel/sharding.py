@@ -80,7 +80,18 @@ def shard_batch_multihost(
 
 
 def replicate(tree: Any, mesh: Optional[Mesh] = None) -> Any:
-    """Replicate a pytree (weights) across the mesh — the broadcast analogue."""
+    """Replicate a pytree (weights) across the mesh — the broadcast analogue.
+
+    A leaf that already lives on the mesh's devices, whole on each, is
+    handed back as it is: weights made on the device (gigabytes of them) are
+    neither copied nor given a second buffer."""
     mesh = mesh or get_mesh()
     sh = NamedSharding(mesh, P())
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)
+
+    def put(x: Any) -> Any:
+        if isinstance(x, jax.Array) and x.is_fully_replicated \
+                and x.sharding.device_set == sh.device_set:
+            return x
+        return jax.device_put(x, sh)
+
+    return jax.tree_util.tree_map(put, tree)

@@ -1,5 +1,16 @@
 # Generated by mmlspark_tpu.codegen.generate_r_package — do not edit.
 
+#' Per-row next-token log-probabilities of a token-id column.
+#' (model: mmlspark_tpu.models.causal_lm.CausalLMScorer)
+#' @export
+mt_causal_lm_scorer <- function(buckets = NULL, config = NULL, input_col = NULL, output_col = NULL, variables = NULL) {
+  # snapshot formals BEFORE any local assignment leaks in
+  args <- as.list(environment())
+  args <- args[!vapply(args, is.null, logical(1))]
+  m <- reticulate::import("mmlspark_tpu.models.causal_lm")
+  do.call(m$CausalLMScorer, args)
+}
+
 #' A fitted transformer.
 #' (model: mmlspark_tpu.models.image_featurizer.ImageFeaturizer)
 #' @export

@@ -502,7 +502,7 @@ EXCLUDED = {
     # abstract/base-ish
     "Pipeline", "PipelineModel", "HasMiniBatcher", "CognitiveServiceBase",
     # covered by dedicated suites with model/zoo setup
-    "XLAModel", "ImageFeaturizer",
+    "XLAModel", "ImageFeaturizer", "CausalLMScorer",
     # network-bound: fuzzed against a live localhost server in test_io.py
     "HTTPTransformer", "SimpleHTTPTransformer",
     # fitted-model classes produced by their estimator (estimator is covered)

@@ -124,6 +124,18 @@ class TestKVMask:
         out = np.asarray(ring_attention(q, k, v, kv_mask=mask))
         assert np.all(np.isfinite(out)) and np.all(out == 0.0)
 
+    def test_dense_golden_gives_a_fully_masked_query_zero_too(self, devices8):
+        """The golden agrees with the ring where no key is visible: key 0
+        masked under causal masking leaves query 0 with nothing to see."""
+        q, k, v = _qkv(seed=8)
+        mask = jnp.ones((2, 64), bool).at[:, 0].set(False)
+        ref = np.asarray(dense_attention(q, k, v, causal=True, kv_mask=mask))
+        assert np.all(np.isfinite(ref)) and np.all(ref[:, 0] == 0.0)
+        out = ring_attention(q, k, v, causal=True, kv_mask=mask)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
+        none = np.asarray(dense_attention(q, k, v, kv_mask=jnp.zeros((2, 64), bool)))
+        assert np.all(none == 0.0)
+
     def test_single_device_mask(self):
         from jax.sharding import Mesh
 

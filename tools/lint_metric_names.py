@@ -52,6 +52,9 @@ SUBSYSTEMS = (
     "prof", "watchdog",
     # compile requests by cache hit / miss (core/compile_cache.py)
     "xla",
+    # the language-model scorer's token positions (models/causal_lm.py) and
+    # the sparse expert layer's routed tokens per expert (ops/moe.py)
+    "lm", "moe",
 )
 # "state" is for enum-valued gauges (e.g. the circuit-breaker gauge
 # mmlspark_gateway_breaker_state: 0=closed 1=open 2=half-open)
