@@ -61,6 +61,38 @@ def _csc_columns(x: object):
             yield f, np.asarray(xc.data[lo:hi], np.float64)
 
 
+def _quantile_edges(col: np.ndarray, max_bin: int) -> np.ndarray:
+    """Upper bounds of one feature's value bins from its (sampled) column:
+    midpoints between its distinct values where they fit the bins, else the
+    ``max_bin - 2`` interior percentiles, duplicates merged.
+
+    One sort serves all of it — the sort ``np.unique`` would make, then the
+    distinct values by one comparison pass and the percentiles read off the
+    sorted column, where NumPy's selection costs a fifth of what it costs on
+    an unsorted one. The edges are the ones the two independent passes gave
+    (tests/test_gbdt_binning_identity.py keeps that loop as its oracle)."""
+    col = col[~np.isnan(col)]
+    s = np.sort(col)
+    first = np.ones(len(s), bool)
+    first[1:] = s[1:] != s[:-1]
+    n_uniq = int(np.count_nonzero(first))
+    if n_uniq <= 1:
+        return np.array([], dtype=np.float64)
+    if n_uniq <= max_bin - 1:
+        uniq = s[first]
+        bounds = (uniq[:-1] + uniq[1:]) / 2.0
+    else:
+        qs = np.linspace(0, 100, max_bin)[1:-1]
+        bounds = np.unique(np.percentile(s, qs, method="linear"))
+    return bounds.astype(np.float64)
+
+
+# columns of the sample laid out contiguously at a time: a column read off
+# the row-major sample touches every cache line of it, a block's transpose
+# touches each once, and a block bounds the extra memory on wide inputs
+_COLUMN_BLOCK = 32
+
+
 @dataclass
 class BinMapper:
     # uppers[f] has length n_bins[f]-1: upper bound (inclusive) of each
@@ -108,8 +140,12 @@ class BinMapper:
             xs = x
         cat = set(int(f) for f in categorical_features)
         uppers = []
-        for f in range(d):
-            if f in cat:
+        for f0 in range(0, d, _COLUMN_BLOCK):
+            block = np.ascontiguousarray(xs[:, f0:f0 + _COLUMN_BLOCK].T)
+            for f, col in enumerate(block, start=f0):
+                if f not in cat:
+                    uppers.append(_quantile_edges(col, max_bin))
+                    continue
                 # full column, not the sample: hi must cover every category
                 # actually present or training bins and prediction's
                 # identity mapping would diverge for the unsampled tail
@@ -122,19 +158,6 @@ class BinMapper:
                     )
                 hi = int(col.max()) if len(col) else 0
                 uppers.append(np.arange(hi, dtype=np.float64) + 0.5)
-                continue
-            col = xs[:, f]
-            col = col[~np.isnan(col)]
-            uniq = np.unique(col)
-            if len(uniq) <= 1:
-                uppers.append(np.array([], dtype=np.float64))
-                continue
-            if len(uniq) <= max_bin - 1:
-                bounds = (uniq[:-1] + uniq[1:]) / 2.0
-            else:
-                qs = np.linspace(0, 100, max_bin)[1:-1]
-                bounds = np.unique(np.percentile(col, qs, method="linear"))
-            uppers.append(bounds.astype(np.float64))
         return BinMapper(uppers=uppers, max_bin=max_bin)
 
     @staticmethod
@@ -149,16 +172,7 @@ class BinMapper:
         for f, col in _csc_columns(x):
             if len(col) > sample:
                 col = rng.choice(col, sample, replace=False)
-            col = col[~np.isnan(col)]
-            uniq = np.unique(col)
-            if len(uniq) <= 1:
-                continue
-            if len(uniq) <= max_bin - 1:
-                bounds = (uniq[:-1] + uniq[1:]) / 2.0
-            else:
-                qs = np.linspace(0, 100, max_bin)[1:-1]
-                bounds = np.unique(np.percentile(col, qs, method="linear"))
-            uppers[f] = bounds.astype(np.float64)
+            uppers[f] = _quantile_edges(col, max_bin)
         return BinMapper(uppers=uppers, max_bin=max_bin)
 
     def _transform_sparse(self, x: object) -> np.ndarray:

@@ -39,6 +39,33 @@ from mmlspark_tpu.models.gbdt.booster import Booster
 from mmlspark_tpu.models.gbdt.train import TrainConfig, train
 
 
+def _read_column(df: DataFrame, name: str, dtype: Any = None) -> tuple:
+    """Column ``name`` of all partitions as one C-ordered array of ``dtype``
+    (``None``: as stored), for a caller that only READS it, and the bytes
+    that had to be copied to make it.
+
+    ``df[name]`` hands every caller a fresh array, which stages that mutate
+    their column rely on; at 2,625,000 x 28 that copy, and the ``astype``
+    after it, were 0.7 s of a 3.5 s fit (PERF.md section 6, PR 28).
+    ``train()`` writes to none of its inputs, so a single non-empty
+    partition whose array already has the dtype and the layout is handed on
+    as it is — as a read-only view: a write would raise, not reach the
+    caller's data — and anything else takes exactly one pass: several
+    partitions are concatenated straight into ``dtype``."""
+    arrs = [p[name] for p in df.partitions if p and len(p[name])]
+    if not arrs:
+        return np.array([], dtype=dtype), 0
+    if len(arrs) > 1:
+        out = np.concatenate(arrs, axis=0, dtype=dtype, casting="unsafe")
+        return out, out.nbytes
+    out = np.asarray(arrs[0], dtype=dtype, order="C")
+    if not np.may_share_memory(out, arrs[0]):
+        return out, out.nbytes
+    out = out.view()
+    out.flags.writeable = False
+    return out, 0
+
+
 class _LightGBMParams(
     HasFeaturesCol,
     HasLabelCol,
@@ -180,17 +207,24 @@ class _LightGBMParams(
             fair_c=self.get("fair_c"),
         )
 
-    def _gather(self, df: DataFrame) -> dict:
+    def _gather(self, df: DataFrame, label_dtype: Any = np.float64) -> dict:
+        """The columns ``train()`` reads, each in the dtype it reads them in
+        (the label in ``label_dtype``; ``None``: as stored), and under
+        ``copied_bytes`` how much of the feature matrix had to be copied to
+        get there (0: ``train()`` reads the DataFrame's own array), which the
+        ``gbdt.gather`` span carries as its attribute."""
+        x, copied = _read_column(df, self.get("features_col"), np.float32)
         out = {
-            "x": df[self.get("features_col")].astype(np.float32),
-            "y": df[self.get("label_col")].astype(np.float64),
+            "x": x,
+            "y": _read_column(df, self.get("label_col"), label_dtype)[0],
+            "copied_bytes": copied,
         }
         wc = self.get("weight_col")
-        out["w"] = df[wc].astype(np.float32) if wc else None
+        out["w"] = _read_column(df, wc, np.float32)[0] if wc else None
         vc = self.get("validation_indicator_col")
-        out["valid"] = df[vc].astype(bool) if vc else None
+        out["valid"] = _read_column(df, vc, bool)[0] if vc else None
         ic = self.get("init_score_col")
-        out["init"] = df[ic].astype(np.float32) if ic else None
+        out["init"] = _read_column(df, ic, np.float32)[0] if ic else None
         return out
 
     def _describe_fit(self, sp: obs.Span, data: dict) -> None:
@@ -306,9 +340,15 @@ class LightGBMClassifier(Estimator, _LightGBMParams, HasProbabilityCol, HasRawPr
         # one trace per fit: every span below (train()'s binning, upload
         # and chunks among them) is a descendant of this root
         with obs.span("gbdt.fit") as sp:
-            with obs.span("gbdt.gather"):
-                data = self._gather(df)
-                y = data["y"].astype(np.int64)
+            with obs.span("gbdt.gather") as gsp:
+                data = self._gather(df, label_dtype=None)
+                gsp.set_attr("copied_bytes", data["copied_bytes"])
+                # classes are whole numbers: an integer or bool label is read
+                # as stored (a float one truncates), and the class count and
+                # the priors below read that same array
+                y = data["y"]
+                if y.dtype.kind not in "biu":
+                    y = y.astype(np.int64)
                 n_classes = int(y.max()) + 1 if len(y) else 2
                 objective = self.get("objective")
                 if objective == "binary" and n_classes > 2:
@@ -504,8 +544,9 @@ class LightGBMRegressor(Estimator, _LightGBMParams, HasPredictionCol):
 
     def fit(self, df: DataFrame) -> "LightGBMRegressionModel":
         with obs.span("gbdt.fit") as sp:
-            with obs.span("gbdt.gather"):
+            with obs.span("gbdt.gather") as gsp:
                 data = self._gather(df)
+                gsp.set_attr("copied_bytes", data["copied_bytes"])
                 obj = objectives.canonical_objective(self.get("objective"))
                 base = 0.0
                 y = data["y"]
@@ -603,9 +644,10 @@ class LightGBMRanker(Estimator, _LightGBMParams, HasGroupCol, HasPredictionCol):
         if not gc:
             raise ValueError("LightGBMRanker requires group_col (query column)")
         with obs.span("gbdt.fit") as sp:
-            with obs.span("gbdt.gather"):
+            with obs.span("gbdt.gather") as gsp:
                 data = self._gather(df)
-                groups_raw = df[gc]
+                gsp.set_attr("copied_bytes", data["copied_bytes"])
+                groups_raw = _read_column(df, gc)[0]
                 _, group_ids = np.unique(
                     groups_raw.astype(str) if groups_raw.dtype == object else groups_raw,
                     return_inverse=True,

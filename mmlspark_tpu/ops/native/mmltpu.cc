@@ -82,11 +82,18 @@ void mml_murmur3_batch(const char** strings, const int32_t* lengths,
 }
 
 // Feature binning (LightGBM BinMapper.transform hot loop): for each cell,
-// out = 1 + (# edges < value), NaN -> 0 (missing bin). `edges` is the
-// concatenation of per-feature ascending edge arrays; `edge_offsets` has
-// d+1 entries delimiting them. Row-major x (n, d), threads split rows.
-void mml_bin_features(const float* x, int64_t n, int64_t d,
-                      const double* edges, const int64_t* edge_offsets,
+// out = 1 + (# thresholds <= value), NaN -> 0 (missing bin). `thresholds`
+// is the concatenation of per-feature ascending float32 arrays (a NaN, which
+// no value reaches, may end one); `offsets` has d+1 entries delimiting
+// them. The caller derives them from the float64 bin edges so that
+// `threshold <= v` is `edge < (double)v` for every float32 v: the bins are
+// the ones the float64 comparison gives, with float32 compares, no
+// conversion and no data-dependent branch in the search (a mispredicted
+// branch a level was most of its time). Row-major x (n, d), threads split
+// rows. (`_f32`: until PR 28 `mml_bin_features` took the float64 edges; a
+// library built from that source must fail to bind, not misread floats.)
+void mml_bin_features_f32(const float* x, int64_t n, int64_t d,
+                      const float* thresholds, const int64_t* offsets,
                       uint8_t* out) {
   auto worker = [&](int64_t lo, int64_t hi) {
     for (int64_t r = lo; r < hi; r++) {
@@ -94,19 +101,23 @@ void mml_bin_features(const float* x, int64_t n, int64_t d,
       uint8_t* orow = out + r * d;
       for (int64_t f = 0; f < d; f++) {
         float v = row[f];
-        if (std::isnan(v)) {
-          orow[f] = 0;
-          continue;
+        const float* t = thresholds + offsets[f];
+        int64_t len = offsets[f + 1] - offsets[f];
+        int64_t below = 0;  // thresholds <= v
+        if (len > 0) {
+          // halving search whose step is an add of 0 or `half` (a compare's
+          // 0/1 times `half`), not a jump; every compare with a NaN is
+          // false, so a NaN v counts none and a trailing NaN threshold is
+          // never counted
+          const float* base = t;
+          while (len > 1) {
+            int64_t half = len >> 1;
+            base += half * (int64_t)(base[half] <= v);
+            len -= half;
+          }
+          below = (base - t) + (int64_t)(*base <= v);
         }
-        const double* e = edges + edge_offsets[f];
-        int64_t m = edge_offsets[f + 1] - edge_offsets[f];
-        // branchless-ish binary search: first index with e[idx] >= v
-        int64_t lo_i = 0, hi_i = m;
-        while (lo_i < hi_i) {
-          int64_t mid = (lo_i + hi_i) >> 1;
-          if (e[mid] < (double)v) lo_i = mid + 1; else hi_i = mid;
-        }
-        orow[f] = (uint8_t)(lo_i + 1);
+        orow[f] = (v != v) ? 0 : (uint8_t)(below + 1);
       }
     }
   };

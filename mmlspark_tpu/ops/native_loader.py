@@ -35,15 +35,15 @@ class _NativeLib:
             ctypes.POINTER(ctypes.c_uint32),
         ]
         lib.mml_murmur3_batch.restype = None
-        lib.mml_bin_features.argtypes = [
+        lib.mml_bin_features_f32.argtypes = [
             ctypes.POINTER(ctypes.c_float),
             ctypes.c_int64,
             ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_float),
             ctypes.POINTER(ctypes.c_int64),
             ctypes.POINTER(ctypes.c_uint8),
         ]
-        lib.mml_bin_features.restype = None
+        lib.mml_bin_features_f32.restype = None
         lib.mml_parse_csv.argtypes = [
             ctypes.c_char_p,
             ctypes.c_int64,
@@ -75,7 +75,9 @@ class _NativeLib:
         return out
 
     def bin_features(self, x: np.ndarray, uppers: list) -> np.ndarray:
-        """(n, d) float32 -> uint8 bins via per-feature edge search (threaded)."""
+        """(n, d) float32 -> uint8 bins via per-feature edge search (threaded):
+        1 + the number of edges below the value, as the float64 comparison
+        ``edge < value`` counts them."""
         x = np.ascontiguousarray(x, np.float32)
         n, d = x.shape
         offsets = np.zeros(d + 1, np.int64)
@@ -86,12 +88,13 @@ class _NativeLib:
             if offsets[-1]
             else np.zeros(0, np.float64)
         )
+        thresholds = _float32_thresholds(edges)
         out = np.empty((n, d), np.uint8)
-        self._lib.mml_bin_features(
+        self._lib.mml_bin_features_f32(
             x.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
             n,
             d,
-            edges.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            thresholds.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
             offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
         )
@@ -111,6 +114,20 @@ class _NativeLib:
             n_rows.value,
         )
         return out[:got]
+
+
+def _float32_thresholds(edges: np.ndarray) -> np.ndarray:
+    """For each float64 edge ``e`` the float32 ``t`` with ``e < v  <=>  t <= v``
+    for every float32 ``v``: the smallest float32 above ``e``. The kernel
+    then compares float32 with float32 and finds the bins the float64
+    comparison gives. An edge no value exceeds (+inf, NaN) becomes NaN,
+    which no value reaches either."""
+    with np.errstate(over="ignore"):  # an edge beyond float32's range: t = inf
+        near = edges.astype(np.float32)  # nearest: the answer, or the float32 just below it
+        above = np.nextafter(near, np.float32(np.inf))
+    t = np.where(near.astype(np.float64) > edges, near, above)
+    t[np.isposinf(edges)] = np.nan
+    return np.ascontiguousarray(t, np.float32)
 
 
 def _build() -> Optional[str]:

@@ -1498,3 +1498,166 @@ class TestPartitionedInteractions:
         pred = b.predict_raw(x)
         cov = float((y <= pred).mean())
         assert 0.74 < cov < 0.86, cov  # coverage near the 0.8 target
+
+
+# -- the gather: what fit hands train() (PR 28) ------------------------------
+
+def _gather_span():
+    from mmlspark_tpu import obs
+
+    return obs.recent_spans(name="gbdt.gather")[-1]
+
+
+def _gather_data(seed=11, n=600, d=6):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    x[rng.random((n, d)) < 0.02] = np.nan
+    logit = 1.3 * np.nan_to_num(x[:, 0]) - np.nan_to_num(x[:, 1]) * np.nan_to_num(x[:, 2])
+    return x, logit, rng
+
+
+def _gather_cases():
+    """name -> (estimator, DataFrame): every dtype, layout and partition
+    count ``_gather`` tells apart, over the three estimators."""
+    x, logit, rng = _gather_data()
+    n = len(x)
+    w = rng.uniform(0.5, 2.0, n).astype(np.float32)
+    init = (0.1 * rng.standard_normal(n)).astype(np.float32)
+    yb = (logit + rng.logistic(size=n) > 0).astype(np.int64)
+    ym = np.digitize(logit, [-1.0, 1.0]).astype(np.int64)
+    yr = (logit + 0.1 * rng.standard_normal(n)).astype(np.float64)
+    grp = np.repeat(np.arange(n // 20), 20).astype(np.int64)
+    rel = np.clip(np.digitize(logit, [-1, 0, 1]), 0, 3).astype(np.int64)
+    kw = dict(num_iterations=5, num_leaves=7, min_data_in_leaf=5, seed=3)
+    fd = DataFrame.from_dict
+    return {
+        "classifier_f32_int_1part": (
+            LightGBMClassifier(**kw), fd({"features": x, "label": yb})),
+        "classifier_f64_float_3part_weight": (
+            LightGBMClassifier(weight_col="w", **kw),
+            fd({"features": x.astype(np.float64), "label": yb.astype(np.float64), "w": w},
+               num_partitions=3)),
+        "classifier_bool_label": (
+            LightGBMClassifier(**kw), fd({"features": x, "label": yb.astype(bool)})),
+        "classifier_multiclass_fortran": (
+            LightGBMClassifier(**kw), fd({"features": np.asfortranarray(x), "label": ym})),
+        "classifier_multiclass_float_label": (
+            LightGBMClassifier(**kw), fd({"features": x, "label": ym.astype(np.float32)})),
+        "classifier_init_score": (
+            LightGBMClassifier(init_score_col="init", **kw),
+            fd({"features": x, "label": yb, "init": init})),
+        "classifier_num_batches": (
+            LightGBMClassifier(num_batches=2, **kw),
+            fd({"features": x, "label": yb}, num_partitions=2)),
+        "regressor_f32_1part": (
+            LightGBMRegressor(**kw), fd({"features": x, "label": yr})),
+        "regressor_l1_int_label_3part": (
+            LightGBMRegressor(objective="regression_l1", **kw),
+            fd({"features": x, "label": np.round(yr * 3).astype(np.int64)}, num_partitions=3)),
+        "regressor_f32_label_weight": (
+            LightGBMRegressor(weight_col="w", **kw),
+            fd({"features": x, "label": yr.astype(np.float32), "w": w})),
+        "ranker_1part": (
+            LightGBMRanker(group_col="g", **kw), fd({"features": x, "label": rel, "g": grp})),
+        "ranker_2part_f64": (
+            LightGBMRanker(group_col="g", **kw),
+            fd({"features": x.astype(np.float64), "label": rel.astype(np.float64), "g": grp},
+               num_partitions=2)),
+    }
+
+
+# sha256 of the model string each case's fit gave at the parent commit
+# (75ab77a, before the gather stopped copying), under this suite's conftest
+_PARENT_MODEL_SHA256 = {
+    "classifier_bool_label": "fbd6d1971967c6133293a5f60c4fcf25f6b2f9aa73de0964fe959036130518df",
+    "classifier_f32_int_1part": "fbd6d1971967c6133293a5f60c4fcf25f6b2f9aa73de0964fe959036130518df",
+    "classifier_f64_float_3part_weight": "78518370a0341939f8927b371490436d3e76763418d4e00b42e81c4e0837d82d",
+    "classifier_init_score": "386bb6f6b0f2e0470eb21656887ecbbd5350ad8d9c0d7957f0a33f97b4a0bf7b",
+    "classifier_multiclass_float_label": "f9fb596c3faa0796aac34c417d75ff896bdd8899891fe553043e7e3e2976138c",
+    "classifier_multiclass_fortran": "f9fb596c3faa0796aac34c417d75ff896bdd8899891fe553043e7e3e2976138c",
+    "classifier_num_batches": "2e572fbe1e6095ba11a0b85a9b052420dbb7047aef7dafff64e27890cf02a288",
+    "ranker_1part": "39120d160560f9021881f73e927ae13e4f264f9277edcd55bdc67b0ff428a7f3",
+    "ranker_2part_f64": "39120d160560f9021881f73e927ae13e4f264f9277edcd55bdc67b0ff428a7f3",
+    "regressor_f32_1part": "da0bf954b8abbb5399493eba9c06c3fa8688d240785f8c3f3f6dd61035889ea9",
+    "regressor_f32_label_weight": "dc7d684de56b45e4eef04f2ad69514761a993c39fafff611098c39c05108c42e",
+    "regressor_l1_int_label_3part": "2b65ac86406860463615aa0996a78304da014787c2ee3bbde4c5397a1070fb04",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_gather_cases()))
+def test_fit_model_and_inputs_unchanged_by_gather(case):
+    """The gather changed what is copied, not what is trained: every seeded
+    fit gives the parent commit's model string, and leaves every array of
+    the DataFrame byte for byte as it was."""
+    import hashlib
+
+    est, df = _gather_cases()[case]
+    before = [{k: v.copy() for k, v in p.items()} for p in df.partitions]
+    flags = [{k: v.flags.writeable for k, v in p.items()} for p in df.partitions]
+    s = est.fit(df).get("model_string")
+    for p, b, f in zip(df.partitions, before, flags):
+        for k in p:
+            assert p[k].dtype == b[k].dtype and p[k].tobytes() == b[k].tobytes(), k
+            assert p[k].flags.writeable == f[k], k
+    assert hashlib.sha256(s.encode()).hexdigest() == _PARENT_MODEL_SHA256[case]
+
+
+def test_gather_one_float32_partition_is_not_copied():
+    x, y = make_binary(n=300)
+    df = DataFrame.from_dict({"features": x, "label": y})
+    data = LightGBMClassifier()._gather(df)
+    part = df.partitions[0]["features"]
+    assert np.shares_memory(data["x"], part)
+    assert data["copied_bytes"] == 0
+    # a read-only view: a write under train() would raise, not reach the
+    # caller's array (which stays writable)
+    assert not data["x"].flags.writeable and part.flags.writeable
+    # df[...] keeps its meaning: a fresh array for every other caller
+    assert not np.shares_memory(df["features"], part)
+    LightGBMClassifier(num_iterations=2, num_leaves=4).fit(df)
+    assert _gather_span().attrs["copied_bytes"] == 0
+
+
+@pytest.mark.parametrize("make", [
+    lambda x: (x, 3),                          # several partitions
+    lambda x: (x.astype(np.float64), 1),       # another dtype
+    lambda x: (np.asfortranarray(x), 1),       # another layout
+    lambda x: (x.astype(np.float64), 3),       # both: still one pass
+], ids=["3part", "float64", "fortran", "float64_3part"])
+def test_gather_copies_features_once(make):
+    """One pass into a float32 matrix, never two: the gather allocates the
+    matrix it hands on and nothing else of that size (NumPy's buffers are
+    traced by ``tracemalloc``)."""
+    import tracemalloc
+
+    x, y = make_binary(n=20_000)
+    feats, parts = make(x)
+    df = DataFrame.from_dict({"features": feats, "label": y}, num_partitions=parts)
+    tracemalloc.start()
+    try:
+        data = LightGBMClassifier()._gather(df)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    got = data["x"]
+    assert got.dtype == np.float32 and got.flags.c_contiguous
+    np.testing.assert_array_equal(got, x)
+    assert data["copied_bytes"] == got.nbytes == x.nbytes
+    assert not any(np.shares_memory(got, p["features"]) for p in df.partitions)
+    # the matrix and the label's copy; a second matrix would make it 2x
+    assert peak < 1.5 * got.nbytes, (peak, got.nbytes)
+
+
+@pytest.mark.parametrize("label", [
+    lambda y: y.astype(np.int64), lambda y: y.astype(np.int32), lambda y: y.astype(bool),
+    lambda y: y.astype(np.float32), lambda y: y,
+], ids=["int64", "int32", "bool", "float32", "float64"])
+def test_classifier_label_dtypes_train_the_same(label):
+    x, y = make_binary(n=300)
+    kw = dict(num_iterations=3, num_leaves=5, seed=1)
+    want = LightGBMClassifier(**kw).fit(
+        DataFrame.from_dict({"features": x, "label": y})).get("model_string")
+    got = LightGBMClassifier(**kw).fit(
+        DataFrame.from_dict({"features": x, "label": label(y)}, num_partitions=2)
+    ).get("model_string")
+    assert got == want

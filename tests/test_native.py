@@ -32,6 +32,51 @@ class TestBinFeatures:
             want[:, f] = np.where(np.isnan(col), 0, b).astype(np.uint8)
         np.testing.assert_array_equal(got, want)
 
+    def test_float32_thresholds_count_what_float64_edges_count(self, lib):
+        """The kernel compares float32 thresholds (PR 28); the bins are the
+        ones ``edge < float64(value)`` gives, at every place the two
+        precisions could part: edges that are float32 values themselves,
+        edges between two neighbouring float32s, zeros of both signs,
+        subnormals, infinities, edges beyond float32's range, a NaN edge."""
+        f32 = np.float32
+        vals = np.array([0.0, -0.0, 1.0, -1.0, 0.1, 1e-45, -1e-45, 1e-38, 3.4028235e38,
+                         -3.4028235e38, np.inf, -np.inf, np.nan, 16777216.0, 0.33333334], f32)
+        with np.errstate(over="ignore"):
+            vals = np.concatenate(
+                [vals, np.nextafter(vals, f32(np.inf)), np.nextafter(vals, f32(-np.inf))])
+        v64 = vals.astype(np.float64)
+        finite = v64[np.isfinite(v64)]
+        edges = np.unique(np.concatenate([
+            finite,                                        # exactly a value
+            np.nextafter(finite, np.inf), np.nextafter(finite, -np.inf),  # a float64 ulp off it
+            (finite[:-1] + finite[1:]) / 2.0,              # between float32 neighbours
+            [1e300, -1e300, 3.4028235677973366e38, np.inf, -np.inf, np.nan],
+        ]))                                                # sorted, NaN last
+        rng = np.random.RandomState(5)
+        x = np.stack([vals, vals[::-1], rng.permutation(vals)], axis=1)
+        uppers = [edges, edges[::3].copy(), np.array([], np.float64)]
+        got = lib.bin_features(x, uppers)
+        for f, u in enumerate(uppers):
+            col = x[:, f].astype(np.float64)
+            with np.errstate(invalid="ignore"):
+                want = 1 + (u[None, :] < col[:, None]).sum(axis=1)
+            want = np.where(np.isnan(col), 0, want)
+            np.testing.assert_array_equal(got[:, f], want.astype(np.uint8), err_msg=str(f))
+
+    def test_binmapper_transform_same_with_and_without_native(self, lib, monkeypatch):
+        from mmlspark_tpu.models.gbdt.binning import BinMapper
+        from mmlspark_tpu.ops import native_loader
+
+        rng = np.random.RandomState(6)
+        x = rng.randn(20_000, 5).astype(np.float32)
+        x[:, 1] = np.round(x[:, 1], 1)            # values that ARE edges' neighbours
+        x[:, 2] = rng.randint(0, 9, 20_000)       # midpoint edges
+        x[rng.rand(20_000, 5) < 0.03] = np.nan
+        mapper = BinMapper.fit(x, max_bin=255, sample=5_000)
+        with_native = mapper.transform(x)
+        monkeypatch.setattr(native_loader, "try_load", lambda: None)
+        np.testing.assert_array_equal(mapper.transform(x), with_native)
+
     def test_gbdt_binmapper_uses_native(self):
         from mmlspark_tpu.models.gbdt.binning import BinMapper
 
