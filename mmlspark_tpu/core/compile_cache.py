@@ -1,7 +1,8 @@
 """Where the persistent XLA compile cache lives — decided in ONE place.
 
-Every entry point that compiles (the ``fleet`` roles, ``bench.py --child``,
-``chip_smoke.py``'s phases, ``tests/conftest.py``) calls
+Every entry point that compiles (the ``fleet`` roles, the benchmark's
+``chipbench/harness.py``, ``chip_smoke.py``'s phases,
+``tests/conftest.py``) calls
 :func:`enable_compile_cache` before its first dispatch. The directory is
 part of the cache key's neighbourhood: a directory that moves never hits,
 so it is never a temporary name, a pid or a time.

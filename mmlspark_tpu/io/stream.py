@@ -6,8 +6,8 @@ custom file formats (io/binary/BinaryFileFormat.scala:112-149 reads
 portioned binary records on demand). ``StreamingDataFrame`` is that
 capability here: a re-iterable source of bounded eager CHUNKS (each a
 normal DataFrame), so a fitted pipeline can score datasets far larger than
-host memory — the 1M-row x 224^2 north-star image workload is launchable
-through it (tools/northstar_stream.py).
+host memory — the benchmark's ResNet-50 featurization stream runs through
+it (chipbench/drivers/featurize_stream.py).
 
 Semantics:
 - A chunk is a plain eager DataFrame; every existing Transformer works on

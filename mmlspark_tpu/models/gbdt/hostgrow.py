@@ -12,9 +12,9 @@ tree behind ONE callback pays the bridge once, runs the split scan in
 vectorized f64 numpy, and keeps the feature-parallel bincount pool
 (ops/histpool.py) hot across levels.
 
-Selection: only on unsharded CPU traces (``use_host_hist()``), chosen in
-:func:`treegrow.grow_tree_depthwise`. TPU, sharded meshes and
-``MMLSPARK_TPU_HIST_HOST=0`` keep the XLA grower. Split semantics mirror
+Selection: only on unsharded CPU traces, by :func:`treegrow.choose_grower`
+(its ``hostcall`` and ``depthwise_hostcall`` growers). TPU, sharded meshes
+and ``MMLSPARK_TPU_HIST_HOST=0`` keep the XLA growers. Split semantics mirror
 ``treegrow.make_leaf_best`` + the vectorized level application exactly
 (same tie-breaks: first-max over the (d*B) plane, stable gain ordering
 across a level); gains accumulate in f64 where the XLA grower uses f32,

@@ -55,10 +55,9 @@ from mmlspark_tpu.core import faults
 from mmlspark_tpu.models.gbdt import objectives
 from mmlspark_tpu.parallel.mesh import DATA_AXIS as _DATA_AXIS
 from mmlspark_tpu.models.gbdt.binning import BinMapper
-from mmlspark_tpu.ops.histogram import NUM_BINS, hist_lowering as _hist_lowering
+from mmlspark_tpu.ops.histogram import NUM_BINS
 from mmlspark_tpu.models.gbdt.booster import Booster, Tree, per_tree_raw
 from mmlspark_tpu.models.gbdt import treegrow
-from mmlspark_tpu.models.gbdt.treegrow import grow_tree
 
 log = logging.getLogger("mmlspark_tpu.gbdt")
 
@@ -400,15 +399,12 @@ def _iteration_core(
     k: int,
     grad_pre: bool,
     is_goss: bool,
-    use_voting: bool,
     has_cat: bool,
     num_leaves: int,
     max_depth: int,
     min_data_in_leaf: int,
     top_k: int,
-    mesh: Any,
-    depthwise: bool = False,
-    partitioned: bool = False,
+    grower: treegrow.Grower,
     num_bins: int = NUM_BINS,
 ) -> tuple:
     """One boosting iteration (traced): gradients, GOSS weights, k tree
@@ -456,32 +452,14 @@ def _iteration_core(
     for c in range(k) if k > 1 else [0]:
         gc = g_dev[:, c] if k > 1 else g_dev
         hc = h_dev[:, c] if k > 1 else h_dev
-        if use_voting:
-            from mmlspark_tpu.models.gbdt.voting import grow_tree_voting
-
-            grown = grow_tree_voting(
-                bins, gc, hc, w_it, top_k=top_k, mesh=mesh,
-                categorical_mask=cat_mask, **grow_kw
-            )
-        elif depthwise:
-            from mmlspark_tpu.models.gbdt.treegrow import grow_tree_depthwise
-
-            grown = grow_tree_depthwise(
-                bins, gc, hc, w_it, categorical_mask=cat_mask,
-                mesh=mesh, shard_axis=_DATA_AXIS if mesh is not None else None,
-                **grow_kw,
-            )
-        else:
-            grown = grow_tree(
-                bins, gc, hc, w_it, categorical_mask=cat_mask,
-                partitioned=partitioned,
-                mesh=mesh, shard_axis=_DATA_AXIS if mesh is not None else None,
-                **grow_kw,
-            )
+        grown = treegrow.grow_tree(
+            bins, gc, hc, w_it, categorical_mask=cat_mask, grower=grower,
+            top_k=top_k, **grow_kw,
+        )
         if (
             objective in objectives.RENEWED_KINDS
             and not grad_pre
-            and not use_voting
+            and grower.kind != "voting"
         ):
             # LightGBM's RenewTreeOutput: quantile-family leaf values are
             # the weighted alpha-percentile of the leaf's residuals, not
@@ -512,9 +490,9 @@ def _iteration_core(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "objective", "k", "grad_pre", "is_goss", "use_voting", "has_cat",
-        "num_leaves", "max_depth", "min_data_in_leaf", "top_k", "mesh",
-        "depthwise", "partitioned", "num_bins", "hist_mode",
+        "objective", "k", "grad_pre", "is_goss", "has_cat",
+        "num_leaves", "max_depth", "min_data_in_leaf", "top_k", "grower",
+        "num_bins",
     ),
 )
 def _fused_iteration(
@@ -542,17 +520,13 @@ def _fused_iteration(
     k: int,
     grad_pre: bool,
     is_goss: bool,
-    use_voting: bool,
     has_cat: bool,
     num_leaves: int,
     max_depth: int,
     min_data_in_leaf: int,
     top_k: int,
-    mesh: Any,
-    depthwise: bool = False,
-    partitioned: bool = False,
+    grower: treegrow.Grower,
     num_bins: int = NUM_BINS,
-    hist_mode: str = "",
 ) -> tuple:
     """One whole boosting iteration as ONE XLA program — the dispatch-per-
     iteration path kept for the modes whose loop does host work between
@@ -566,10 +540,9 @@ def _fused_iteration(
         obj_p1, top_rate, other_rate, lambda_l2, lambda_l1, min_sum_hessian,
         min_gain, learning_rate,
         objective=objective, k=k, grad_pre=grad_pre, is_goss=is_goss,
-        use_voting=use_voting, has_cat=has_cat, num_leaves=num_leaves,
+        has_cat=has_cat, num_leaves=num_leaves,
         max_depth=max_depth, min_data_in_leaf=min_data_in_leaf,
-        top_k=top_k, mesh=mesh, depthwise=depthwise,
-        partitioned=partitioned, num_bins=num_bins,
+        top_k=top_k, grower=grower, num_bins=num_bins,
     )
     return new_scores, tuple(grown_list)
 
@@ -618,10 +591,9 @@ _PACK_FIELDS = (
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "objective", "k", "grad_pre", "is_goss", "use_voting", "has_cat",
-        "num_leaves", "max_depth", "min_data_in_leaf", "top_k", "mesh",
-        "depthwise", "partitioned", "bagging_freq", "eval_kind", "is_rf",
-        "num_bins", "eval_k", "hist_mode",
+        "objective", "k", "grad_pre", "is_goss", "has_cat",
+        "num_leaves", "max_depth", "min_data_in_leaf", "top_k", "grower",
+        "bagging_freq", "eval_kind", "is_rf", "num_bins", "eval_k",
     ),
 )
 def _scan_chunk(
@@ -657,21 +629,17 @@ def _scan_chunk(
     k: int,
     grad_pre: bool,
     is_goss: bool,
-    use_voting: bool,
     has_cat: bool,
     num_leaves: int,
     max_depth: int,
     min_data_in_leaf: int,
     top_k: int,
-    mesh: Any,
-    depthwise: bool,
-    partitioned: bool,
+    grower: treegrow.Grower,
     bagging_freq: int,
     eval_kind: str,
     is_rf: bool,
     num_bins: int = NUM_BINS,
     eval_k: int = 5,
-    hist_mode: str = "",
 ) -> tuple:
     """C whole boosting iterations as ONE XLA program (``lax.scan`` over
     iterations). The per-iteration loop pays O(iterations) dispatches and
@@ -700,10 +668,9 @@ def _scan_chunk(
             obj_p1, top_rate, other_rate, lambda_l2, lambda_l1,
             min_sum_hessian, min_gain, learning_rate,
             objective=objective, k=k, grad_pre=grad_pre, is_goss=is_goss,
-            use_voting=use_voting, has_cat=has_cat, num_leaves=num_leaves,
+            has_cat=has_cat, num_leaves=num_leaves,
             max_depth=max_depth, min_data_in_leaf=min_data_in_leaf,
-            top_k=top_k, mesh=mesh, depthwise=depthwise,
-            partitioned=partitioned, num_bins=num_bins,
+            top_k=top_k, grower=grower, num_bins=num_bins,
         )
         recs = tuple(
             tuple(
@@ -1086,7 +1053,6 @@ def train(
 
     # device placement: rows sharded over the data axis when a mesh exists
     mesh = None
-    use_voting = False
     with obs.span("gbdt.upload", attrs={
         "what": "bins,weights", "bytes": int(bins_host.nbytes + w.nbytes),
     }):
@@ -1105,10 +1071,8 @@ def train(
             )
             w_dev = shard_batch_multihost(np.pad(w, (0, pad)), mesh)
             n_pad = share * jax.process_count()  # GLOBAL padded row count
-            if cfg.parallelism == "voting_parallel":
-                use_voting = True
         elif shard:
-            from mmlspark_tpu.parallel.mesh import DATA_AXIS, get_mesh
+            from mmlspark_tpu.parallel.mesh import get_mesh
             from mmlspark_tpu.parallel.sharding import pad_batch, shard_batch
 
             mesh = get_mesh()
@@ -1118,14 +1082,6 @@ def train(
             bins_dev = shard_batch(bins_p, mesh)
             w_dev = shard_batch(np.pad(w, (0, pad)), mesh)
             n_pad = n + pad
-            if cfg.parallelism == "voting_parallel":
-                if dict(mesh.shape).get(DATA_AXIS, 1) > 1:
-                    use_voting = True
-                else:
-                    log.info(
-                        "voting_parallel needs >1 data shard; "
-                        "falling back to data_parallel"
-                    )
         else:
             pad = 0
             bins_dev = jnp.asarray(bins_host)
@@ -1145,28 +1101,15 @@ def train(
             return shard_batch(a)
         return jnp.asarray(a)
 
-    # leaf-wise growth partitioned by leaf (LightGBM's DataPartition +
-    # histogram subtraction, treegrow._grow_tree_partitioned) where
-    # treegrow.lossguide_grower says so: one device with the Pallas
-    # lowering. Decided here, before the round program is traced, because
-    # the choice is part of that program's cache key. On one v5e at
-    # 2,625,000 x 28 and 255 leaves it grows a tree in 479 device-ms where
-    # the masked grower takes 4,733 (PERF.md section 6, PR 26); a sharded
-    # mesh keeps the masked grower, whose per-shard pass + plane psum has
-    # no global permutation in it.
-    partitioned = (
-        cfg.growth_policy == "lossguide"
-        and not multihost
-        and not use_voting
-        and treegrow.lossguide_grower(
-            mesh, _DATA_AXIS if mesh is not None else None
-        ) == "partitioned"
-    )
-    # rows sharded over the mesh data axis: hand the mesh to the growers so
-    # the histogram op can run its Pallas kernel per shard + psum the planes
-    # (ops/histogram.py shard_map lowering) instead of the GSPMD scatter
-    hist_sharded = (
-        mesh is not None and dict(mesh.shape).get(_DATA_AXIS, 1) > 1
+    # which grower the trees come from (treegrow.choose_grower: the growth
+    # policy, voting, the mesh and its device's histogram lowering).
+    # Decided here, once, before a round program is traced: the value is
+    # that program's one static argument for it
+    grower = treegrow.choose_grower(
+        cfg.growth_policy,
+        voting=cfg.parallelism == "voting_parallel",
+        mesh=mesh,
+        shard_axis=_DATA_AXIS,
     )
 
     # -- device-resident loop state -----------------------------------------
@@ -1488,17 +1431,14 @@ def train(
                         float(cfg.min_gain_to_split),
                         1.0 if is_rf else lr_cur,
                         objective=cfg.objective, k=k, grad_pre=grad_pre_f,
-                        is_goss=is_goss, use_voting=use_voting,
+                        is_goss=is_goss,
                         has_cat=cat_mask_dev is not None,
                         num_leaves=int(cfg.num_leaves), max_depth=int(cfg.max_depth),
                         min_data_in_leaf=int(cfg.min_data_in_leaf),
-                        top_k=int(cfg.top_k),
-                        mesh=mesh if (use_voting or hist_sharded) else None,
-                        depthwise=cfg.growth_policy == "depthwise",
-                        partitioned=partitioned,
+                        top_k=int(cfg.top_k), grower=grower,
                         bagging_freq=int(bagging_freq) if use_bag else 0,
                         eval_kind=eval_kind, is_rf=is_rf, num_bins=hist_bins,
-                        eval_k=int(eval_k), hist_mode=_hist_lowering(),
+                        eval_k=int(eval_k),
                     )
                 # the one blocking fetch of the chunk: the packed tree
                 # records (and the (C,) eval metrics with them)
@@ -1644,14 +1584,10 @@ def train(
             float(cfg.min_sum_hessian_in_leaf), float(cfg.min_gain_to_split),
             1.0 if is_rf else lr_cur,
             objective=cfg.objective, k=k, grad_pre=grad_pre, is_goss=is_goss,
-            use_voting=use_voting, has_cat=cat_mask_dev is not None,
+            has_cat=cat_mask_dev is not None,
             num_leaves=int(cfg.num_leaves), max_depth=int(cfg.max_depth),
             min_data_in_leaf=int(cfg.min_data_in_leaf),
-            top_k=int(cfg.top_k),
-            mesh=mesh if (use_voting or hist_sharded) else None,
-            depthwise=cfg.growth_policy == "depthwise",
-            partitioned=partitioned, num_bins=hist_bins,
-            hist_mode=_hist_lowering(),
+            top_k=int(cfg.top_k), grower=grower, num_bins=hist_bins,
         )
         # the fused step fit against eff_scores (dart: scores minus dropped
         # trees); the running total keeps the dropped contribution

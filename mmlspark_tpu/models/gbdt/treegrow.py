@@ -23,8 +23,9 @@ values — LightGBM's leaf-wise growth expressed as a replay log.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-import os
+import logging
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -32,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from mmlspark_tpu.ops.histogram import NUM_BINS  # uint8 bin space; bin 0 = missing
+
+log = logging.getLogger("mmlspark_tpu.gbdt")
 
 
 class GrownTree(NamedTuple):
@@ -149,32 +152,108 @@ def make_leaf_best(
     return leaf_best
 
 
-def lossguide_grower(mesh: Any = None, shard_axis: Optional[str] = None) -> str:
-    """Which leaf-wise grower a layout gets — the one rule, read from what
-    the code can see (the mesh and the histogram lowering of its device):
+@dataclasses.dataclass(frozen=True)
+class Grower:
+    """Which grower a fit's trees come from, and for what: the one value
+    :func:`choose_grower` returns. Hashable, so it is the one static
+    argument that says it to a jitted program — a program traced for one
+    lowering or layout cannot be reused under another (for the values the
+    rule builds: see ``lowering``)."""
 
-    - ``"masked"`` (:func:`_grow_tree`) where the rows are sharded over the
-      mesh: a split is one masked pass per shard plus a plane ``psum``; the
-      partitioned grower's global row permutation would cross chips;
-    - ``"partitioned"`` (:func:`_grow_tree_partitioned`) on one device with
+    # partitioned | masked | hostcall (leaf-wise), depthwise |
+    # depthwise_hostcall (level-wise), voting
+    kind: str
+    # the one-device histogram lowering of the target device the choice
+    # was made for (pallas | cpu | scatter, ops/histogram.py). A key and
+    # a record, not a selector: no grower or kernel reads it, and the
+    # kernels still ask hist_lowering(mesh) at trace time, so a Grower
+    # built by hand with another device's lowering runs this device's
+    lowering: str
+    # the caller's mesh and the axis its rows are sharded over. None: a
+    # one-device call on the process's default device. With no rows
+    # sharded over it the mesh only names the device the kernels are
+    # lowered for
+    mesh: Any = None
+    shard_axis: Optional[str] = None
+    # the level-wise growers' variants; tests build the other values as
+    # references
+    sibling_subtract: bool = True
+    vector_split: bool = True
+
+
+def choose_grower(
+    growth_policy: str = "lossguide",
+    voting: bool = False,
+    mesh: Any = None,
+    shard_axis: Optional[str] = None,
+    lowering: Optional[str] = None,
+) -> Grower:
+    """Which grower a layout gets — the one rule, read from what the code
+    can see: the growth policy, whether voting-parallel was asked for, the
+    mesh and the axis that shards its rows, and the histogram lowering of
+    the device the mesh names (``lowering``; None asks
+    :func:`ops.histogram.hist_lowering`).
+
+    - ``voting`` (:func:`voting.grow_tree_voting`) where it was asked for
+      and the rows are sharded; on one shard there is nobody to vote with
+      and the request falls back to what follows;
+    - level-wise: ``depthwise_hostcall`` (the whole tree behind one host
+      callback — a per-level histogram callback alone leaves ~9 ms/tree of
+      XLA:CPU glue plus ~1 ms of bridge cost per crossing) on an unsharded
+      CPU lowering, ``depthwise`` (:func:`_grow_tree_depthwise`) anywhere
+      else. Sibling subtraction is always on; the vectorized level
+      application pays on a TPU (the chain of tiny dependent ops per split
+      dominates wall clock there) and costs ~30% on a CPU (full-width
+      scatters per level), so it follows the platform;
+    - leaf-wise: ``masked`` (:func:`_grow_tree`) where the rows are sharded
+      over the mesh: a split is one masked pass per shard plus a plane
+      ``psum``; the partitioned grower's global row permutation would
+      cross chips;
+    - ``partitioned`` (:func:`_grow_tree_partitioned`) on one device with
       the Pallas lowering, i.e. one TPU chip: a split costs its leaf's
       rows. ``higgs_gbdt_fit`` on one v5e (PERF.md section 6, builder's
       chip runs, PR 26; 2,625,000 x 28, 255 leaves): 479 device-ms a
       tree and 1.147 trees/s, against the masked grower's 4,733 and
       0.195;
-    - ``"hostcall"`` (:func:`_grow_tree_lossguide_hostcall`) on one CPU
+    - ``hostcall`` (:func:`_grow_tree_lossguide_hostcall`) on one CPU
       device: the whole tree behind one host callback;
-    - ``"masked"`` for what is left (one device, XLA scatter lowering).
+    - ``masked`` for what is left (one device, XLA scatter lowering).
 
-    Tests force a grower by replacing this function."""
-    from mmlspark_tpu.ops.histogram import _rows_sharded, hist_lowering
+    The trainer asks once, before it traces its round program. Tests force
+    a grower by replacing this function."""
+    from mmlspark_tpu.ops.histogram import (
+        _rows_sharded,
+        _target_device,
+        hist_lowering,
+    )
 
-    if _rows_sharded(mesh, shard_axis):
-        return "masked"
-    lowering = hist_lowering(mesh)
-    if lowering == "pallas" and (mesh is None or mesh.devices.size == 1):
-        return "partitioned"
-    return "hostcall" if lowering == "cpu" else "masked"
+    if mesh is None:
+        shard_axis = None
+    if lowering is None:
+        lowering = hist_lowering(mesh)
+    sharded = _rows_sharded(mesh, shard_axis)
+    if voting and not sharded:
+        log.info(
+            "voting_parallel needs >1 data shard; "
+            "falling back to data_parallel"
+        )
+        voting = False
+    vector_split = True
+    if voting:
+        kind = "voting"
+    elif growth_policy == "depthwise":
+        hostcall = lowering == "cpu" and not sharded
+        kind = "depthwise_hostcall" if hostcall else "depthwise"
+        vector_split = _target_device(mesh).platform == "tpu"
+    elif sharded:
+        kind = "masked"
+    elif lowering == "pallas" and (mesh is None or mesh.devices.size == 1):
+        kind = "partitioned"
+    else:
+        kind = "hostcall" if lowering == "cpu" else "masked"
+    return Grower(
+        kind, lowering, mesh, shard_axis, vector_split=vector_split
+    )
 
 
 def grow_tree(
@@ -193,76 +272,70 @@ def grow_tree(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    partitioned: Optional[bool] = None,
-    mesh: Any = None,
-    shard_axis: Optional[str] = None,
+    grower: Optional[Grower] = None,
+    top_k: int = 20,
 ) -> GrownTree:
-    """Grow one tree. The categorical-split machinery (per-leaf argsort of
-    category bins) is statically compiled OUT when ``categorical_mask`` is
-    None — the common all-numerical case pays nothing for it.
+    """Grow one tree with ``grower`` — what :func:`choose_grower` decided
+    for the caller's layout; None asks it for a leaf-wise tree on the
+    process's default device. The one dispatch on that value.
+
+    The categorical-split machinery (per-leaf argsort of category bins) is
+    statically compiled OUT when ``categorical_mask`` is None — the common
+    all-numerical case pays nothing for it.
 
     ``lambda_l1`` soft-thresholds gradient sums in both split gains and
     leaf values; ``min_sum_hessian`` invalidates splits whose child
     hessian mass is below it (LightGBM lambda_l1 /
-    min_sum_hessian_in_leaf semantics).
-
-    :func:`lossguide_grower` picks the grower from the layout.
-    ``partitioned`` is what the trainer decided with it before it traced
-    its round program (True: :func:`_grow_tree_partitioned`; False: any
-    other); None asks the rule here."""
-    has_categorical = categorical_mask is not None
-    if not has_categorical:
-        categorical_mask = jnp.zeros((bins.shape[1],), bool)
-    # the lowering choice is env/backend-dependent and invisible to jit's
-    # cache key — thread it as a static arg so flipping
-    # MMLSPARK_TPU_HIST_HOST / MMLSPARK_TPU_PALLAS between calls with
-    # identical shapes can never reuse a stale-lowering program
-    from mmlspark_tpu.ops.histogram import hist_lowering
-
-    hm = hist_lowering(mesh)
-    grower = lossguide_grower(mesh, shard_axis)
-    if partitioned:
-        grower = "partitioned"
-    elif partitioned is not None and grower == "partitioned":
-        grower = "masked"
-    if grower == "hostcall":
-        # CPU lowering: the whole leaf-wise tree behind ONE host callback
-        # (see _grow_tree_depthwise_hostcall for the cost argument)
-        return _grow_tree_lossguide_hostcall(
-            bins, grad, hess, row_weight,
-            num_leaves=num_leaves, max_depth=max_depth, num_bins=num_bins,
-            min_data_in_leaf=min_data_in_leaf, min_gain=min_gain,
-            lambda_l2=lambda_l2, lambda_l1=lambda_l1,
-            min_sum_hessian=min_sum_hessian, learning_rate=learning_rate,
-            feature_mask=feature_mask, categorical_mask=categorical_mask,
-            has_categorical=has_categorical,
-        )
-    if grower == "partitioned":
-        return _grow_tree_partitioned(
-            bins, grad, hess, row_weight,
-            num_leaves=num_leaves, lambda_l2=lambda_l2, min_gain=min_gain,
-            learning_rate=learning_rate, feature_mask=feature_mask,
-            max_depth=max_depth, min_data_in_leaf=min_data_in_leaf,
-            categorical_mask=categorical_mask, has_categorical=has_categorical,
-            lambda_l1=lambda_l1, min_sum_hessian=min_sum_hessian,
-            num_bins=num_bins, mesh=mesh, hist_mode=hm,
-        )
-    return _grow_tree(
-        bins, grad, hess, row_weight,
+    min_sum_hessian_in_leaf semantics). ``top_k`` is the voting grower's
+    ballot size."""
+    if grower is None:
+        grower = choose_grower()
+    hyper = dict(
         num_leaves=num_leaves, lambda_l2=lambda_l2, min_gain=min_gain,
         learning_rate=learning_rate, feature_mask=feature_mask,
         max_depth=max_depth, min_data_in_leaf=min_data_in_leaf,
-        categorical_mask=categorical_mask, has_categorical=has_categorical,
         lambda_l1=lambda_l1, min_sum_hessian=min_sum_hessian,
-        num_bins=num_bins, mesh=mesh, shard_axis=shard_axis, hist_mode=hm,
+        num_bins=num_bins,
     )
+    if grower.kind == "voting":
+        from mmlspark_tpu.models.gbdt.voting import grow_tree_voting
+
+        return grow_tree_voting(
+            bins, grad, hess, row_weight, top_k=top_k, mesh=grower.mesh,
+            axis=grower.shard_axis, categorical_mask=categorical_mask,
+            **hyper,
+        )
+    if grower.kind in ("depthwise", "depthwise_hostcall"):
+        return grow_tree_depthwise(
+            bins, grad, hess, row_weight, categorical_mask=categorical_mask,
+            grower=grower, **hyper,
+        )
+    has_categorical = categorical_mask is not None
+    if not has_categorical:
+        categorical_mask = jnp.zeros((bins.shape[1],), bool)
+    hyper.update(
+        categorical_mask=categorical_mask, has_categorical=has_categorical
+    )
+    if grower.kind == "hostcall":
+        # CPU lowering: the whole leaf-wise tree behind ONE host callback
+        # (see choose_grower for the cost argument)
+        return _grow_tree_lossguide_hostcall(
+            bins, grad, hess, row_weight, **hyper
+        )
+    if grower.kind == "partitioned":
+        return _grow_tree_partitioned(
+            bins, grad, hess, row_weight, grower=grower, **hyper
+        )
+    if grower.kind != "masked":
+        raise ValueError(f"unknown grower {grower.kind!r}")
+    return _grow_tree(bins, grad, hess, row_weight, grower=grower, **hyper)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "num_leaves", "max_depth", "min_data_in_leaf", "has_categorical",
-        "num_bins", "mesh", "shard_axis", "hist_mode",
+        "num_bins", "grower",
     ),
 )
 def _grow_tree(
@@ -282,11 +355,9 @@ def _grow_tree(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    mesh: Any = None,
-    shard_axis: Optional[str] = None,
-    hist_mode: str = "",
+    grower: Grower = Grower("masked", "scatter"),
 ) -> GrownTree:
-    del hist_mode  # jit cache key only (see grow_tree)
+    mesh, shard_axis = grower.mesh, grower.shard_axis
     n, d = bins.shape
     L = num_leaves
     B = num_bins
@@ -448,10 +519,10 @@ def _grow_tree(
     )
 
     # leaf values: -ThresholdL1(G)/(H+lambda) * lr per final leaf
-    from mmlspark_tpu.ops.histogram import _rows_sharded, leaf_stat_sums
+    from mmlspark_tpu.ops.histogram import leaf_stat_sums
 
     sums = leaf_stat_sums(
-        row_leaf, row_stats, L, sharded=_rows_sharded(mesh, shard_axis)
+        row_leaf, row_stats, L, mesh=mesh, shard_axis=shard_axis
     )
     Gl, Hl, Cl = sums[:, 0], sums[:, 1], sums[:, 2]
     leaf_values = -soft(Gl) / (Hl + lambda_l2) * learning_rate
@@ -485,7 +556,7 @@ def _range_sizes(n: int, min_size: int = 512) -> tuple:
     jax.jit,
     static_argnames=(
         "num_leaves", "max_depth", "min_data_in_leaf", "has_categorical",
-        "num_bins", "mesh", "hist_mode",
+        "num_bins", "grower",
     ),
 )
 def _grow_tree_partitioned(
@@ -505,8 +576,7 @@ def _grow_tree_partitioned(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    mesh: Any = None,
-    hist_mode: str = "",
+    grower: Grower = Grower("partitioned", "pallas"),
 ) -> GrownTree:
     """Leaf-wise growth over rows kept PARTITIONED by leaf — the TPU
     expression of LightGBM's DataPartition + histogram-subtraction core
@@ -546,11 +616,11 @@ def _grow_tree_partitioned(
 
     One-device layouts only: the partition is a global permutation, which
     would become cross-device traffic under a sharded mesh
-    (:func:`lossguide_grower` keeps those on :func:`_grow_tree`). ``mesh``
-    only names the device the kernels are lowered for."""
-    del hist_mode  # jit cache key only (see grow_tree)
+    (:func:`choose_grower` keeps those on :func:`_grow_tree`). The
+    grower's mesh only names the device the kernels are lowered for."""
     from mmlspark_tpu.ops.histogram import leaf_stat_sums, plane_histogram
 
+    mesh = grower.mesh
     n, d = bins.shape
     L = num_leaves
     B = num_bins
@@ -801,7 +871,7 @@ def _grow_tree_partitioned(
             leaf_of_pos, unique_indices=True
         )
 
-    sums = leaf_stat_sums(row_leaf, row_stats, L)
+    sums = leaf_stat_sums(row_leaf, row_stats, L, mesh=mesh)
     Gl, Hl, Cl = sums[:, 0], sums[:, 1], sums[:, 2]
     leaf_values = -threshold_l1(Gl, lambda_l1) / (Hl + lambda_l2) * learning_rate
     leaf_values = jnp.where(Cl > 0, leaf_values, 0.0)
@@ -829,8 +899,7 @@ def grow_tree_depthwise(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    mesh: Any = None,
-    shard_axis: Optional[str] = None,
+    grower: Optional[Grower] = None,
 ) -> GrownTree:
     """Depthwise (level-wise) growth — the XGBoost-hist/SparkML-GBT grow
     policy, built for the TPU cost model: every level's leaf histograms
@@ -843,13 +912,18 @@ def grow_tree_depthwise(
     With ``max_depth`` unset, depth caps at ceil(log2(num_leaves)) — the
     balanced depth that can realize the leaf budget.
 
-    Sibling subtraction (LightGBM's histogram-subtraction trick, on by
-    default, ``MMLSPARK_TPU_GBDT_SIBLING=0`` to disable): from level 1
-    on, only the RIGHT child of every pair is histogrammed and the left
-    plane is derived as parent - right. The multi-plane kernel's MXU
+    Sibling subtraction (LightGBM's histogram-subtraction trick;
+    ``grower.sibling_subtract``, on in every grower the rule returns): from
+    level 1 on, only the RIGHT child of every pair is histogrammed and the
+    left plane is derived as parent - right. The multi-plane kernel's MXU
     cost scales with the slot count, so this halves the dominant
     per-level matmul width — the per-tree histogram work drops from
-    ~2*num_leaves to ~num_leaves plane-equivalents."""
+    ~2*num_leaves to ~num_leaves plane-equivalents.
+
+    ``grower``: one of :func:`choose_grower`'s two level-wise growers; None
+    asks it for the process's default device."""
+    if grower is None:
+        grower = choose_grower("depthwise")
     has_categorical = categorical_mask is not None
     if not has_categorical:
         categorical_mask = jnp.zeros((bins.shape[1],), bool)
@@ -860,32 +934,7 @@ def grow_tree_depthwise(
         min(int(max_depth), L - 1) if max_depth > 0
         else max(1, int(np.ceil(np.log2(L))))
     )
-    sibling = os.environ.get("MMLSPARK_TPU_GBDT_SIBLING", "1") not in (
-        "0", "false", ""
-    )
-    # vectorized level application pays on TPU (the sequential chain of
-    # tiny dependent ops per split dominates wall clock there) but costs
-    # ~30% on CPU (no dispatch-latency problem; full-width scatters per
-    # level instead). Default by backend, env-overridable.
-    from mmlspark_tpu.ops.histogram import (
-        _rows_sharded,
-        _target_device,
-        hist_lowering,
-        use_host_hist,
-    )
-
-    env_vec = os.environ.get("MMLSPARK_TPU_GBDT_VECTOR_SPLIT")
-    if env_vec is not None:
-        vector = env_vec not in ("0", "false", "")
-    else:
-        vector = _target_device(mesh).platform == "tpu"
-    # CPU lowering: the whole tree grows behind ONE host callback (numpy
-    # split scan + pooled bincount histograms) — a per-level histogram
-    # callback alone leaves ~9 ms/tree of XLA:CPU glue plus ~1 ms of
-    # bridge cost per crossing, which is the difference between losing
-    # and beating sklearn's OpenMP grower at bench shapes. TPU and
-    # sharded meshes keep the XLA grower below.
-    if use_host_hist(mesh) and not _rows_sharded(mesh, shard_axis):
+    if grower.kind == "depthwise_hostcall":
         return _grow_tree_depthwise_hostcall(
             bins, grad, hess, row_weight,
             num_leaves=L, n_levels=n_levels, num_bins=num_bins,
@@ -893,8 +942,11 @@ def grow_tree_depthwise(
             lambda_l2=lambda_l2, lambda_l1=lambda_l1,
             min_sum_hessian=min_sum_hessian, learning_rate=learning_rate,
             feature_mask=feature_mask, categorical_mask=categorical_mask,
-            has_categorical=has_categorical, sibling_subtract=sibling,
+            has_categorical=has_categorical,
+            sibling_subtract=grower.sibling_subtract,
         )
+    if grower.kind != "depthwise":
+        raise ValueError(f"not a level-wise grower: {grower.kind!r}")
     return _grow_tree_depthwise(
         bins, grad, hess, row_weight,
         num_leaves=L, lambda_l2=lambda_l2, min_gain=min_gain,
@@ -902,9 +954,7 @@ def grow_tree_depthwise(
         n_levels=n_levels, min_data_in_leaf=min_data_in_leaf,
         categorical_mask=categorical_mask, has_categorical=has_categorical,
         lambda_l1=lambda_l1, min_sum_hessian=min_sum_hessian,
-        num_bins=num_bins, mesh=mesh, shard_axis=shard_axis,
-        sibling_subtract=sibling, vector_split=vector,
-        hist_mode=hist_lowering(mesh),
+        num_bins=num_bins, grower=grower,
     )
 
 
@@ -1012,8 +1062,7 @@ def _grow_tree_depthwise_hostcall(
     jax.jit,
     static_argnames=(
         "num_leaves", "n_levels", "min_data_in_leaf", "has_categorical",
-        "num_bins", "mesh", "shard_axis", "sibling_subtract",
-        "vector_split", "hist_mode",
+        "num_bins", "grower",
     ),
 )
 def _grow_tree_depthwise(
@@ -1033,14 +1082,13 @@ def _grow_tree_depthwise(
     lambda_l1: float = 0.0,
     min_sum_hessian: float = 1e-3,
     num_bins: int = NUM_BINS,
-    mesh: Any = None,
-    shard_axis: Optional[str] = None,
-    sibling_subtract: bool = True,
-    vector_split: bool = True,
-    hist_mode: str = "",
+    grower: Grower = Grower("depthwise", "scatter"),
 ) -> GrownTree:
-    del hist_mode  # jit cache key only (see grow_tree_depthwise)
     from mmlspark_tpu.ops.histogram import multi_plane_histogram
+
+    mesh, shard_axis = grower.mesh, grower.shard_axis
+    sibling_subtract = grower.sibling_subtract
+    vector_split = grower.vector_split
 
     n, d = bins.shape
     L = num_leaves
@@ -1283,10 +1331,10 @@ def _grow_tree_depthwise(
              rec_is_cat, rec_catmask),
         )
 
-    from mmlspark_tpu.ops.histogram import _rows_sharded, leaf_stat_sums
+    from mmlspark_tpu.ops.histogram import leaf_stat_sums
 
     sums = leaf_stat_sums(
-        row_slot, row_stats, L, sharded=_rows_sharded(mesh, shard_axis)
+        row_slot, row_stats, L, mesh=mesh, shard_axis=shard_axis
     )
     Gl, Hl, Cl = sums[:, 0], sums[:, 1], sums[:, 2]
     leaf_values = (

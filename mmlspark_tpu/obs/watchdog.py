@@ -29,10 +29,9 @@ stall episode: a stalled counter dumps once, then waits for a tick
 before it can fire again (a 10-minute wedge is one file, not twenty).
 
 ``SIGUSR2`` (opt-in via :func:`install_sigusr2`, installed by the fleet
-CLI roles and the bench child) writes the same dump on demand —
-``bench.py``'s harvest loop signals a stalled child and collects the
-dump *before* killing it, so a stalled segment names its wedged frame in
-the BENCH json instead of just going missing.
+CLI roles) writes the same dump on demand — a supervisor signals a
+stalled child and collects the dump *before* killing it, so the stall
+names its wedged frame instead of just going missing.
 
 Fault point ``obs.watchdog_dump`` fires on every stall-dump attempt
 (chaos can fail the spool write; the stall is still counted — losing

@@ -38,12 +38,17 @@ Lowerings:
 
 Selection follows the device the call is lowered for — the caller's mesh
 when it passes one, the process's default device otherwise (see
-:func:`hist_lowering`) — and is overridable with
-``MMLSPARK_TPU_PALLAS=0|1`` and ``MMLSPARK_TPU_HIST_HOST=0|1``. A call
-with no mesh is a ONE-DEVICE call: it takes the kernel on any TPU host,
-however many chips the host has; a caller whose rows are sharded passes
-its mesh. Every choice is counted at trace time in
-``mmlspark_gbdt_hist_lowerings_total{op,lowering}``.
+:func:`hist_lowering`). ``MMLSPARK_TPU_PALLAS=0|1`` and
+``MMLSPARK_TPU_HIST_HOST=0|1`` are how a CPU process stands in for
+another platform's lowering (the benchmark's and ``chip_smoke.py``'s
+rehearsals, the tests); block sizes and the kernels' variants are
+constants or derived from the call (:func:`_use_split`,
+:func:`_hist_vmem_mb`). A call with no mesh is a ONE-DEVICE call: it takes
+the kernel on any TPU host, however many chips the host has; a caller
+whose rows are sharded passes its mesh. Every choice is counted at trace
+time in ``mmlspark_gbdt_hist_lowerings_total{op,lowering}``. Which GROWER
+a fit gets from a lowering is ``models/gbdt/treegrow.choose_grower``'s to
+say, nobody else's.
 """
 
 from __future__ import annotations
@@ -67,9 +72,8 @@ from mmlspark_tpu import obs
 # "Known issues". The flag is read ONCE at CPU client creation, so this
 # import-time update only protects processes that import this module
 # before their first dispatch — embedding code that runs jax first must
-# set it itself (tests/conftest.py and bench.py do). No effect on TPU.
-if os.environ.get("MMLSPARK_TPU_CPU_ASYNC_DISPATCH") != "1":
-    jax.config.update("jax_cpu_enable_async_dispatch", False)
+# set it itself (tests/conftest.py does). No effect on TPU.
+jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 _M_LOWERINGS = obs.counter(
     "mmlspark_gbdt_hist_lowerings_total",
@@ -89,10 +93,9 @@ NUM_BINS = 256
 # block sizes: DF features x NC rows per grid step; the one-hot block is
 # (DF, B, NC) bf16 = 8 x 256 x 512 x 2B = 2 MB VMEM by default, with rows
 # on the 128-lane dim (NC must be a multiple of 128 on real TPU; DF a
-# multiple of 8). Env-tunable (MMLSPARK_TPU_HIST_DF / _NC) so on-chip
-# sweeps need no code edits.
-_DF = int(os.environ.get("MMLSPARK_TPU_HIST_DF", "8"))
-_NC = int(os.environ.get("MMLSPARK_TPU_HIST_NC", "512"))
+# multiple of 8).
+_DF = 8
+_NC = 512
 
 
 # Scoped-VMEM ceiling handed to Mosaic for these kernels, in MB, by
@@ -116,9 +119,6 @@ def _target_device(mesh=None):
 
 
 def _hist_vmem_mb(dev=None) -> int:
-    env = os.environ.get("MMLSPARK_TPU_HIST_VMEM_MB")
-    if env is not None:
-        return int(env)
     kind = (dev or _target_device()).device_kind
     if kind not in _VMEM_LIMIT_MB:
         raise ValueError(
@@ -459,7 +459,7 @@ def _hist_split_kernel(bins_ref, stats_ref, out_ref, *, bh: int, bl: int):
 
 # the decomposed kernel's feature block (bigger blocks amortize the rhs
 # build; 32 measured within 2% of the best and halves padding waste)
-_DF_SPLIT = int(os.environ.get("MMLSPARK_TPU_HIST_SPLIT_DF", "32"))
+_DF_SPLIT = 32
 _BL_SPLIT = 8
 
 
@@ -467,29 +467,26 @@ def _use_split(num_bins: int) -> bool:
     """Decomposition pays when B is large (compare-bound); at B <= 64 the
     plain one-hot is already cheap and the split's fixed rhs cost
     (BL*6 = 48 ops/cell) stops being a win."""
-    if num_bins % _BL_SPLIT != 0 or num_bins < 2 * _BL_SPLIT:
-        # the decomposition needs bin = hi*BL + lo to tile exactly; an env
-        # force must not override that into a trace-time crash
-        return False
-    env = os.environ.get("MMLSPARK_TPU_HIST_SPLIT")
-    if env is not None:
-        return env not in ("0", "false", "")
-    return num_bins >= 128
+    return num_bins >= 128 and num_bins % _BL_SPLIT == 0
 
 
 def _plane_histogram_pallas(
     bins: jnp.ndarray, stats: jnp.ndarray, num_bins: int = NUM_BINS,
-    dev=None,
+    dev=None, split: "bool | None" = None,
 ) -> jnp.ndarray:
     """(n, d) int32 bins + (n, 3) stats -> (d * B, 3) plane via Pallas,
-    lowered for ``dev`` (default: the process's default device)."""
+    lowered for ``dev`` (default: the process's default device).
+    ``split``: the decomposed kernel or the plain one; None, what every
+    caller but the kernels' own tests passes, is :func:`_use_split`'s
+    choice for ``num_bins``."""
     import jax.experimental.pallas as pl
 
     call_kw = _pallas_call_kwargs(dev)
 
     n, d = bins.shape
     b = num_bins
-    split = _use_split(b)
+    if split is None:
+        split = _use_split(b)
     df = _DF_SPLIT if split else _DF
     d_pad = ((d + df - 1) // df) * df
     n_pad = ((n + _NC - 1) // _NC) * _NC
@@ -752,16 +749,16 @@ def multi_plane_histogram(
 
 def leaf_stat_sums(
     leaf: jnp.ndarray, stats: jnp.ndarray, num_leaves: int,
-    sharded: bool = False,
+    mesh=None, shard_axis: str | None = None,
 ) -> jnp.ndarray:
     """Per-leaf (g, h, count) totals: (n,) leaf ids + (n, 3) stats ->
     (num_leaves, 3). The growers' end-of-tree reduction — a (n,)
     scatter-add on the XLA path, one bincount pass on the host path (the
     scatters cost ~3 ms/tree at bench shapes on XLA:CPU, ~25x the host
-    kernel). ``sharded``: the caller's rows are sharded over a mesh —
-    keep the scatter (GSPMD partitions this (n,) scatter; a host callback
-    would force a gather)."""
-    if not sharded and use_host_hist():
+    kernel). ``mesh``/``shard_axis`` as in :func:`plane_histogram`: rows
+    sharded over the mesh keep the scatter (GSPMD partitions this (n,)
+    scatter; a host callback would force a gather)."""
+    if not _rows_sharded(mesh, shard_axis) and use_host_hist(mesh):
         # leaf ids are grower outputs, always in [0, num_leaves)
         return _plane_histogram_host(
             leaf[:, None].astype(jnp.int32), stats, None, num_leaves,
@@ -816,43 +813,6 @@ def _plane_histogram_shard_map(
         out_specs=P(),
         check_vma=False,
     )(bins, stats)
-
-
-# wall time of one EAGER sharded histogram build including the explicit
-# psum allreduce — the bench's hist scaling rows observe this so the
-# ICI-allreduce claim is a recorded number (in-jit builds fuse into the
-# surrounding program and cannot be timed individually)
-_M_ALLREDUCE_SECONDS = obs.histogram(
-    "mmlspark_gbdt_hist_allreduce_seconds",
-    "Wall time of one sharded histogram build including the "
-    "explicit psum allreduce (observed by eager/bench builds)",
-)
-_SHARDED_BUILD_CACHE: dict = {}
-
-
-def sharded_build_timed(
-    bins: jnp.ndarray, stats: jnp.ndarray, mesh, shard_axis: str,
-    num_bins: int = NUM_BINS,
-) -> jnp.ndarray:
-    """Eagerly run one per-shard histogram + explicit psum and record the
-    wall time into ``mmlspark_gbdt_hist_allreduce_seconds``."""
-    import time as _t
-
-    key = (mesh, shard_axis, num_bins)
-    fn = _SHARDED_BUILD_CACHE.get(key)
-    if fn is None:
-        fn = jax.jit(
-            functools.partial(
-                _plane_histogram_shard_map, mesh=mesh,
-                shard_axis=shard_axis, num_bins=num_bins,
-            )
-        )
-        _SHARDED_BUILD_CACHE[key] = fn
-    t0 = _t.perf_counter()
-    out = fn(bins, stats)
-    jax.block_until_ready(out)
-    _M_ALLREDUCE_SECONDS.observe(_t.perf_counter() - t0)
-    return out
 
 
 def _masked(stats: jnp.ndarray, mask: "jnp.ndarray | None") -> jnp.ndarray:

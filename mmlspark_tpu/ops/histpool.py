@@ -16,8 +16,7 @@ fixed control shm block and writes W single bytes (each byte IS the
 stripe id, so racing readers cannot steal each other's stripe), workers
 read 1 byte, execute, write 1 status byte back. Arena (re)mapping is
 generation-stamped inside the control block, so remaps need no extra
-roundtrip. Connections remain for startup handshake, error detail, and
-the spawn start method (where inherited pipe fds are unavailable).
+roundtrip. Connections remain for startup handshake and error detail.
 
 Life cycle: lazily forked on the first large-enough call (small calls and
 therefore most unit-test fits never start it), torn down atexit (tokens
@@ -28,7 +27,7 @@ unguarded user scripts, and startup is milliseconds. A fork gone wrong
 can only hang a child — the handshake/task timeouts turn that into a
 permanent, logged degrade to the serial kernel.
 ``MMLSPARK_TPU_HIST_WORKERS`` overrides the worker count; ``0``/``1``
-disables; ``MMLSPARK_TPU_HIST_POOL_CTX=spawn`` switches the start method.
+disables.
 
 Determinism: each (slot, feature, bin) cell is accumulated by exactly one
 worker with the same row-order ``np.bincount`` the serial kernel uses, so
@@ -50,7 +49,7 @@ log = logging.getLogger("mmlspark_tpu.histpool")
 
 # below this many (row, feature) items the roundtrip costs more than the
 # bincount itself — stay serial (also keeps unit-test fits pool-free)
-MIN_POOL_ITEMS = int(os.environ.get("MMLSPARK_TPU_HIST_POOL_MIN", "120000"))
+MIN_POOL_ITEMS = 120000
 
 _ARENAS = ("bins", "stats", "base", "out", "out0", "out1", "cand")
 _CTRL_BYTES = 4 << 20          # fixed-size control block (never regrown)
@@ -298,7 +297,7 @@ def _worker_main(
 
     fcntl.fcntl(task_fd, fcntl.F_SETFL,
                 fcntl.fcntl(task_fd, fcntl.F_GETFL) | os.O_NONBLOCK)
-    spin_s = float(os.environ.get("MMLSPARK_TPU_HIST_POOL_SPIN_S", "0.05"))
+    spin_s = 0.05
     spin_until = 0.0
     while True:
         tok = b""
@@ -372,10 +371,9 @@ class _HistPool:
         w = _workers_wanted()
         if w <= 1:
             return False
-        ctx = mp.get_context(
-            os.environ.get("MMLSPARK_TPU_HIST_POOL_CTX", "fork")
-        )
-        if ctx.get_start_method() != "fork":
+        try:
+            ctx = mp.get_context("fork")
+        except ValueError:
             # the token pipes rely on fd inheritance; without fork there
             # is no cheap transport, and the serial kernel is already
             # within ~2x of a chatty pool — stay serial

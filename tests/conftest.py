@@ -15,7 +15,7 @@ os.environ["XLA_FLAGS"] = (
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 # repo root on sys.path: `pytest` (unlike `python -m pytest`) does not add
-# the cwd, and tests import repo-root modules like tools.northstar_stream
+# the cwd, and tests import repo-root modules like tools.deploy.smoke
 import sys
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

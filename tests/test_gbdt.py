@@ -22,6 +22,7 @@ from mmlspark_tpu.models.gbdt import (
     TrainConfig,
     train,
 )
+from mmlspark_tpu.models.gbdt.treegrow import choose_grower as _THE_RULE
 
 
 def make_binary(n=600, d=8, seed=0, noise=0.1):
@@ -827,9 +828,9 @@ class TestDepthwise:
         assert b.trees[0].active.sum() <= 9
 
     def test_sibling_subtraction_equivalence(self, monkeypatch):
-        # exercise the XLA grower's env-flag variants (the host
-        # grower would otherwise front these unsharded CPU calls
-        # and make the comparison trivial)
+        # exercise the XLA grower's variants (the host grower would
+        # otherwise front these unsharded CPU calls and make the
+        # comparison trivial)
         monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
         """Sibling subtraction (default) must grow the same trees as the
         direct full-frontier build: derived left planes are parent -
@@ -839,7 +840,7 @@ class TestDepthwise:
         x, y = self._xy(n=2500, d=6, seed=3)
         outs = {}
         for flag in ("1", "0"):
-            monkeypatch.setenv("MMLSPARK_TPU_GBDT_SIBLING", flag)
+            _force_grower(monkeypatch, sibling_subtract=flag == "1")
             cfg = TrainConfig(objective="binary", num_iterations=8,
                               num_leaves=31, min_data_in_leaf=10, seed=2,
                               growth_policy="depthwise")
@@ -848,9 +849,9 @@ class TestDepthwise:
         self._assert_tree_parity(t_on, t_off, outs, x)
 
     def test_sibling_subtraction_odd_frontier(self, monkeypatch):
-        # exercise the XLA grower's env-flag variants (the host
-        # grower would otherwise front these unsharded CPU calls
-        # and make the comparison trivial)
+        # exercise the XLA grower's variants (the host grower would
+        # otherwise front these unsharded CPU calls and make the
+        # comparison trivial)
         monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
         """max_depth deeper than log2(num_leaves) makes a level's frontier
         capacity S_next = num_leaves (odd, e.g. 31): the interleaved pair
@@ -860,17 +861,21 @@ class TestDepthwise:
         x, y = self._xy(n=2500, d=6, seed=4)
         outs = {}
         for flag in ("1", "0"):
-            monkeypatch.setenv("MMLSPARK_TPU_GBDT_SIBLING", flag)
+            _force_grower(monkeypatch, sibling_subtract=flag == "1")
             cfg = TrainConfig(objective="binary", num_iterations=6,
                               num_leaves=31, min_data_in_leaf=5, seed=2,
                               growth_policy="depthwise", max_depth=8)
             outs[flag] = train(x, y, cfg)
-        self._assert_tree_parity(outs["1"].trees, outs["0"].trees, outs, x)
+        # depth 8 under a 31-leaf budget and min_data_in_leaf=5 leaves many
+        # leaves of a few rows, so most of a leaf's bins are empty and most
+        # trees have a threshold that moved across some (ROADMAP D16)
+        self._assert_tree_parity(outs["1"].trees, outs["0"].trees, outs, x,
+                                 thresholds_nearly_all_equal=False)
 
     def test_vector_split_matches_sequential(self, monkeypatch):
-        # exercise the XLA grower's env-flag variants (the host
-        # grower would otherwise front these unsharded CPU calls
-        # and make the comparison trivial)
+        # exercise the XLA grower's variants (the host grower would
+        # otherwise front these unsharded CPU calls and make the
+        # comparison trivial)
         monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
         """The vectorized level application (default) must grow trees
         IDENTICAL to the sequential fori_loop reference — gain-order,
@@ -887,7 +892,7 @@ class TestDepthwise:
                       {"categorical_features": [5]}):
             outs = {}
             for flag in ("1", "0"):
-                monkeypatch.setenv("MMLSPARK_TPU_GBDT_VECTOR_SPLIT", flag)
+                _force_grower(monkeypatch, vector_split=flag == "1")
                 cfg = TrainConfig(objective="binary", num_iterations=6,
                                   num_leaves=31, min_data_in_leaf=5, seed=2,
                                   growth_policy="depthwise", **extra)
@@ -901,9 +906,9 @@ class TestDepthwise:
                 )
 
     def test_vector_split_frozen_leaf_rows_stay_put(self, monkeypatch):
-        # exercise the XLA grower's env-flag variants (the host
-        # grower would otherwise front these unsharded CPU calls
-        # and make the comparison trivial)
+        # exercise the XLA grower's variants (the host grower would
+        # otherwise front these unsharded CPU calls and make the
+        # comparison trivial)
         monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
         """A leaf that EXITS the frontier early (too few rows to split)
         must keep its rows under the vectorized application: the
@@ -925,7 +930,7 @@ class TestDepthwise:
         y[:6] = 1.0
         outs = {}
         for flag in ("1", "0"):
-            monkeypatch.setenv("MMLSPARK_TPU_GBDT_VECTOR_SPLIT", flag)
+            _force_grower(monkeypatch, vector_split=flag == "1")
             cfg = TrainConfig(objective="binary", num_iterations=2,
                               num_leaves=16, min_data_in_leaf=5, seed=0,
                               growth_policy="depthwise")
@@ -938,16 +943,52 @@ class TestDepthwise:
             outs["1"].predict_raw(x), outs["0"].predict_raw(x), rtol=1e-6
         )
 
-    def _assert_tree_parity(self, t_on, t_off, outs, x):
+    def _assert_tree_parity(self, t_on, t_off, outs, x,
+                            thresholds_nearly_all_equal=True):
+        """Sibling subtraction on (``t_on``) against the direct build
+        (``t_off``). The two may put a split's threshold on different
+        sides of bins that hold none of its leaf's rows: there the direct
+        build has exact zeros and every such bin ties, where parent -
+        right leaves f32 rounding residue that breaks the tie. With
+        ``thresholds_nearly_all_equal`` that may touch one tree at most;
+        without it (many small leaves, so many empty bins) every differing
+        split is held to that cause: no training row of its leaf lies
+        between the two thresholds."""
         assert len(t_on) == len(t_off)
-        same = sum(
-            int(np.array_equal(a.feature, b.feature)
-                and np.array_equal(a.threshold, b.threshold))
-            for a, b in zip(t_on, t_off)
-        )
+        same_structure = same = moved = 0
+        for a, b in zip(t_on, t_off):
+            if not (np.array_equal(a.feature, b.feature)
+                    and np.array_equal(a.leaf, b.leaf)
+                    and np.array_equal(a.active, b.active)):
+                continue
+            same_structure += 1
+            same += int(np.array_equal(a.threshold, b.threshold))
+            # replay the splits on the training rows (split k sends the
+            # rows of leaf[k] with value > threshold to leaf k + 1)
+            leaf_of = np.zeros(x.shape[0], np.int64)
+            for k in np.flatnonzero(a.active):
+                v = x[:, a.feature[k]]
+                at = leaf_of == a.leaf[k]
+                lo, hi = sorted((a.threshold[k], b.threshold[k]))
+                between = at & (v > lo) & (v <= hi)
+                assert not between.any(), (
+                    f"split {k} on feature {a.feature[k]}: "
+                    f"{int(between.sum())} of the leaf's {int(at.sum())} "
+                    f"rows lie between thresholds {lo} and {hi}"
+                )
+                moved += int(lo != hi)
+                leaf_of[at & (v > a.threshold[k])] = k + 1
         # identical structure on nearly every tree (a rare f32 tie may
         # flip one split late in the boosting chain)
-        assert same >= len(t_on) - 1, f"{same}/{len(t_on)} trees identical"
+        assert same_structure >= len(t_on) - 1, (
+            f"{same_structure}/{len(t_on)} trees of one structure"
+        )
+        if thresholds_nearly_all_equal:
+            assert same >= len(t_on) - 1, f"{same}/{len(t_on)} trees identical"
+        else:
+            # the case this flag is for: were no threshold to move, the
+            # stronger assertion above would be the one to make
+            assert moved > 0
         pr_on = outs["1"].predict_raw(x)
         pr_off = outs["0"].predict_raw(x)
         np.testing.assert_allclose(pr_on, pr_off, rtol=1e-3, atol=1e-3)
@@ -1005,13 +1046,17 @@ class TestDepthwise:
         assert np.mean(np.abs(ps - pp)) < 0.01
 
 
-def _force_lossguide_grower(monkeypatch, name):
-    """Make ``treegrow.lossguide_grower`` — the one rule that chooses the
-    leaf-wise grower — answer ``name`` whatever the layout."""
+def _force_grower(monkeypatch, **fields):
+    """Replace ``treegrow.choose_grower`` — the one rule that chooses a
+    fit's grower, and the one way a test forces another — by itself with
+    ``fields`` of its answer overridden, whatever the layout."""
+    import dataclasses
+
     from mmlspark_tpu.models.gbdt import treegrow
 
     monkeypatch.setattr(
-        treegrow, "lossguide_grower", lambda mesh=None, shard_axis=None: name
+        treegrow, "choose_grower",
+        lambda *a, **kw: dataclasses.replace(_THE_RULE(*a, **kw), **fields),
     )
 
 
@@ -1021,6 +1066,113 @@ def _hist_rows_streamed():
     fam = obs.REGISTRY.snapshot().get("mmlspark_gbdt_hist_rows_total") or {}
     return sum(v for labels, v in fam.get("samples", [])
                if labels.get("kind") == "streamed")
+
+
+# what choose_grower is asked -> what it answers. Meshes by name (built in
+# the test: 8 CPU devices): "one" 1x1, "sharded" 8x1, "wide" 1x8 (several
+# devices, rows not sharded). ``lowering`` None: the rule reads it from the
+# device, i.e. from the environment a CPU process stands in with.
+_RULE_TABLE = [
+    # id, policy, voting, mesh, lowering, env, kind, lowering decided for, vector_split
+    ("lossguide-cpu", "lossguide", False, None, None, {}, "hostcall", "cpu", True),
+    ("lossguide-cpu-one-device-mesh", "lossguide", False, "one", None, {},
+     "hostcall", "cpu", True),
+    ("lossguide-scatter", "lossguide", False, None, "scatter", {},
+     "masked", "scatter", True),
+    ("lossguide-scatter-from-env", "lossguide", False, None, None,
+     {"MMLSPARK_TPU_PALLAS": "0", "MMLSPARK_TPU_HIST_HOST": "0"},
+     "masked", "scatter", True),
+    ("lossguide-pallas", "lossguide", False, None, "pallas", {},
+     "partitioned", "pallas", True),
+    ("lossguide-pallas-from-env", "lossguide", False, "one", None,
+     {"MMLSPARK_TPU_PALLAS": "1"}, "partitioned", "pallas", True),
+    ("lossguide-sharded-cpu", "lossguide", False, "sharded", None, {},
+     "masked", "cpu", True),
+    ("lossguide-sharded-pallas", "lossguide", False, "sharded", "pallas", {},
+     "masked", "pallas", True),
+    ("lossguide-several-devices-pallas", "lossguide", False, "wide", "pallas",
+     {}, "masked", "pallas", True),
+    ("depthwise-cpu", "depthwise", False, None, None, {},
+     "depthwise_hostcall", "cpu", False),
+    ("depthwise-scatter", "depthwise", False, None, "scatter", {},
+     "depthwise", "scatter", False),
+    ("depthwise-pallas-on-a-cpu", "depthwise", False, "one", "pallas", {},
+     "depthwise", "pallas", False),
+    ("depthwise-sharded-cpu", "depthwise", False, "sharded", None, {},
+     "depthwise", "cpu", False),
+    ("voting-sharded", "lossguide", True, "sharded", None, {},
+     "voting", "cpu", True),
+    ("voting-on-one-shard-falls-back", "lossguide", True, "one", None, {},
+     "hostcall", "cpu", True),
+    ("voting-without-a-mesh-falls-back", "lossguide", True, None, "pallas", {},
+     "partitioned", "pallas", True),
+]
+
+
+@pytest.mark.parametrize(
+    "policy,voting,mesh_name,lowering,env,kind,decided_for,vector_split",
+    [row[1:] for row in _RULE_TABLE], ids=[row[0] for row in _RULE_TABLE],
+)
+def test_one_rule_chooses_the_grower_from_the_layout(
+    monkeypatch, policy, voting, mesh_name, lowering, env, kind, decided_for,
+    vector_split,
+):
+    """The rows a TPU's devices give (one chip: partitioned; four: masked;
+    level-wise: vector_split on) are in tests/test_tpu_compile.py, where
+    the compile-only topology lives."""
+    import jax
+    from jax.sharding import Mesh
+
+    from mmlspark_tpu.models.gbdt.treegrow import Grower
+    from mmlspark_tpu.parallel.mesh import DATA_AXIS
+
+    for var in ("MMLSPARK_TPU_PALLAS", "MMLSPARK_TPU_HIST_HOST"):
+        monkeypatch.delenv(var, raising=False)
+    for var, value in env.items():
+        monkeypatch.setenv(var, value)
+    devs = np.array(jax.devices())
+    assert devs.size == 8 and devs[0].platform == "cpu"
+    mesh = {
+        None: None,
+        "one": Mesh(devs[:1].reshape(1, 1), (DATA_AXIS, "model")),
+        "sharded": Mesh(devs.reshape(-1, 1), (DATA_AXIS, "model")),
+        "wide": Mesh(devs.reshape(1, -1), (DATA_AXIS, "model")),
+    }[mesh_name]
+    got = _THE_RULE(policy, voting=voting, mesh=mesh, shard_axis=DATA_AXIS,
+                    lowering=lowering)
+    # the mesh is carried as given, its axis only with it; siblings always
+    assert got == Grower(
+        kind, decided_for, mesh, DATA_AXIS if mesh is not None else None,
+        sibling_subtract=True, vector_split=vector_split,
+    )
+    hash(got)   # a static argument of the round programs
+
+
+def test_fits_under_two_growers_do_not_share_a_round_program(monkeypatch):
+    """The grower — its kind, and the lowering it was decided for — is part
+    of a round program's key: two fits of equal shapes under different
+    ones trace two programs (flipping the lowering between fits must never
+    reuse the other's), and a second fit under the same one traces none."""
+    import importlib
+
+    T = importlib.import_module("mmlspark_tpu.models.gbdt.train")
+    monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
+    rng = np.random.default_rng(17)
+    x = rng.normal(size=(777, 5)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float64)
+    cfg = TrainConfig(objective="binary", num_iterations=2, num_leaves=5,
+                      min_data_in_leaf=5, seed=0)
+    models = []
+    for fields in ({"kind": "masked"},
+                   {"kind": "masked", "lowering": "pallas"},
+                   {"kind": "partitioned"}):
+        _force_grower(monkeypatch, **fields)
+        before = T._scan_chunk._cache_size()
+        models.append(train(x, y, cfg, shard=False))
+        assert T._scan_chunk._cache_size() == before + 1, fields
+        train(x, y, cfg, shard=False)
+        assert T._scan_chunk._cache_size() == before + 1, fields
+    assert models[0].to_model_string() == models[1].to_model_string()
 
 
 class TestPartitionedGrower:
@@ -1034,7 +1186,7 @@ class TestPartitionedGrower:
 
         import jax.numpy as jnp
 
-        from mmlspark_tpu.models.gbdt.treegrow import grow_tree
+        from mmlspark_tpu.models.gbdt.treegrow import Grower, grow_tree
 
         # pin the masked reference to the XLA scatter lowering: this suite
         # validates the PARTITIONED grower against the masked XLA grower;
@@ -1052,8 +1204,10 @@ class TestPartitionedGrower:
         args = (jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(w))
         cm = jnp.asarray(cat) if cat is not None else None
         try:
-            a = grow_tree(*args, categorical_mask=cm, **kw)
-            b = grow_tree(*args, categorical_mask=cm, partitioned=True, **kw)
+            a = grow_tree(*args, categorical_mask=cm,
+                          grower=Grower("masked", "scatter"), **kw)
+            b = grow_tree(*args, categorical_mask=cm,
+                          grower=Grower("partitioned", "scatter"), **kw)
         finally:
             if prev_env is None:
                 os.environ.pop("MMLSPARK_TPU_HIST_HOST", None)
@@ -1176,35 +1330,6 @@ class TestPartitionedGrower:
             m = np.asarray(a.hist_rows).astype(np.int64)
             assert m[0] * 4096 + m[1] == n * leaves
 
-    def test_one_rule_chooses_the_grower_from_the_layout(self, monkeypatch):
-        import jax
-        from jax.sharding import Mesh
-
-        from mmlspark_tpu.models.gbdt import treegrow
-        from mmlspark_tpu.parallel.mesh import DATA_AXIS
-
-        for var in ("MMLSPARK_TPU_PALLAS", "MMLSPARK_TPU_HIST_HOST"):
-            monkeypatch.delenv(var, raising=False)
-        devs = np.array(jax.devices())
-        assert devs.size >= 2 and devs[0].platform == "cpu"
-        sharded = Mesh(devs.reshape(-1, 1), (DATA_AXIS, "model"))
-        one = Mesh(devs[:1].reshape(1, 1), (DATA_AXIS, "model"))
-        unsharded = Mesh(devs.reshape(1, -1), (DATA_AXIS, "model"))
-        rule = treegrow.lossguide_grower
-        # the CPU as it comes: the whole tree behind one host callback
-        assert rule() == "hostcall"
-        assert rule(one, DATA_AXIS) == "hostcall"
-        assert rule(sharded, DATA_AXIS) == "masked"
-        # the Pallas lowering, as on a TPU: partitioned on one device only
-        monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "1")
-        assert rule() == "partitioned"
-        assert rule(one, DATA_AXIS) == "partitioned"
-        assert rule(sharded, DATA_AXIS) == "masked"
-        assert rule(unsharded, DATA_AXIS) == "masked"   # several devices
-        monkeypatch.setenv("MMLSPARK_TPU_PALLAS", "0")
-        monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
-        assert rule() == "masked"                        # XLA scatter
-
     def test_training_takes_the_rules_grower(self, monkeypatch):
         """The trainer asks the rule: on one device with the Pallas
         lowering (here the interpreter) a fit streams buckets, not n rows
@@ -1254,7 +1379,8 @@ class TestPartitionedGrower:
             return treegrow.grow_tree(
                 b, g, v, v, num_leaves=L, lambda_l2=1.0, min_gain=0.0,
                 learning_rate=0.1, feature_mask=jnp.ones((d,), jnp.float32),
-                min_data_in_leaf=1, num_bins=B, partitioned=True,
+                min_data_in_leaf=1, num_bins=B,
+                grower=treegrow.Grower("partitioned", lowering),
                 categorical_mask=jnp.asarray([True, False, False, False]),
             )
 
@@ -1339,10 +1465,10 @@ class TestPartitionedGrower:
         cfg = TrainConfig(objective="binary", num_iterations=5, num_leaves=15,
                           min_data_in_leaf=5, seed=0)
         s0 = _hist_rows_streamed()
-        _force_lossguide_grower(monkeypatch, "partitioned")
+        _force_grower(monkeypatch, kind="partitioned")
         b_part = train(x, y, cfg, shard=False)
         s1 = _hist_rows_streamed()
-        _force_lossguide_grower(monkeypatch, "masked")
+        _force_grower(monkeypatch, kind="masked")
         b_mask = train(x, y, cfg, shard=False)
         s2 = _hist_rows_streamed()
         pa = sigmoid(b_part.predict_raw(x))
@@ -1456,9 +1582,9 @@ class TestPartitionedInteractions:
         x, y = self._xy()
         cfg = TrainConfig(objective="binary", num_iterations=6, num_leaves=15,
                           min_data_in_leaf=5, seed=0, boosting_type="goss")
-        _force_lossguide_grower(monkeypatch, "partitioned")
+        _force_grower(monkeypatch, kind="partitioned")
         b_part = train(x, y, cfg, shard=False)
-        _force_lossguide_grower(monkeypatch, "masked")
+        _force_grower(monkeypatch, kind="masked")
         b_mask = train(x, y, cfg, shard=False)
         pa = sigmoid(b_part.predict_raw(x))
         pb = sigmoid(b_mask.predict_raw(x))
@@ -1474,9 +1600,9 @@ class TestPartitionedInteractions:
         cfg = TrainConfig(objective="regression", num_iterations=6,
                           num_leaves=15, min_data_in_leaf=5, seed=0,
                           bagging_fraction=0.7, bagging_freq=1)
-        _force_lossguide_grower(monkeypatch, "partitioned")
+        _force_grower(monkeypatch, kind="partitioned")
         b_part = train(x, yr, cfg, shard=False)
-        _force_lossguide_grower(monkeypatch, "masked")
+        _force_grower(monkeypatch, kind="masked")
         b_mask = train(x, yr, cfg, shard=False)
         pa, pb = b_part.predict_raw(x), b_mask.predict_raw(x)
         assert np.mean(np.abs(pa - pb)) < 1e-3 * max(1.0, np.abs(pb).mean())
@@ -1491,7 +1617,7 @@ class TestPartitionedInteractions:
         rng = np.random.default_rng(11)
         x = rng.normal(size=(4000, 6)).astype(np.float32)
         y = x[:, 0] * 3.0 + rng.normal(size=4000) * (1.0 + np.abs(x[:, 1]))
-        _force_lossguide_grower(monkeypatch, "partitioned")
+        _force_grower(monkeypatch, kind="partitioned")
         cfg = TrainConfig(objective="quantile", alpha=0.8, num_iterations=40,
                           num_leaves=15, min_data_in_leaf=10, seed=0)
         b = train(x, y, cfg, shard=False)

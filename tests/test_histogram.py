@@ -132,7 +132,7 @@ def test_multi_plane_histogram_num_bins_variants():
     )
 
 
-def test_plain_and_split_pallas_kernels_agree(monkeypatch):
+def test_plain_and_split_pallas_kernels_agree():
     """Both Pallas lowerings of the 256-bin plane (plain one-hot and the
     decomposed hi/lo kernel) must produce the same sums — the plain kernel
     stays the production path for B < 128, so it needs its own coverage
@@ -143,27 +143,27 @@ def test_plain_and_split_pallas_kernels_agree(monkeypatch):
     n, d = 1500, 6
     bins = jnp.asarray(rng.integers(0, 256, size=(n, d)).astype(np.int32))
     stats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_SPLIT", "0")
-    plain = np.asarray(H._plane_histogram_pallas(bins, stats, 256))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_SPLIT", "1")
+    assert H._use_split(256)
+    plain = np.asarray(H._plane_histogram_pallas(bins, stats, 256, split=False))
     split = np.asarray(H._plane_histogram_pallas(bins, stats, 256))
     np.testing.assert_allclose(plain, split, rtol=1e-4, atol=1e-3)
     ref = np.asarray(H._plane_histogram_scatter(bins, stats, 256))
     np.testing.assert_allclose(split, ref, rtol=1e-4, atol=1e-3)
 
 
-def test_split_force_safe_on_indivisible_bins(monkeypatch):
-    """MMLSPARK_TPU_HIST_SPLIT=1 must not crash when num_bins can't tile
-    the decomposition (e.g. 63): it falls back to the plain kernel."""
+@pytest.mark.parametrize("num_bins", [63, 132])
+def test_a_bin_count_the_decomposition_cannot_tile_takes_the_plain_kernel(num_bins):
+    """bin = hi * 8 + lo must tile exactly: a narrow count (63) and a wide
+    one that is no multiple of 8 (132) get the plain kernel, not a
+    trace-time crash."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(12)
-    bins = jnp.asarray(rng.integers(0, 63, size=(500, 4)).astype(np.int32))
+    bins = jnp.asarray(rng.integers(0, num_bins, size=(500, 4)).astype(np.int32))
     stats = jnp.asarray(rng.normal(size=(500, 3)).astype(np.float32))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_SPLIT", "1")
-    assert not H._use_split(63)
-    got = np.asarray(H._plane_histogram_pallas(bins, stats, 63))
-    ref = np.asarray(H._plane_histogram_scatter(bins, stats, 63))
+    assert not H._use_split(num_bins)
+    got = np.asarray(H._plane_histogram_pallas(bins, stats, num_bins))
+    ref = np.asarray(H._plane_histogram_scatter(bins, stats, num_bins))
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
 
 
@@ -218,8 +218,7 @@ def test_multi_df_vmem_accounting(monkeypatch):
     Expected values below are hand-computed, NOT re-derived through the
     implementation's formula: at NC=512, B=256 the per-DF resident set is
     DF*256*(512*2 + S*48) bytes = DF * (256 KiB + S * 12 KiB)."""
-    if (H._NC, H._DF) != (512, 8):
-        pytest.skip("hand-computed table assumes default NC/DF tiles")
+    assert (H._NC, H._DF) == (512, 8)   # what the table was computed for
     # default ceiling 96 MB -> budget 64 MB:
     #   S=32:  DF=32 -> 32*(0.25+0.375)MiB*32 = 20 MiB  -> fits, picked
     assert H._multi_df(32, 256, 64) == 32
@@ -227,10 +226,11 @@ def test_multi_df_vmem_accounting(monkeypatch):
     assert H._multi_df(256, 256, 64) == 16
     #   S=1024: even DF=8 is 8*(0.25+12) = 98 MiB > 64 -> no block fits
     assert H._multi_df(1024, 256, 64) is None
-    # the knob and the budget move together: restoring the Mosaic default
-    # ceiling (16 MB -> 10 MiB budget) must reject the DF=32/S=32 pick
-    # that compile-failed on chip (resident 20 MiB); DF=16 (10 MiB) fits
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_VMEM_MB", "16")
+    # the ceiling and the budget move together: at the Mosaic default
+    # ceiling (16 MB -> 10 MiB budget) the DF=32/S=32 pick that
+    # compile-failed on chip (resident 20 MiB) is rejected; DF=16 (10 MiB)
+    # fits
+    monkeypatch.setitem(H._VMEM_LIMIT_MB, "cpu", 16)
     assert H._multi_df(32, 256, 64) == 16
 
 

@@ -114,16 +114,6 @@ def test_write_csv_roundtrip(tmp_path):
     assert len(df) == 20 and set(df.columns) == {"x", "i"}
 
 
-def test_northstar_config_launches():
-    """The 1M-row north-star workload is LAUNCHABLE: same code path, tiny
-    override (rows/size shrunk, trained zoo backbone)."""
-    import tools.northstar_stream as ns
-
-    res = ns.run(rows=96, chunk=32, size=32, model="ResNet8_Digits", batch=16)
-    assert res["rows"] == 96
-    assert res["images_per_sec"] > 0
-
-
 def test_stream_csv_serial_consolidator_semantics(tmp_path, monkeypatch):
     """Consolidation holds under SERIAL partition execution too: exactly one
     output partition carries all rows."""

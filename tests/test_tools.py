@@ -1,5 +1,7 @@
 """Ops-tool contracts (tools/): the source linters that tier-1 runs."""
 
+import pytest
+
 
 def test_metric_names_follow_convention():
     """mmlspark_<subsystem>_<name>_<unit> over the whole tree — drift in
@@ -109,3 +111,28 @@ def test_wire_rule_linter_catches_untested_kind(tmp_path):
     untested, n = lint_chaos_rules(test_paths=[str(tests_file)])
     assert n >= 6
     assert untested == ["truncate_rst"]
+
+
+# The histogram kernels' and growers' environment knobs that had one value
+# in use (PR 29): block sizes, kernel variants, pool tuning. Each is now a
+# constant or derived from the call; none may come back as a read.
+_REMOVED_KNOBS = (
+    "HIST_DF", "HIST_NC", "HIST_SPLIT_DF", "HIST_SPLIT", "HIST_VMEM_MB",
+    "GBDT_SIBLING", "GBDT_VECTOR_SPLIT", "CPU_ASYNC_DISPATCH",
+    "HIST_POOL_MIN", "HIST_POOL_SPIN_S", "HIST_POOL_CTX",
+)
+
+
+@pytest.mark.parametrize("knob", _REMOVED_KNOBS)
+def test_removed_histogram_knob_is_read_nowhere(knob):
+    import pathlib
+    import re
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    name = re.compile("MMLSPARK_TPU_" + knob + r"(?![A-Z0-9_])")
+    files = [root / "chip_smoke.py", root / "__graft_entry__.py"]
+    for sub in ("mmlspark_tpu", "tools"):
+        files += sorted((root / sub).rglob("*.py"))
+    assert len(files) > 100
+    hits = [str(p.relative_to(root)) for p in files if name.search(p.read_text())]
+    assert not hits, hits

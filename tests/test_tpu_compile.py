@@ -108,7 +108,34 @@ def test_default_backend_stays_cpu_while_lowering_for_tpu(v5e):
     assert kw["compiler_params"].vmem_limit_bytes == 96 << 20
 
 
-def test_partitioned_grower_compiles_for_one_v5e_chip(v5e, monkeypatch):
+@pytest.mark.parametrize(
+    "policy,voting,chips,kind,vector_split",
+    [
+        ("lossguide", False, 1, "partitioned", True),
+        ("lossguide", False, 4, "masked", True),
+        ("depthwise", False, 1, "depthwise", True),
+        ("depthwise", False, 4, "depthwise", True),
+        ("lossguide", True, 4, "voting", True),
+        ("lossguide", True, 1, "partitioned", True),
+    ],
+    ids=["lossguide-one_chip", "lossguide-four_chips", "depthwise-one_chip",
+         "depthwise-four_chips", "voting-four_chips",
+         "voting-one_chip-falls-back"],
+)
+def test_the_rule_reads_a_tpu_mesh(v5e, policy, voting, chips, kind, vector_split):
+    """``choose_grower``'s rows for the chip (its CPU rows are in
+    tests/test_gbdt.py): the lowering is read off the mesh's device, not
+    the process's default backend, and nothing in the environment."""
+    from mmlspark_tpu.models.gbdt.treegrow import Grower, choose_grower
+
+    mesh = Mesh(np.array(v5e[:chips]), ("data",))
+    assert choose_grower(policy, voting=voting, mesh=mesh, shard_axis="data") == Grower(
+        kind, "pallas", mesh, "data", sibling_subtract=True,
+        vector_split=vector_split,
+    )
+
+
+def test_partitioned_grower_compiles_for_one_v5e_chip(v5e):
     """The one-chip leaf-wise grower as the chip gets it: uint8 bins, every
     bucket of both switches with its own Mosaic kernel, the gathers through
     ``order`` and the scatter that partitions a bucket (XLA:TPU refuses
@@ -116,12 +143,9 @@ def test_partitioned_grower_compiles_for_one_v5e_chip(v5e, monkeypatch):
     per bucket."""
     from mmlspark_tpu.models.gbdt import treegrow
 
-    # the leaf sums of an unsharded call ask the process's default device
-    # (the CPU here) for their lowering: keep the host callback out
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_HOST", "0")
     mesh = Mesh(np.array(v5e[:1]), ("data",))
-    assert treegrow.lossguide_grower(mesh, "data") == "partitioned"
-    assert treegrow.lossguide_grower(Mesh(np.array(v5e), ("data",)), "data") == "masked"
+    grower = treegrow.choose_grower(mesh=mesh, shard_axis="data")
+    assert grower.kind == "partitioned"
     n, d = RAGGED, 28
     sizes = treegrow._range_sizes(n)
 
@@ -129,8 +153,7 @@ def test_partitioned_grower_compiles_for_one_v5e_chip(v5e, monkeypatch):
         return treegrow.grow_tree(
             b, g, h, w, num_leaves=15, lambda_l2=0.0, min_gain=0.0,
             learning_rate=0.1, feature_mask=jnp.ones((d,), jnp.float32),
-            min_data_in_leaf=0, min_sum_hessian=100.0, mesh=mesh,
-            shard_axis="data",
+            min_data_in_leaf=0, min_sum_hessian=100.0, grower=grower,
         )
 
     rows = _spec((n,), jnp.float32, mesh)
