@@ -18,16 +18,124 @@ Semantics:
   than once (like a Spark source, unlike a Python generator).
 - ``fit`` on unbounded data is out of scope, as in SparkML: estimators
   need a bounded DataFrame (``materialize`` a sample for that).
+
+The look-ahead of a transformed stream. Iterating ``transform(stage)`` —
+and only that: a bare source and ``map_chunks`` stay one chunk at a time —
+keeps ``_CHUNKS_IN_FLIGHT`` chunks between the source and the consumer: a
+feeder thread pulls chunk k+1 and hands ``stage.transform`` of it to a
+worker while chunk k's transform still runs, and the consumer gets the
+results strictly in the source's order. A stage that feeds a device
+(``XLAModel.apply_batch``: its calls take turns at dispatching and give the
+turn up before they drain) then has chunk k+1's first batch on its way
+while chunk k's last batches compute; without the look-ahead the device
+stands idle at every chunk's border for as long as that transfer takes.
+What follows from it:
+
+- Nothing is pulled or transformed before iteration starts. The source is
+  pulled by the feeder alone, in order, and never more than
+  ``_CHUNKS_IN_FLIGHT`` chunks beyond what the consumer has been handed.
+  Chunk k is handed over as soon as its transform is done, also while the
+  source blocks on a later chunk.
+- A stage's ``transform`` may run on two threads at once, on different
+  chunks — the contract ``DataFrame.map_partitions(parallel=True)`` already
+  holds a stage's partition function to.
+- A sink that feeds its own source (a feedback loop) sees the source read
+  up to ``_CHUNKS_IN_FLIGHT`` chunks ahead of what it has been handed; so
+  does ``first()`` or a ``materialize(max_rows=)`` that stops early: the
+  chunks in flight are transformed and dropped.
+- A failure in chunk k's transform (or in the source's pull of it) is
+  raised where chunk k would have been handed over. Closing the iterator
+  early stops the feeder, waits for the transforms in flight and closes
+  the upstream generator on the feeder's thread; only a feeder that is
+  inside a pull at that moment is left to finish it (it cannot be
+  interrupted there) and closes the generator when the pull returns.
 """
 
 from __future__ import annotations
 
+import concurrent.futures as _futures
 import os
+import queue
+import threading
 from typing import Any, Callable, Iterator, Optional, Sequence
 
 import numpy as np
 
 from mmlspark_tpu.core.dataframe import DataFrame
+
+# chunks of a transformed stream between the source and the consumer. Two:
+# one whose transform holds the device and one whose first batch is already
+# staged behind it, which is all a chunk's border needs; a third would hold
+# another chunk in host memory for nothing the chip runs show (PERF.md,
+# Findings, PR 30)
+_CHUNKS_IN_FLIGHT = 2
+_END = object()
+
+
+def _in_flight(src: Callable[[], Iterator[DataFrame]],
+               fn: Callable[[DataFrame], DataFrame]) -> Iterator[DataFrame]:
+    """``fn`` of every chunk of ``src()``, in order, ``_CHUNKS_IN_FLIGHT``
+    of them pulled and being transformed at a time (the module's docstring
+    has the contract)."""
+    slots = threading.Semaphore(_CHUNKS_IN_FLIGHT)  # freed as the consumer is handed a chunk
+    results: queue.SimpleQueue = queue.SimpleQueue()  # futures in source order, then None
+    lock = threading.Lock()
+    state = {"stop": False, "pulling": False}
+    workers = _futures.ThreadPoolExecutor(
+        max_workers=_CHUNKS_IN_FLIGHT, thread_name_prefix="stream-transform")
+
+    def begin_pull() -> bool:
+        with lock:
+            state["pulling"] = not state["stop"]
+            return state["pulling"]
+
+    def end_pull() -> bool:
+        with lock:
+            state["pulling"] = False
+            return not state["stop"]
+
+    def feed() -> None:
+        it = None
+        try:
+            it = src()
+            while True:
+                slots.acquire()
+                if not begin_pull():
+                    return
+                try:
+                    chunk = next(it, _END)
+                finally:
+                    wanted = end_pull()
+                if chunk is _END or not wanted:
+                    return
+                results.put(workers.submit(fn, chunk))
+        except BaseException as e:  # noqa: BLE001 - the source's failure, raised in its place
+            failed: _futures.Future = _futures.Future()
+            failed.set_exception(e)
+            results.put(failed)
+        finally:
+            if hasattr(it, "close"):
+                it.close()
+            results.put(None)
+
+    feeder = threading.Thread(target=feed, name="stream-feed", daemon=True)
+    feeder.start()
+    try:
+        while True:
+            result = results.get()
+            if result is None:
+                return
+            chunk = result.result()
+            slots.release()
+            yield chunk
+    finally:
+        with lock:
+            state["stop"] = True
+            in_pull = state["pulling"]
+        slots.release()
+        if not in_pull:
+            feeder.join()
+        workers.shutdown(wait=True)
 
 
 class StreamingDataFrame:
@@ -142,8 +250,11 @@ class StreamingDataFrame:
         return StreamingDataFrame(source)
 
     def transform(self, stage: Any) -> "StreamingDataFrame":
-        """Lazily apply a fitted Transformer/PipelineModel chunk-by-chunk."""
-        return self.map_chunks(stage.transform)
+        """Lazily apply a fitted Transformer/PipelineModel chunk-by-chunk,
+        ``_CHUNKS_IN_FLIGHT`` chunks at a time and in order (the module's
+        docstring says what a stage and a sink may assume)."""
+        src = self._source
+        return StreamingDataFrame(lambda: _in_flight(src, stage.transform))
 
     # -- consumption ---------------------------------------------------------
 

@@ -19,6 +19,9 @@ minibatches through the native eval API per partition
 
 from __future__ import annotations
 
+import collections
+import concurrent.futures as _futures
+import threading
 from typing import Any, Callable, Optional
 
 import jax
@@ -36,6 +39,61 @@ from mmlspark_tpu.core.params import (
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.parallel.mesh import get_mesh
 from mmlspark_tpu.parallel.sharding import pad_batch, replicate, shard_batch
+
+_M_CALLS = obs.counter(
+    "mmlspark_xla_model_calls_total",
+    "apply_batch calls by what their first dispatch found on the device: "
+    "overlapped = a batch of an earlier call on the same model still in "
+    "flight, cold = nothing",
+    labels=("start",),
+)
+
+
+def _to_host(y: Any, landed: threading.Event) -> np.ndarray:
+    """Fetch one batch's result; on the host (or failed), its slot of the
+    window is free."""
+    try:
+        return np.asarray(y)
+    finally:
+        landed.set()
+
+
+class _Turns:
+    """A lock whose waiters are served in the order they arrived."""
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._next = 0      # the ticket the next arrival draws
+        self._serving = 0   # the ticket whose holder dispatches now
+
+    def acquire(self) -> None:
+        with self._cond:
+            ticket = self._next
+            self._next += 1
+            while self._serving != ticket:
+                self._cond.wait()
+
+    def release(self) -> None:
+        with self._cond:
+            self._serving += 1
+            self._cond.notify_all()
+
+
+class _Feed:
+    """What one model's calls share of the way to the device. Calls take
+    turns at dispatching, so a second caller's batches queue behind all of
+    the first's and never between them; ``window`` holds, oldest first, one
+    event for each batch dispatched and not yet known to be on the host
+    (set when its fetch ends), and only the holder of the turn touches it;
+    one fetcher thread hands results back in dispatch order, whichever call
+    they belong to."""
+
+    def __init__(self) -> None:
+        self.turns = _Turns()
+        self.window: collections.deque = collections.deque()
+        self.fetcher = _futures.ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="xla-model-fetch")
+        self.lock = threading.Lock()  # the weights' one copy on the device
 
 
 class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
@@ -64,6 +122,7 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
         self._jit_cache: dict = {}
         self._dev_vars: Any = None
         self._dev_vars_src: Any = None
+        self._feed = _Feed()
 
     @classmethod
     def from_flax(
@@ -91,10 +150,11 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
 
     def _device_variables(self, mesh: Any) -> Any:
         vs = self.get_or_fail("variables")
-        if self._dev_vars is None or self._dev_vars_src is not vs:
-            self._dev_vars = replicate(vs, mesh)
-            self._dev_vars_src = vs
-        return self._dev_vars
+        with self._feed.lock:
+            if self._dev_vars is None or self._dev_vars_src is not vs:
+                self._dev_vars = replicate(vs, mesh)
+                self._dev_vars_src = vs
+            return self._dev_vars
 
     def _compiled(self, shape: tuple, mesh: Any) -> Callable:
         key = (shape, id(mesh))
@@ -113,13 +173,14 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
                     out = out[node]
                 return out
 
-            fn = jax.jit(run)
-            self._jit_cache[key] = fn
+            # two callers that miss at once keep one program
+            fn = self._jit_cache.setdefault(key, jax.jit(run))
         return fn
 
-    # how many minibatches may be in flight on device at once: JAX's async
-    # dispatch then overlaps host staging of batch i+1..i+k with compute of
-    # batch i, while bounding live HBM for inputs+outputs
+    # how many minibatches of this model may be in flight on device at once,
+    # whichever calls they belong to: JAX's async dispatch then overlaps host
+    # staging of batch i+1..i+k with compute of batch i, while bounding live
+    # HBM for inputs+outputs
     _MAX_IN_FLIGHT = 4
 
     def apply_batch(self, x: np.ndarray, batch_size: Optional[int] = None) -> np.ndarray:
@@ -133,14 +194,22 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
         ``input_dtype=None``; what a row carries beside its data (its
         length) rides as a trailing column.
 
-        Double-buffered: the main thread ONLY stages + dispatches (upload of
-        batch k+1 streams while batch k computes), and result fetches run on
-        a dedicated thread so a blocking device-to-host copy never
+        Double-buffered: the calling thread ONLY stages + dispatches (upload
+        of batch k+1 streams while batch k computes), and result fetches run
+        on the model's fetcher thread so a blocking device-to-host copy never
         serializes with the next dispatch (CNTKModel.scala:515-520 batches
         for the same keep-the-accelerator-busy reason). The in-flight
-        window bounds live HBM and applies backpressure."""
-        import concurrent.futures as _futures
+        window bounds live HBM and applies backpressure.
 
+        Safe to call from several threads, and worth it: the turn at
+        dispatching and the window are the model's (``_Feed``), not the
+        call's. A call gives up its turn BEFORE it drains, so the next
+        call's first batch is staged and queued while this call's last
+        batches still compute, and the device's queue does not run empty
+        between two calls (a stream's chunks, io/stream.py). Each call
+        returns its own rows in its own order; a lone call finds the turn
+        free and the window empty."""
+        feed = self._feed
         # spans time the host in each call and add no synchronisation: when
         # the device started is the device trace's to say
         with obs.span("xla_model.apply_batch") as sp:
@@ -154,23 +223,40 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
                 fn = self._compiled(padded[:bs].shape, mesh)
             sp.set_attr("rows", int(n))
             sp.set_attr("batches", padded.shape[0] // bs)
-            outs: list = []
-            pending: list = []
-            # one fetcher thread keeps results ordered; np.asarray releases
-            # the GIL while it waits on the transfer, so dispatch continues
-            with _futures.ThreadPoolExecutor(max_workers=1) as fetcher:
+            mine: list = []
+            with obs.span("xla_model.turn"):
+                feed.turns.acquire()
+            try:
                 for i in range(0, padded.shape[0], bs):
                     batch = padded[i: i + bs]
                     with obs.span("xla_model.stage", attrs={"bytes": batch.nbytes}):
                         chunk = shard_batch(batch, mesh)
                     with obs.span("xla_model.dispatch"):
+                        if not mine:
+                            # fetches finish in dispatch order: what is left
+                            # after the finished ones is still on the device
+                            while feed.window and feed.window[0].is_set():
+                                feed.window.popleft()
+                            overlapped = bool(feed.window)
+                            sp.set_attr("overlapped", overlapped)
+                            _M_CALLS.labels(start="overlapped" if overlapped else "cold").inc()
                         y = fn(vs, chunk)  # async dispatch, no host sync
-                        pending.append(fetcher.submit(np.asarray, y))
-                    if len(pending) >= self._MAX_IN_FLIGHT:
+                        landed = threading.Event()
+                        mine.append(feed.fetcher.submit(_to_host, y, landed))
+                        feed.window.append(landed)
+                        # the device keeps a batch for as long as it needs it;
+                        # a call that kept its last one by name through its
+                        # drain would hold a fifth while the next call stages
+                        del chunk, y
+                    if len(feed.window) >= self._MAX_IN_FLIGHT:
+                        # the oldest may be an earlier call's: waited for
+                        # here, collected (and its failure raised) there
                         with obs.span("xla_model.backpressure"):
-                            outs.append(pending.pop(0).result())
-                with obs.span("xla_model.drain"):
-                    outs.extend(f.result() for f in pending)
+                            feed.window.popleft().wait()
+            finally:
+                feed.turns.release()
+            with obs.span("xla_model.drain"):
+                outs = [f.result() for f in mine]
             with obs.span("xla_model.concat"):
                 return np.concatenate(outs, axis=0)[:n]
 
@@ -188,6 +274,7 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
             q[oc] = self.apply_batch(x)
             return q
 
-        # partitions run sequentially: there is one device mesh; overlap
-        # comes from async dispatch inside JAX, not host threads
+        # partitions run sequentially: there is one device mesh; within a
+        # call overlap comes from async dispatch inside JAX, and between
+        # calls from apply_batch's turns, for a caller that has two to make
         return df.map_partitions(fn, parallel=False)

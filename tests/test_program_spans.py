@@ -205,7 +205,9 @@ def test_transform_yields_the_span_tree(fresh_obs):
     batches = apply.attrs["batches"]
     assert apply.attrs["rows"] == rows and batches == 6
     kids = _children(tree, apply)
-    assert kids[0] == "xla_model.prepare"
+    # a lone call: the parent's sequence, and the wait for the turn a leaf
+    assert kids[:2] == ["xla_model.prepare", "xla_model.turn"]
+    assert apply.attrs["overlapped"] is False
     assert kids[-2:] == ["xla_model.drain", "xla_model.concat"]
     assert kids.count("xla_model.stage") == batches
     assert kids.count("xla_model.dispatch") == batches

@@ -47,7 +47,7 @@ def test_a_batch_size_per_call_is_a_compiled_shape_per_bucket(rng, length, batch
     mesh = get_mesh()
     assert set(m._jit_cache) == {((batch, length + 1), id(mesh))}
     span = [s for s in obs.recent_spans() if s.name == "xla_model.apply_batch"][-1]
-    assert span.attrs == {"rows": 2 * batch + 3, "batches": 3}
+    assert span.attrs == {"rows": 2 * batch + 3, "batches": 3, "overlapped": False}
     # the stage's own batch size still serves a call that names none
     np.testing.assert_array_equal(m.apply_batch(packed), want)
     assert set(m._jit_cache) == {((batch, length + 1), id(mesh)), ((16, length + 1), id(mesh))}
@@ -112,7 +112,7 @@ def test_image_featurizer_compiled_shape_and_call_sequence_are_what_they_were():
              if s.name.startswith(("featurize.", "xla_model."))]
     assert names == [
         "featurize.partition", "featurize.coerce", "xla_model.apply_batch",
-        "xla_model.prepare",
+        "xla_model.prepare", "xla_model.turn",
         "xla_model.stage", "xla_model.dispatch", "xla_model.stage", "xla_model.dispatch",
         "xla_model.stage", "xla_model.dispatch", "xla_model.drain", "xla_model.concat"]
     assert names.count("xla_model.backpressure") == 0
