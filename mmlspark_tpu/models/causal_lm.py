@@ -1,4 +1,5 @@
-"""Causal language model as a corpus scorer — the LFM2-MoE family.
+"""Causal language model as a corpus scorer: one forward pass, built from
+the configuration's keys (``lfm2_moe`` and the ``KeyeVL2`` language model).
 
 A DataFrame column of token-id arrays of unequal length in, per row the
 log-probability of every next token out
@@ -8,19 +9,27 @@ reference's deep-learning stage evaluates any network over DataFrame rows
 (cntk/CNTKModel.scala:86-138); this is the stage for the networks people
 score text with today.
 
-The model (``model_type: lfm2_moe``; docs/models.md has the equations):
-pre-norm residual layers, each a sequence mixer — a gated short convolution
-(``"conv"``) or grouped-query attention with per-head QK RMSNorm and RoPE
-(``"full_attention"``) — and a feed-forward network, dense in the leading
-``num_dense_layers`` layers and a sparse expert layer
-(:mod:`mmlspark_tpu.ops.moe`) in the rest; the head is the embedding
-transposed. Weights and activations bfloat16 with float32 accumulation;
+The model (docs/models.md has the equations and says which key chooses
+what): pre-norm residual layers, each a sequence mixer — a gated short
+convolution (``layer_types[i] == "conv"``) or grouped-query attention with
+per-head QK RMSNorm and RoPE (``"full_attention"``; heads of ``head_dim``,
+``hidden_size / num_attention_heads`` where the key is absent), over all
+causal keys or, where the configuration has an ``sa_config``, over the
+``topk`` keys an indexer picks for each query
+(:mod:`mmlspark_tpu.ops.sparse_attention`) — and a feed-forward network,
+dense in the leading ``num_dense_layers`` layers and a sparse expert layer
+(:mod:`mmlspark_tpu.ops.moe`; its router is the sigmoid one with a
+selection bias or the softmax one, ``moe.router_kind``) in the rest; the
+head is the embedding transposed unless ``tie_word_embeddings`` is false.
+Weights and activations bfloat16 with float32 accumulation;
 the residual stream, router scores, the norms' statistics, softmax and the
 head's log-sum-exp in float32 (every product reads bfloat16: the norm that
 feeds a sub-layer casts; a bfloat16 stream would round the whole sum at
 every add, and a rounding error in the stream is what flips a near-tie in
 a router). Causal attention is blockwise (a block of queries against the keys
-up to its end, never a whole ``L x L`` score matrix) and the head folds the
+up to its end — a Python loop over the blocks of rows of a few thousand
+tokens, a device loop where an indexer selects the keys and rows run to
+32,768 — never a whole ``L x L`` score matrix) and the head folds the
 vocabulary into a log-sum-exp a block of tokens at a time, never the whole
 ``tokens x vocabulary`` logits.
 
@@ -33,7 +42,10 @@ are dropped from the output and counted. A batch travels as one int32 array
 ``(rows, L + 1)`` — the ids and, as the trailing column, the row's length
 (:func:`mmlspark_tpu.models.sequence.pack_lengths`' convention) — and comes
 back as one float32 array ``(rows, L - 1 + E)``: the log-probabilities and
-the row's real tokens routed to each expert, summed over the expert layers.
+the row's real tokens routed to each expert, summed over the expert layers;
+with an indexer, four columns more: the keys the row's real positions
+attended and the causal keys they had, summed over the layers, each as
+``count >> 12`` and ``count & 4095`` (float32 holds those exactly).
 """
 
 from __future__ import annotations
@@ -49,7 +61,7 @@ from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import ComplexParam, HasInputCol, HasOutputCol
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.models.xla_model import XLAModel
-from mmlspark_tpu.ops import moe
+from mmlspark_tpu.ops import moe, sparse_attention
 
 _M_TOKENS = obs.counter(
     "mmlspark_lm_tokens_total",
@@ -64,19 +76,43 @@ _M_ROUTED = obs.counter(
     "carried out of the program with each batch's output",
     labels=("expert",),
 )
+_M_ATTN_KEYS = obs.counter(
+    "mmlspark_lm_attn_keys_total",
+    "Keys the real positions of a learned sparse attention met, summed over "
+    "its layers: kind=selected are the keys the indexer kept for a query, "
+    "kind=causal all the keys at or before it; counted on the device and "
+    "carried out of the program with each batch's output",
+    labels=("kind",),
+)
 
 
-# queries per block of the blockwise attention (the largest score tensor is
-# batch x heads x Q_BLOCK x length in float32: 1.07 GB at 32,768 tokens a
-# batch) and tokens per block of the head's log-sum-exp (0.5 GB of logits)
+# queries per block of the blockwise attention: the largest score tensor is
+# batch x heads x Q_BLOCK x keys in float32, 1.07 GB at 32 heads and 32,768
+# tokens a batch, be they 8 rows of 4,096 or one of 32,768 (an indexer's
+# products, batch x its heads x Q_BLOCK x keys, are half that at 16 heads);
+# and tokens per block of the head's log-sum-exp (0.5 GB of logits at 65,536
+# ids, 1.2 GB at 151,936)
 Q_BLOCK = 256
 HEAD_BLOCK = 2048
+# the four trailing columns of a batch's output where an indexer selects keys
+# hold two counts as (count >> COUNT_BITS, count & (2**COUNT_BITS - 1))
+COUNT_BITS = 12
 
 
 def layer_kinds(config: dict) -> list:
     """``[(mixer, ffn)]`` per layer: ("conv" | "full_attention", "dense" | "moe")."""
     return [(config["layer_types"][i], "dense" if i < config["num_dense_layers"] else "moe")
             for i in range(config["num_hidden_layers"])]
+
+
+def norm_eps(config: dict) -> float:
+    """``norm_eps`` (lfm2_moe's name) or ``rms_norm_eps`` (qwen3_moe's)."""
+    return config["norm_eps"] if "norm_eps" in config else config["rms_norm_eps"]
+
+
+def selects_keys(config: dict) -> bool:
+    """Whether the attention layers carry an indexer (``sa_config``)."""
+    return bool(config.get("sa_config"))
 
 
 # -- the layers ----------------------------------------------------------------
@@ -155,20 +191,61 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.concatenate(out, axis=1)
 
 
+def indexer(w: dict, u: jnp.ndarray, config: dict) -> tuple:
+    """The indexer's reading of (B, L, h): ``qI`` (B, L, J, di) and ``kI``
+    (B, L, di) — one key head, through a LayerNorm — both rotated, bfloat16;
+    and the heads' weights (B, L, J) float32, scaled by ``(J di) ** -0.5``."""
+    with jax.named_scope("lm.attn.index"):
+        sa = config["sa_config"]
+        heads, di = sa["indexer_num_heads"], sa["indexer_head_dim"]
+        rows, length, _ = u.shape
+        theta = config["rope_theta"]
+        qi = _mm("blh,hk->blk", u, w["wqi"]).reshape(rows, length, heads, di)
+        qi = rope(qi.astype(jnp.float32), theta).astype(u.dtype)
+        ki = _mm("blh,hk->blk", u, w["wki"]).astype(jnp.float32)
+        ki = ki - ki.mean(-1, keepdims=True)
+        ki = ki * jax.lax.rsqrt(jnp.mean(ki * ki, -1, keepdims=True) + norm_eps(config))
+        ki = ki * w["ki_norm"].astype(jnp.float32) + w["ki_bias"].astype(jnp.float32)
+        ki = rope(ki[:, :, None, :], theta)[:, :, 0].astype(u.dtype)
+        wt = jnp.einsum("blh,hj->blj", u, w["wwi"], preferred_element_type=jnp.float32)
+        return qi, ki, wt * (heads * di) ** -0.5
+
+
+def _qkv(w: dict, u: jnp.ndarray, config: dict) -> tuple:
+    """Attention's own reading of (B, L, h): ``q`` (B, L, nkv, g, d), ``k``
+    and ``v`` (B, L, nkv, d), ``q`` and ``k`` normed per head and rotated."""
+    nq, nkv = config["num_attention_heads"], config["num_key_value_heads"]
+    rows, length, h = u.shape
+    d = config.get("head_dim", h // nq)
+    eps, theta = norm_eps(config), config["rope_theta"]
+    q = _mm("blh,hk->blk", u, w["wq"]).reshape(rows, length, nq, d)
+    k = _mm("blh,hk->blk", u, w["wk"]).reshape(rows, length, nkv, d)
+    v = _mm("blh,hk->blk", u, w["wv"]).reshape(rows, length, nkv, d)
+    q = rope(qk_norm(q, w["q_norm"], eps), theta).astype(u.dtype)
+    k = rope(qk_norm(k, w["k_norm"], eps), theta).astype(u.dtype)
+    return q.reshape(rows, length, nkv, nq // nkv, d), k, v
+
+
 def attn_mixer(w: dict, u: jnp.ndarray, config: dict, q_block: int) -> jnp.ndarray:
-    """GQA with per-head QK RMSNorm before RoPE. (B, L, h)."""
+    """GQA with per-head QK RMSNorm before RoPE, over all causal keys.
+    (B, L, h)."""
     with jax.named_scope("lm.mixer.attn"):
-        nq, nkv = config["num_attention_heads"], config["num_key_value_heads"]
-        rows, length, h = u.shape
-        d = h // nq
-        eps, theta = config["norm_eps"], config["rope_theta"]
-        q = _mm("blh,hk->blk", u, w["wq"]).reshape(rows, length, nq, d)
-        k = _mm("blh,hk->blk", u, w["wk"]).reshape(rows, length, nkv, d)
-        v = _mm("blh,hk->blk", u, w["wv"]).reshape(rows, length, nkv, d)
-        q = rope(qk_norm(q, w["q_norm"], eps), theta).astype(u.dtype)
-        k = rope(qk_norm(k, w["k_norm"], eps), theta).astype(u.dtype)
-        o = causal_attention(q.reshape(rows, length, nkv, nq // nkv, d), k, v, q_block)
-        return _mm("blk,kh->blh", o.reshape(rows, length, nq * d), w["wo"])
+        q, k, v = _qkv(w, u, config)
+        o = causal_attention(q, k, v, q_block)
+        return _mm("blk,kh->blh", o.reshape(*u.shape[:2], -1), w["wo"])
+
+
+def sparse_attn_mixer(w: dict, u: jnp.ndarray, config: dict, q_block: int,
+                      real: jnp.ndarray) -> tuple:
+    """The same attention over the ``topk`` keys the layer's indexer picks
+    for each query. (B, L, h) and the (B, L) bool of positions that are no
+    padding -> ((B, L, h), (B,) int32: the keys each row's real positions
+    attended)."""
+    with jax.named_scope("lm.mixer.attn"):
+        o, kept = sparse_attention.sparse_attention(
+            *_qkv(w, u, config), *indexer(w, u, config), real,
+            config["sa_config"]["topk"], q_block)
+        return _mm("blk,kh->blh", o.reshape(*u.shape[:2], -1), w["wo"]), kept
 
 
 def dense_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
@@ -182,8 +259,13 @@ def dense_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
 def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> tuple:
     """The sparse expert layer over (T, h) tokens -> (its part of the result
     for the experts held, the (T, k) expert ids the router chose)."""
-    idx, weights = moe.route(u, w["router"], w["expert_bias"], config["num_experts_per_tok"],
-                             float(config["routed_scaling_factor"]))
+    if moe.router_kind(config) == "softmax":
+        idx, weights = moe.route_softmax(u, w["router"], config["num_experts_per_tok"],
+                                         config.get("norm_topk_prob", True))
+    else:
+        idx, weights = moe.route(u, w["router"], w["expert_bias"],
+                                 config["num_experts_per_tok"],
+                                 float(config["routed_scaling_factor"]))
     out = moe.expert_ffn(u, idx, weights, w["w1"], w["w3"], w["w2"],
                          config["num_experts"], experts)
     return out, idx
@@ -191,7 +273,8 @@ def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> 
 
 def head_logprobs(embed: jnp.ndarray, u: jnp.ndarray, targets: jnp.ndarray,
                   block: int) -> jnp.ndarray:
-    """(T, h) normed final states and (T,) target ids -> (T,) float32
+    """(T, h) normed final states, the head's (V, h) matrix (the embedding
+    where they are tied) and (T,) target ids -> (T,) float32
     ``log softmax(u E^T)[target]``, ``block`` tokens at a time: the logits of
     a block in float32, their log-sum-exp, the target's logit, and on."""
     tokens = u.shape[0]
@@ -210,22 +293,39 @@ def head_logprobs(embed: jnp.ndarray, u: jnp.ndarray, targets: jnp.ndarray,
     return out.reshape(tokens)
 
 
+def count_columns(count: jnp.ndarray) -> jnp.ndarray:
+    """(B,) int32 -> (B, 2) float32 ``(count >> COUNT_BITS, count & mask)``:
+    float32 holds both halves exactly, and their sums over a model's layers,
+    where a row of 32,768 positions counts 6.7e7 keys a layer."""
+    return jnp.stack([count >> COUNT_BITS, count & ((1 << COUNT_BITS) - 1)],
+                     axis=-1).astype(jnp.float32)
+
+
 def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q_BLOCK,
             head_block: int = HEAD_BLOCK, experts: Optional[tuple] = None) -> jnp.ndarray:
     """(B, L + 1) int32 — ids, then each row's length — to (B, L - 1 + E)
     float32: next-token log-probabilities (0 from the row's last real token
-    on) and the row's real tokens routed to each expert over all layers."""
+    on) and the row's real tokens routed to each expert over all layers;
+    with an indexer four columns more (:func:`count_columns`: the keys
+    attended, the causal keys)."""
     ids, lengths = packed[:, :-1], packed[:, -1]
     rows, length = ids.shape
     num_experts = config["num_experts"]
-    eps = config["norm_eps"]
+    eps = norm_eps(config)
     real = jnp.arange(length)[None, :] < lengths[:, None]
+    kept = 0.0
     with jax.named_scope("lm.embed"):
         x = variables["embed"][ids].astype(jnp.float32)
     load = jnp.zeros((rows, num_experts), jnp.float32)
     for (mixer, ffn), w in zip(layer_kinds(config), variables["layers"]):
         u = rmsnorm(x, w["norm_op"], eps)
-        y = conv_mixer(w, u) if mixer == "conv" else attn_mixer(w, u, config, q_block)
+        if mixer == "conv":
+            y = conv_mixer(w, u)
+        elif selects_keys(config):
+            y, n = sparse_attn_mixer(w, u, config, q_block, real)
+            kept = kept + count_columns(n)
+        else:
+            y = attn_mixer(w, u, config, q_block)
         x = x + y.astype(jnp.float32)
         u = rmsnorm(x, w["norm_ffn"], eps).reshape(rows * length, -1)
         if ffn == "dense":
@@ -238,10 +338,15 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
     with jax.named_scope("lm.head"):
         u = rmsnorm(x, variables["norm"], eps).reshape(rows * length, -1)
         targets = jnp.concatenate([ids[:, 1:], jnp.zeros((rows, 1), ids.dtype)], axis=1)
-        logp = head_logprobs(variables["embed"], u, targets.reshape(-1), head_block)
+        head = variables["embed" if config.get("tie_word_embeddings", True) else "head"]
+        logp = head_logprobs(head, u, targets.reshape(-1), head_block)
         logp = logp.reshape(rows, length)[:, :-1]
         logp = jnp.where(real[:, 1:], logp, 0.0)
-    return jnp.concatenate([logp, load], axis=1)
+    if not selects_keys(config):
+        return jnp.concatenate([logp, load], axis=1)
+    layers = sum(1 for mixer, _ffn in layer_kinds(config) if mixer != "conv")
+    causal = count_columns(lengths * (lengths + 1) // 2) * layers
+    return jnp.concatenate([logp, load, kept, causal], axis=1)
 
 
 # -- the stage -----------------------------------------------------------------
@@ -252,7 +357,8 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
     ``config`` holds the model's published keys (``hidden_size``,
     ``layer_types``, ``num_experts`` ...: the model's own ``config.json``);
     ``variables`` is ``{"embed": (V, h), "norm": (h,), "layers": [per-layer
-    dict]}`` (docs/models.md names every array). Hand it arrays that already
+    dict]}``, with ``"head": (V, h)`` where the head is not the embedding
+    (docs/models.md names every array). Hand it arrays that already
     live on the device and they are used where they are: 9 GB of weights are
     not copied. ``buckets`` are ``[length, rows per batch]`` pairs; a row
     goes to the shortest bucket that holds it, and every bucket is one
@@ -304,6 +410,7 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
         buckets = self._buckets()
         edges = np.array([length for length, _ in buckets])
         num_experts = int(self.get_or_fail("config")["num_experts"])
+        counts_keys = selects_keys(self.get_or_fail("config"))
 
         def fn(p: Partition) -> Partition:
             rows = [np.asarray(r, np.int32) for r in p[ic]]
@@ -315,6 +422,7 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
             bucket_of = np.searchsorted(edges, lens, side="left")
             out = np.empty(len(rows), dtype=object)
             routed = np.zeros(num_experts, np.float64)
+            keys = np.zeros(4, np.float64)  # selected and causal, each (high, low)
             real = padded = 0
             # one trace per partition; every bucket's apply_batch is a child
             with obs.span("lm.score", attrs={"rows": len(rows)}) as sp:
@@ -332,15 +440,25 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                         res = inner.apply_batch(packed, batch_size=batch)
                     for j, i in enumerate(at):
                         out[i] = res[j, :lens[i] - 1].copy()
-                    routed += res[:, length - 1:].sum(0, dtype=np.float64)
+                    routed += res[:, length - 1:length - 1 + num_experts].sum(0, dtype=np.float64)
+                    if counts_keys:
+                        keys += res[:, length - 1 + num_experts:].sum(0, dtype=np.float64)
                     real += int(lens[at].sum())
                     padded += batches * batch * length - int(lens[at].sum())
                 sp.set_attr("tokens_real", real)
                 sp.set_attr("tokens_padded", padded)
+                if counts_keys:
+                    selected, causal = (int(hi) * (1 << COUNT_BITS) + int(lo)
+                                        for hi, lo in keys.reshape(2, 2))
+                    sp.set_attr("attn_keys_selected", selected)
+                    sp.set_attr("attn_keys_causal", causal)
             _M_TOKENS.labels(kind="real").inc(real)
             _M_TOKENS.labels(kind="padded").inc(padded)
             for e, n in enumerate(routed):
                 _M_ROUTED.labels(expert=str(e)).inc(float(n))
+            if counts_keys:
+                _M_ATTN_KEYS.labels(kind="selected").inc(selected)
+                _M_ATTN_KEYS.labels(kind="causal").inc(causal)
             q = dict(p)
             q[oc] = out
             return q

@@ -16,9 +16,14 @@ per held expert (on the TPU a grouped-matmul kernel of XLA's own, tiled over
 the rows; elsewhere a masked dense product). The combine gathers the rows
 back into token order and weights them in float32.
 
-Router (LFM2-MoE / DeepSeek-V3 style): ``s = sigmoid(W_g u)`` in float32;
-the top-k is taken over ``s + bias`` (the load-balancing expert bias) while
-the combine weights come from ``s`` alone, normalised over the selection.
+Two routers; the configuration's keys say which (:func:`router_kind`).
+``"sigmoid"`` (LFM2-MoE / DeepSeek-V3 style): ``s = sigmoid(W_g u)`` in
+float32; the top-k is taken over ``s + bias`` (the load-balancing expert
+bias) while the combine weights come from ``s`` alone, normalised over the
+selection. ``"softmax"`` (the ``qwen3_moe`` family): ``p = softmax(W_g u)``
+over all the experts in float32, the top-k of ``p``, and as weights ``p``
+over its sum over the selection (``norm_topk_prob``) or ``p`` as it is; no
+bias.
 """
 
 from __future__ import annotations
@@ -43,12 +48,41 @@ def combine_weights(scores: jnp.ndarray, idx: jnp.ndarray, scaling: float) -> jn
     return picked / (picked.sum(-1, keepdims=True) + ROUTE_EPS) * scaling
 
 
+def router_logits(u: jnp.ndarray, router: jnp.ndarray) -> jnp.ndarray:
+    """(T, E) float32 at full matmul precision: a near-tie decides which
+    experts run, and a bfloat16 product would flip many more."""
+    return jnp.einsum("th,he->te", u.astype(jnp.float32), router.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def router_scores(u: jnp.ndarray, router: jnp.ndarray) -> jnp.ndarray:
-    """(T, E) sigmoid scores in float32 at full matmul precision: a near-tie
-    decides which experts run, and a bfloat16 product would flip many more."""
-    logits = jnp.einsum("th,he->te", u.astype(jnp.float32), router.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    return jax.nn.sigmoid(logits)
+    """(T, E) sigmoid scores in float32."""
+    return jax.nn.sigmoid(router_logits(u, router))
+
+
+def router_kind(config: dict) -> str:
+    """``"sigmoid"`` or ``"softmax"``: what ``scoring_func`` says; where a
+    configuration has no such key, its family's: the sigmoid router's
+    families carry ``routed_scaling_factor``, the softmax router's do not."""
+    return config.get("scoring_func",
+                      "sigmoid" if "routed_scaling_factor" in config else "softmax")
+
+
+def softmax_weights(probs: jnp.ndarray, idx: jnp.ndarray, norm_topk_prob: bool) -> jnp.ndarray:
+    """(T, k) weights of the selected experts: their probabilities, over
+    their sum if ``norm_topk_prob``."""
+    picked = jnp.take_along_axis(probs, idx, axis=-1)
+    return picked / picked.sum(-1, keepdims=True) if norm_topk_prob else picked
+
+
+def route_softmax(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
+                  norm_topk_prob: bool = True) -> tuple:
+    """(T, h) tokens -> ((T, k) int32 expert ids, (T, k) float32 weights)
+    by the softmax router."""
+    with jax.named_scope("lm.moe.route"):
+        probs = jax.nn.softmax(router_logits(u, router), axis=-1)
+        idx = jax.lax.top_k(probs, top_k)[1]
+        return idx, softmax_weights(probs, idx, norm_topk_prob)
 
 
 def route(u: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, top_k: int,

@@ -189,3 +189,39 @@ def test_expert_layer_compiles_for_one_v5e_chip_at_published_widths(v5e):
     text = compiled.as_text()
     assert text.count('op_name="ragged-dot-none"') == 3
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("rows,keys", [(1, 32_768), (8, 4_096)], ids=["one_row", "eight_rows"])
+def test_sparse_attention_compiles_for_one_v5e_chip_at_published_widths(v5e, rows, keys):
+    """A block of 256 queries of the long-document cell against a batch's
+    32,768 keys — an indexer of 16 heads of 64, 32 query heads over 4
+    key/value heads of 128 — compiles for one chip: the attention over the
+    selection is a Mosaic kernel, the index scores and the selection XLA's,
+    and neither the indexer's ``Q x J x K`` products (the compiler folds
+    the ReLU and the weighted sum into the product) nor the heads'
+    ``Q x K`` scores exist as arrays: a block's temporaries stay at a few
+    copies of its 33.5 MB of scores."""
+    from jax.sharding import SingleDeviceSharding
+
+    from mmlspark_tpu.ops import sparse_attention as sa
+
+    one = SingleDeviceSharding(v5e[0])
+    call = H._pallas_call_kwargs(v5e[0])
+    assert call["interpret"] is False
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def block(qi, ki, w, q, k, v, reach):
+        causal = (reach - 255 + jnp.arange(256))[:, None] >= jnp.arange(keys)[None, :]
+        mask = sa.select(sa.index_scores(qi, ki, w), causal, 2048)
+        return sa.attend_kernel(q, k, v, mask, reach, **call)
+
+    compiled = jax.jit(block).lower(
+        spec((rows, 16, 256, 64), jnp.bfloat16), spec((rows, keys, 64), jnp.bfloat16),
+        spec((rows, 16, 256), jnp.float32), spec((rows, 4, 8, 256, 128), jnp.bfloat16),
+        spec((rows, 4, keys, 128), jnp.bfloat16), spec((rows, 4, keys, 128), jnp.bfloat16),
+        spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "sparse_attend" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
