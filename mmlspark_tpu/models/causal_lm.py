@@ -41,11 +41,14 @@ look back only; norms, FFNs and the router are per token). Padded positions
 are dropped from the output and counted. A batch travels as one int32 array
 ``(rows, L + 1)`` — the ids and, as the trailing column, the row's length
 (:func:`mmlspark_tpu.models.sequence.pack_lengths`' convention) — and comes
-back as one float32 array ``(rows, L - 1 + E)``: the log-probabilities and
-the row's real tokens routed to each expert, summed over the expert layers;
-with an indexer, four columns more: the keys the row's real positions
-attended and the causal keys they had, summed over the layers, each as
-``count >> 12`` and ``count & 4095`` (float32 holds those exactly).
+back as one float32 array ``(rows, L - 1 + E + 2)``: the log-probabilities,
+the row's real tokens routed to each expert, summed over the expert layers,
+and in the batch's first row the visits and the row tiles of the experts'
+grouped-matmul kernel (:func:`mmlspark_tpu.ops.moe.expert_ffn`; a count of
+the batch, not of a row); with an indexer, four columns more: the keys the
+row's real positions attended and the causal keys they had, summed over the
+layers, each as ``count >> 12`` and ``count & 4095`` (float32 holds those
+exactly).
 """
 
 from __future__ import annotations
@@ -75,6 +78,15 @@ _M_ROUTED = obs.counter(
     "Real tokens routed to each expert, summed over the expert layers; "
     "carried out of the program with each batch's output",
     labels=("expert",),
+)
+_M_GMM_TILES = obs.counter(
+    "mmlspark_moe_gmm_tiles_total",
+    "Row tiles of the experts' grouped-matmul kernel, summed over the expert "
+    "layers: kind=visited are the (expert, row tile) pairs it multiplied, "
+    "kind=aligned the row tiles that held a routed row (a tile two experts "
+    "share is visited once for each); 0 where the products ran as XLA's "
+    "ragged_dot; carried out of the program with each batch's output",
+    labels=("kind",),
 )
 _M_ATTN_KEYS = obs.counter(
     "mmlspark_lm_attn_keys_total",
@@ -258,7 +270,8 @@ def dense_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
 
 def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> tuple:
     """The sparse expert layer over (T, h) tokens -> (its part of the result
-    for the experts held, the (T, k) expert ids the router chose)."""
+    for the experts held, the (T, k) expert ids the router chose, the (2,)
+    int32 visits and row tiles of the experts' kernel)."""
     if moe.router_kind(config) == "softmax":
         idx, weights = moe.route_softmax(u, w["router"], config["num_experts_per_tok"],
                                          config.get("norm_topk_prob", True))
@@ -266,9 +279,9 @@ def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> 
         idx, weights = moe.route(u, w["router"], w["expert_bias"],
                                  config["num_experts_per_tok"],
                                  float(config["routed_scaling_factor"]))
-    out = moe.expert_ffn(u, idx, weights, w["w1"], w["w3"], w["w2"],
-                         config["num_experts"], experts)
-    return out, idx
+    out, tiles = moe.expert_ffn(u, idx, weights, w["w1"], w["w3"], w["w2"],
+                                config["num_experts"], experts)
+    return out, idx, tiles
 
 
 def head_logprobs(embed: jnp.ndarray, u: jnp.ndarray, targets: jnp.ndarray,
@@ -303,9 +316,10 @@ def count_columns(count: jnp.ndarray) -> jnp.ndarray:
 
 def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q_BLOCK,
             head_block: int = HEAD_BLOCK, experts: Optional[tuple] = None) -> jnp.ndarray:
-    """(B, L + 1) int32 — ids, then each row's length — to (B, L - 1 + E)
+    """(B, L + 1) int32 — ids, then each row's length — to (B, L - 1 + E + 2)
     float32: next-token log-probabilities (0 from the row's last real token
-    on) and the row's real tokens routed to each expert over all layers;
+    on), the row's real tokens routed to each expert over all layers, and in
+    row 0 the batch's ``[visited, aligned]`` tiles of the experts' kernel;
     with an indexer four columns more (:func:`count_columns`: the keys
     attended, the causal keys)."""
     ids, lengths = packed[:, :-1], packed[:, -1]
@@ -317,6 +331,7 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
     with jax.named_scope("lm.embed"):
         x = variables["embed"][ids].astype(jnp.float32)
     load = jnp.zeros((rows, num_experts), jnp.float32)
+    tiles = jnp.zeros((2,), jnp.int32)
     for (mixer, ffn), w in zip(layer_kinds(config), variables["layers"]):
         u = rmsnorm(x, w["norm_op"], eps)
         if mixer == "conv":
@@ -331,7 +346,8 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
         if ffn == "dense":
             y = dense_ffn(w, u)
         else:
-            y, idx = moe_ffn(w, u, config, experts)
+            y, idx, visited = moe_ffn(w, u, config, experts)
+            tiles = tiles + visited
             with jax.named_scope("lm.moe.route"):
                 load = load + moe.expert_load(idx.reshape(rows, length, -1), real, num_experts)
         x = x + y.reshape(rows, length, -1).astype(jnp.float32)
@@ -342,11 +358,13 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
         logp = head_logprobs(head, u, targets.reshape(-1), head_block)
         logp = logp.reshape(rows, length)[:, :-1]
         logp = jnp.where(real[:, 1:], logp, 0.0)
+    # a count of the batch: its first row carries it (every batch has a real one)
+    tiles = jnp.zeros((rows, 2), jnp.float32).at[0].set(tiles.astype(jnp.float32))
     if not selects_keys(config):
-        return jnp.concatenate([logp, load], axis=1)
+        return jnp.concatenate([logp, load, tiles], axis=1)
     layers = sum(1 for mixer, _ffn in layer_kinds(config) if mixer != "conv")
     causal = count_columns(lengths * (lengths + 1) // 2) * layers
-    return jnp.concatenate([logp, load, kept, causal], axis=1)
+    return jnp.concatenate([logp, load, tiles, kept, causal], axis=1)
 
 
 # -- the stage -----------------------------------------------------------------
@@ -422,6 +440,7 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
             bucket_of = np.searchsorted(edges, lens, side="left")
             out = np.empty(len(rows), dtype=object)
             routed = np.zeros(num_experts, np.float64)
+            tiles = np.zeros(2, np.float64)  # the experts' kernel: visited, aligned
             keys = np.zeros(4, np.float64)  # selected and causal, each (high, low)
             real = padded = 0
             # one trace per partition; every bucket's apply_batch is a child
@@ -440,13 +459,17 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                         res = inner.apply_batch(packed, batch_size=batch)
                     for j, i in enumerate(at):
                         out[i] = res[j, :lens[i] - 1].copy()
-                    routed += res[:, length - 1:length - 1 + num_experts].sum(0, dtype=np.float64)
+                    counts = res[:, length - 1:].sum(0, dtype=np.float64)
+                    routed += counts[:num_experts]
+                    tiles += counts[num_experts:num_experts + 2]
                     if counts_keys:
-                        keys += res[:, length - 1 + num_experts:].sum(0, dtype=np.float64)
+                        keys += counts[num_experts + 2:]
                     real += int(lens[at].sum())
                     padded += batches * batch * length - int(lens[at].sum())
                 sp.set_attr("tokens_real", real)
                 sp.set_attr("tokens_padded", padded)
+                sp.set_attr("gmm_tiles_visited", int(tiles[0]))
+                sp.set_attr("gmm_tiles_aligned", int(tiles[1]))
                 if counts_keys:
                     selected, causal = (int(hi) * (1 << COUNT_BITS) + int(lo)
                                         for hi, lo in keys.reshape(2, 2))
@@ -456,6 +479,8 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
             _M_TOKENS.labels(kind="padded").inc(padded)
             for e, n in enumerate(routed):
                 _M_ROUTED.labels(expert=str(e)).inc(float(n))
+            _M_GMM_TILES.labels(kind="visited").inc(int(tiles[0]))
+            _M_GMM_TILES.labels(kind="aligned").inc(int(tiles[1]))
             if counts_keys:
                 _M_ATTN_KEYS.labels(kind="selected").inc(selected)
                 _M_ATTN_KEYS.labels(kind="causal").inc(causal)

@@ -11,10 +11,18 @@ adds an exchange around :func:`expert_ffn`, not a change inside it.
 
 No token is dropped and there is no capacity: the ``T * k`` (token, expert)
 pairs are sorted by expert — held experts first — and the three products of
-the gated FFN run as ``jax.lax.ragged_dot`` over the sorted rows, one group
-per held expert (on the TPU a grouped-matmul kernel of XLA's own, tiled over
-the rows; elsewhere a masked dense product). The combine gathers the rows
-back into token order and weights them in float32.
+the gated FFN run over the sorted rows, one group per held expert. On a TPU
+they are two calls of the Pallas kernel ``expert_gmm``
+(:mod:`mmlspark_tpu.ops.grouped_matmul`): the up-call multiplies a tile of
+rows by ``W1_e`` and ``W3_e`` at once and stores ``silu(a) * b`` from the
+float32 products, the down-call multiplies that by ``W2_e``; the group
+metadata is computed once a layer and both calls share it.
+``ops.histogram.use_pallas`` decides, as for the other kernels, and
+``grouped_matmul.tiling`` says from the shapes alone how the products are
+tiled; elsewhere, and for widths the rule cannot tile, the products are
+``jax.lax.ragged_dot`` (XLA's own grouped matmul, which the kernel is tested
+against) and the gate an elementwise pass. The combine gathers the rows back
+into token order and weights them in float32.
 
 Two routers; the configuration's keys say which (:func:`router_kind`).
 ``"sigmoid"`` (LFM2-MoE / DeepSeek-V3 style): ``s = sigmoid(W_g u)`` in
@@ -32,6 +40,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from mmlspark_tpu.ops import grouped_matmul, histogram
 
 ROUTE_EPS = 1e-6
 
@@ -96,13 +106,17 @@ def route(u: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray, top_k: int,
 
 def expert_ffn(u: jnp.ndarray, idx: jnp.ndarray, weights: jnp.ndarray,
                w1: jnp.ndarray, w3: jnp.ndarray, w2: jnp.ndarray, num_experts: int,
-               experts: Optional[tuple] = None) -> jnp.ndarray:
-    """The held experts' part of ``sum_e w_e W2_e (silu(W1_e u) * W3_e u)``.
+               experts: Optional[tuple] = None) -> tuple:
+    """The held experts' part of ``sum_e w_e W2_e (silu(W1_e u) * W3_e u)``,
+    and what the products' tiling cost.
 
     ``u`` (T, h); ``idx`` / ``weights`` (T, k) from :func:`route` over all
     ``num_experts``; ``w1`` / ``w3`` (n_held, h, f) and ``w2`` (n_held, f, h)
     are the weights of experts ``experts = (lo, hi)`` (default: all). Tokens
-    routed to an expert outside the range get nothing from it here."""
+    routed to an expert outside the range get nothing from it here.
+    -> ((T, h), (2,) int32 ``[visited, aligned]``: the (expert, row tile)
+    visits the kernel made and the row tiles that held a routed row; zeros
+    where the products ran as ``ragged_dot``)."""
     lo, hi = experts or (0, num_experts)
     held = hi - lo
     if w1.shape[0] != held:
@@ -116,16 +130,27 @@ def expert_ffn(u: jnp.ndarray, idx: jnp.ndarray, weights: jnp.ndarray,
         rows = u[order // k]
         sizes = (rank[:, None] == jnp.arange(held)[None, :]).sum(0, dtype=jnp.int32)
     with jax.named_scope("lm.moe.experts"):
-        def gmm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-            return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=u.dtype)
+        tiling = None
+        if histogram.use_pallas():
+            tiling = grouped_matmul.tiling(rows.shape[0], w1.shape[1], w1.shape[2],
+                                           histogram._hist_vmem_mb() << 20)
+        if tiling is None:
+            def gmm(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+                return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=u.dtype)
 
-        out = gmm(jax.nn.silu(gmm(rows, w1)) * gmm(rows, w3), w2)
+            out = gmm(jax.nn.silu(gmm(rows, w1)) * gmm(rows, w3), w2)
+            tiles = jnp.zeros((2,), jnp.int32)
+        else:
+            out, tiles = grouped_matmul.expert_products(
+                rows, sizes, w1, w3, w2, tiling=tiling,
+                call=tuple(histogram._pallas_call_kwargs().items()))
     with jax.named_scope("lm.moe.combine"):
         if held < num_experts:  # rows past the groups hold nothing defined
             out = jnp.where((rank[order] < held)[:, None], out, 0)
         back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size, dtype=order.dtype))
         picked = out[back].reshape(tokens, k, -1)
-        return jnp.einsum("tk,tkh->th", weights, picked.astype(jnp.float32)).astype(u.dtype)
+        return jnp.einsum("tk,tkh->th", weights,
+                          picked.astype(jnp.float32)).astype(u.dtype), tiles
 
 
 def expert_load(idx: jnp.ndarray, real: jnp.ndarray, num_experts: int) -> jnp.ndarray:

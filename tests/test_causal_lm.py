@@ -72,7 +72,7 @@ def test_program_matches_reference_in_every_bucket_shape(config, variables, leng
         packed[b, :n] = rng.integers(0, config["vocab_size"], n)
     packed[:, -1] = lens
     out = np.asarray(jax.jit(lambda v, p: lm.forward(v, p, config, 16, 64))(variables, packed))
-    assert out.shape == (rows, length - 1 + config["num_experts"])
+    assert out.shape == (rows, length - 1 + config["num_experts"] + 2)
     want = ref.logprobs(config, KEY, [packed[b, :n] for b, n in enumerate(lens)][:3])
     gaps = np.concatenate([np.abs(out[b, :lens[b] - 1] - want[b]) for b in range(3)])
     assert np.median(gaps) < 0.01 and np.percentile(gaps, 90) < 0.05, (
@@ -149,7 +149,7 @@ def test_expert_shares_add_up_to_the_whole_layer_and_the_reference():
 
     def part(lo, hi):
         return np.asarray(jax.jit(lambda a, b, c: moe.expert_ffn(
-            ub, idx, weights, a, b, c, 32, (lo, hi)).astype(jnp.float32))(
+            ub, idx, weights, a, b, c, 32, (lo, hi))[0].astype(jnp.float32))(
                 w1[lo:hi], w3[lo:hi], w2[lo:hi]))
 
     whole = part(0, 32)

@@ -89,7 +89,7 @@ def test_program_matches_reference_in_every_bucket_shape(config, variables, leng
     packed[:, -1] = lens
     out = np.asarray(jax.jit(lambda v, p: lm.forward(v, p, config, 16, 64))(variables, packed))
     experts = config["num_experts"]
-    assert out.shape == (rows, length - 1 + experts + 4)
+    assert out.shape == (rows, length - 1 + experts + 2 + 4)
     # the reference runs each row padded as the program saw it
     want = ref.logprobs(config, KEY, [packed[b, :length] for b in range(3)])
     gaps = np.concatenate([np.abs(out[b, :lens[b] - 1] - want[b][:lens[b] - 1])
@@ -319,7 +319,7 @@ def test_expert_ranges_add_up_to_the_whole_layer_and_the_reference(config):
 
     def share(lo, hi):
         return np.asarray(moe.expert_ffn(ub, idx, weights, pw["w1"][lo:hi], pw["w3"][lo:hi],
-                                         pw["w2"][lo:hi], 8, (lo, hi)).astype(jnp.float32))
+                                         pw["w2"][lo:hi], 8, (lo, hi))[0].astype(jnp.float32))
 
     whole = share(0, 8)
     parts = [share(lo, lo + 2) for lo in (0, 2, 4, 6)]
@@ -341,7 +341,7 @@ def test_expert_ranges_add_up_to_the_whole_layer_and_the_reference(config):
         assert untouched.any() and not part[untouched].any()
     # and through the model's own layer: forward's moe_ffn with a range
     held = dict(pw, **{k: pw[k][2:4] for k in ("w1", "w3", "w2")})
-    out, _ = lm.moe_ffn(held, ub, config, (2, 4))
+    out, _idx, _tiles = lm.moe_ffn(held, ub, config, (2, 4))
     assert np.abs(np.asarray(out.astype(jnp.float32)) - parts[1]).max() == 0
 
 

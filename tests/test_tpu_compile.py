@@ -162,24 +162,35 @@ def test_partitioned_grower_compiles_for_one_v5e_chip(v5e):
     assert "all-reduce" not in text
 
 
-def test_expert_layer_compiles_for_one_v5e_chip_at_published_widths(v5e):
-    """The sparse expert layer of the language-model cell — 32 experts of
-    2048 x 1792, top-4, a batch of 32,768 tokens — compiles for one chip:
-    the three grouped products become the TPU compiler's own grouped-matmul
-    kernel (not 32 masked dense products), and the layer's temporaries stay
+@pytest.mark.parametrize("h,f,experts,k,router", [
+    (2048, 1792, 32, 4, "sigmoid"), (2048, 768, 128, 8, "softmax")],
+    ids=["lfm2_8b_a1b", "keye_vl2_30b_a3b"])
+def test_expert_layer_compiles_for_one_v5e_chip_at_published_widths(
+        v5e, monkeypatch, h, f, experts, k, router):
+    """The sparse expert layer of both language-model cells — 32 experts of
+    2048 x 1792, top-4 by the sigmoid router; 128 of 2048 x 768, top-8 by the
+    softmax router; a batch of 32,768 tokens — compiles for one chip: the
+    three grouped products are two calls of the Mosaic kernel ``expert_gmm``
+    under the scope ``lm.moe.experts`` (the up-call holds the gate; no
+    ``ragged-dot`` of the TPU compiler's is left, and neither of the two
+    routed x f products exists as an array), and the layer's temporaries stay
     far under the 6 GB the weights leave."""
     from jax.sharding import SingleDeviceSharding
 
     from mmlspark_tpu.ops import moe
 
+    # the layer asks the histogram kernels' rule which device it lowers for:
+    # a CPU process that compiles for a described chip answers for the chip
+    monkeypatch.setattr(H, "_target_device", lambda mesh=None: v5e[0])
     one = SingleDeviceSharding(v5e[0])
-    tokens, h, f, experts, k = 32_768, 2048, 1792, 32, 4
+    tokens = 32_768
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    def layer(u, router, bias, w1, w3, w2):
-        idx, weights = moe.route(u, router, bias, k)
+    def layer(u, gate, bias, w1, w3, w2):
+        idx, weights = (moe.route(u, gate, bias, k) if router == "sigmoid"
+                        else moe.route_softmax(u, gate, k))
         return moe.expert_ffn(u, idx, weights, w1, w3, w2, experts)
 
     compiled = jax.jit(layer).lower(
@@ -187,7 +198,9 @@ def test_expert_layer_compiles_for_one_v5e_chip_at_published_widths(v5e):
         spec((experts,), jnp.float32), spec((experts, h, f), jnp.bfloat16),
         spec((experts, h, f), jnp.bfloat16), spec((experts, f, h), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-none"') == 3
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 2 and all("lm.moe.experts" in ln and "expert_gmm" in ln for ln in calls)
+    assert "ragged-dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
