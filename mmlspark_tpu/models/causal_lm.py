@@ -1,5 +1,6 @@
 """Causal language model as a corpus scorer: one forward pass, built from
-the configuration's keys (``lfm2_moe`` and the ``KeyeVL2`` language model).
+the configuration's keys (``lfm2_moe``, the ``KeyeVL2`` language model and
+``deepseek_v2``).
 
 A DataFrame column of token-id arrays of unequal length in, per row the
 log-probability of every next token out
@@ -11,16 +12,27 @@ score text with today.
 
 The model (docs/models.md has the equations and says which key chooses
 what): pre-norm residual layers, each a sequence mixer — a gated short
-convolution (``layer_types[i] == "conv"``) or grouped-query attention with
+convolution (``layer_types[i] == "conv"``), grouped-query attention with
 per-head QK RMSNorm and RoPE (``"full_attention"``; heads of ``head_dim``,
 ``hidden_size / num_attention_heads`` where the key is absent), over all
 causal keys or, where the configuration has an ``sa_config``, over the
 ``topk`` keys an indexer picks for each query
-(:mod:`mmlspark_tpu.ops.sparse_attention`) — and a feed-forward network,
-dense in the leading ``num_dense_layers`` layers and a sparse expert layer
-(:mod:`mmlspark_tpu.ops.moe`; its router is the sigmoid one with a
-selection bias or the softmax one, ``moe.router_kind``) in the rest; the
-head is the embedding transposed unless ``tie_word_embeddings`` is false.
+(:mod:`mmlspark_tpu.ops.sparse_attention`), or, where it has a
+``kv_lora_rank``, latent attention: low-rank queries and one low-rank
+latent for keys and values, a rotation (YaRN's frequencies) on a part of
+the score's width whose key is shared by all heads, values narrower than
+scores (:mod:`mmlspark_tpu.ops.latent_attention`) — and a feed-forward
+network, dense in the leading ``num_dense_layers`` (``first_k_dense_replace``)
+layers and a sparse expert layer (:mod:`mmlspark_tpu.ops.moe`; its router is
+the sigmoid one with a selection bias, the softmax one or the softmax one
+limited to groups, ``moe.router_kind``), with ``n_shared_experts`` shared
+experts every token passes through, in the rest; the head is the embedding
+transposed unless ``tie_word_embeddings`` is false. A configuration may be
+one chip's share of a deployment: ``expert_range`` names the experts held
+of those the router scores (the layer computes their part of the result,
+:func:`mmlspark_tpu.ops.moe.expert_ffn`), ``vocab_range`` the slice of the
+vocabulary whose embedding and head rows are held (ids come from the slice,
+and the log-probabilities are over it).
 Weights and activations bfloat16 with float32 accumulation;
 the residual stream, router scores, the norms' statistics, softmax and the
 head's log-sum-exp in float32 (every product reads bfloat16: the norm that
@@ -29,7 +41,8 @@ every add, and a rounding error in the stream is what flips a near-tie in
 a router). Causal attention is blockwise (a block of queries against the keys
 up to its end — a Python loop over the blocks of rows of a few thousand
 tokens, a device loop where an indexer selects the keys and rows run to
-32,768 — never a whole ``L x L`` score matrix) and the head folds the
+32,768, a flash kernel over the causal tiles for latent attention's 128
+heads — never a whole ``L x L`` score matrix) and the head folds the
 vocabulary into a log-sum-exp a block of tokens at a time, never the whole
 ``tokens x vocabulary`` logits.
 
@@ -64,7 +77,7 @@ from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import ComplexParam, HasInputCol, HasOutputCol
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.models.xla_model import XLAModel
-from mmlspark_tpu.ops import moe, sparse_attention
+from mmlspark_tpu.ops import latent_attention, moe, sparse_attention
 
 _M_TOKENS = obs.counter(
     "mmlspark_lm_tokens_total",
@@ -98,11 +111,13 @@ _M_ATTN_KEYS = obs.counter(
 )
 
 
-# queries per block of the blockwise attention: the largest score tensor is
-# batch x heads x Q_BLOCK x keys in float32, 1.07 GB at 32 heads and 32,768
-# tokens a batch, be they 8 rows of 4,096 or one of 32,768 (an indexer's
-# products, batch x its heads x Q_BLOCK x keys, are half that at 16 heads);
-# and tokens per block of the head's log-sum-exp (0.5 GB of logits at 65,536
+# queries per block of the blockwise grouped-query attention: the largest
+# score tensor is batch x heads x Q_BLOCK x keys in float32, 1.07 GB at 32
+# heads and 32,768 tokens a batch, be they 8 rows of 4,096 or one of 32,768
+# (an indexer's products, batch x its heads x Q_BLOCK x keys, are half that
+# at 16 heads); latent attention, 128 heads, never holds such a tensor on a
+# TPU and sizes its own block elsewhere (ops/latent_attention.py); and tokens
+# per block of the head's log-sum-exp (0.5 GB of logits at 65,536
 # ids, 1.2 GB at 151,936)
 Q_BLOCK = 256
 HEAD_BLOCK = 2048
@@ -112,9 +127,28 @@ COUNT_BITS = 12
 
 
 def layer_kinds(config: dict) -> list:
-    """``[(mixer, ffn)]`` per layer: ("conv" | "full_attention", "dense" | "moe")."""
-    return [(config["layer_types"][i], "dense" if i < config["num_dense_layers"] else "moe")
+    """``[(mixer, ffn)]`` per layer: ("conv" | "full_attention", "dense" | "moe").
+    Without ``layer_types`` every mixer is attention; the leading dense
+    layers are ``num_dense_layers`` (lfm2_moe's name) or
+    ``first_k_dense_replace`` (deepseek_v2's)."""
+    dense = config["num_dense_layers"] if "num_dense_layers" in config \
+        else config["first_k_dense_replace"]
+    kinds = config.get("layer_types") or ["full_attention"] * config["num_hidden_layers"]
+    return [(kinds[i], "dense" if i < dense else "moe")
             for i in range(config["num_hidden_layers"])]
+
+
+def router_width(config: dict) -> int:
+    """The experts the router scores: ``num_experts`` or ``n_routed_experts``."""
+    return config["num_experts"] if "num_experts" in config else config["n_routed_experts"]
+
+
+def held_range(config: dict, key: str) -> Optional[tuple]:
+    """``(lo, hi)`` under ``key`` where the configuration is a share:
+    ``expert_range``, the experts held of those the router scores;
+    ``vocab_range``, the ids whose embedding and head rows are held."""
+    held = config.get(key)
+    return None if held is None else (int(held[0]), int(held[1]))
 
 
 def norm_eps(config: dict) -> float:
@@ -125,6 +159,11 @@ def norm_eps(config: dict) -> float:
 def selects_keys(config: dict) -> bool:
     """Whether the attention layers carry an indexer (``sa_config``)."""
     return bool(config.get("sa_config"))
+
+
+def latent(config: dict) -> bool:
+    """Whether attention is latent attention (``kv_lora_rank``)."""
+    return bool(config.get("kv_lora_rank"))
 
 
 # -- the layers ----------------------------------------------------------------
@@ -260,27 +299,93 @@ def sparse_attn_mixer(w: dict, u: jnp.ndarray, config: dict, q_block: int,
         return _mm("blk,kh->blh", o.reshape(*u.shape[:2], -1), w["wo"]), kept
 
 
-def dense_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
+def _pairs_first(w: jnp.ndarray) -> jnp.ndarray:
+    """Reorder the last axis ``(x0, x1, x2, x3, ...)`` to ``(x0, x2, ...,
+    x1, x3, ...)``: the published rotation's pairs ``(2j, 2j + 1)`` become the
+    halves' ``(j, j + d / 2)``."""
+    d = w.shape[-1]
+    return jnp.swapaxes(w.reshape(*w.shape[:-1], d // 2, 2), -1, -2).reshape(w.shape)
+
+
+def latent_operands(w: dict, u: jnp.ndarray, config: dict) -> tuple:
+    """Latent attention's reading of (B, L, h), in the kernel's layouts:
+    ``q_n`` (B, H, L, d_n) and ``q_r`` (B, H, L, d_r), both times the softmax
+    scale; ``k_n`` (B, H, L, d_n), the shared ``k_r`` (B, L, d_r), ``v`` (B,
+    H, L, d_v). ``q_r`` and ``k_r`` are rotated, with their dimensions
+    reordered alike (:func:`_pairs_first`, on the weights' columns)."""
+    with jax.named_scope("lm.attn.latent"):
+        heads = config["num_attention_heads"]
+        dn, dr, dv = (config["qk_nope_head_dim"], config["qk_rope_head_dim"],
+                      config["v_head_dim"])
+        rank, eps, scaling = config["kv_lora_rank"], norm_eps(config), config.get("rope_scaling")
+        freqs = latent_attention.yarn_frequencies(dr, config["rope_theta"], scaling)
+        gain = (latent_attention.yarn_mscale(scaling, "mscale")
+                / latent_attention.yarn_mscale(scaling, "mscale_all_dim"))
+        scale = latent_attention.softmax_scale(dn + dr, scaling)
+
+        def heads_of(c: jnp.ndarray, wu: jnp.ndarray) -> jnp.ndarray:
+            return jnp.einsum("blr,rhd->bhld", c, wu, preferred_element_type=jnp.float32)
+
+        cq = rmsnorm(_mm("blh,hr->blr", u, w["w_dq"]), w["q_a_norm"], eps)
+        w_uq = w["w_uq"].reshape(-1, heads, dn + dr)
+        qn = (heads_of(cq, w_uq[..., :dn]) * scale).astype(u.dtype)
+        qr = latent_attention.rotate_halves(
+            heads_of(cq, _pairs_first(w_uq[..., dn:])), freqs, gain * scale).astype(u.dtype)
+        kr = latent_attention.rotate_halves(
+            jnp.einsum("blh,hr->blr", u, _pairs_first(w["w_dkv"][:, rank:]),
+                       preferred_element_type=jnp.float32), freqs, gain).astype(u.dtype)
+        ckv = rmsnorm(_mm("blh,hr->blr", u, w["w_dkv"][:, :rank]), w["kv_a_norm"], eps)
+        w_ukv = w["w_ukv"].reshape(-1, heads, dn + dv)
+        kn = heads_of(ckv, w_ukv[..., :dn]).astype(u.dtype)
+        v = heads_of(ckv, w_ukv[..., dn:]).astype(u.dtype)
+        return qn, qr, kn, kr, v
+
+
+def latent_attn_mixer(w: dict, u: jnp.ndarray, config: dict,
+                      lengths: jnp.ndarray) -> jnp.ndarray:
+    """Latent attention over all causal keys. (B, L, h), (B,) real lengths."""
+    with jax.named_scope("lm.mixer.attn"):
+        o = latent_attention.attend(*latent_operands(w, u, config), lengths)
+        wo = w["wo"].reshape(o.shape[1], o.shape[3], -1)
+        return _mm("bhld,hdk->blk", o, wo)
+
+
+def dense_ffn(w: dict, u: jnp.ndarray, names: tuple = ("w1", "w3", "w2"),
+              scope: str = "lm.ffn.dense") -> jnp.ndarray:
     """``W_2 (silu(W_1 u) * W_3 u)``. (T, h)."""
-    with jax.named_scope("lm.ffn.dense"):
-        a = jnp.einsum("th,hf->tf", u, w["w1"], preferred_element_type=jnp.float32)
-        g = jnp.einsum("th,hf->tf", u, w["w3"], preferred_element_type=jnp.float32)
-        return _mm("tf,fh->th", (jax.nn.silu(a) * g).astype(u.dtype), w["w2"])
+    with jax.named_scope(scope):
+        a = jnp.einsum("th,hf->tf", u, w[names[0]], preferred_element_type=jnp.float32)
+        g = jnp.einsum("th,hf->tf", u, w[names[1]], preferred_element_type=jnp.float32)
+        return _mm("tf,fh->th", (jax.nn.silu(a) * g).astype(u.dtype), w[names[2]])
+
+
+def shared_ffn(w: dict, u: jnp.ndarray) -> jnp.ndarray:
+    """The shared experts, one gated FFN of their summed width every token
+    passes through: ``W_2^s (silu(W_1^s u) * W_3^s u)``. (T, h)."""
+    return dense_ffn(w, u, ("ws1", "ws3", "ws2"), "lm.ffn.shared")
 
 
 def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> tuple:
     """The sparse expert layer over (T, h) tokens -> (its part of the result
     for the experts held, the (T, k) expert ids the router chose, the (2,)
     int32 visits and row tiles of the experts' kernel)."""
-    if moe.router_kind(config) == "softmax":
+    kind = moe.router_kind(config)
+    if kind == "softmax":
         idx, weights = moe.route_softmax(u, w["router"], config["num_experts_per_tok"],
                                          config.get("norm_topk_prob", True))
+    elif kind == "group_limited":
+        idx, weights = moe.route_group_limited(
+            u, w["router"], config["num_experts_per_tok"], config["n_group"],
+            config["topk_group"], config.get("norm_topk_prob", True),
+            float(config.get("routed_scaling_factor", 1.0)))
     else:
         idx, weights = moe.route(u, w["router"], w["expert_bias"],
                                  config["num_experts_per_tok"],
                                  float(config["routed_scaling_factor"]))
     out, tiles = moe.expert_ffn(u, idx, weights, w["w1"], w["w3"], w["w2"],
-                                config["num_experts"], experts)
+                                router_width(config), experts)
+    if config.get("n_shared_experts"):
+        out = out + shared_ffn(w, u)
     return out, idx, tiles
 
 
@@ -315,19 +420,24 @@ def count_columns(count: jnp.ndarray) -> jnp.ndarray:
 
 
 def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q_BLOCK,
-            head_block: int = HEAD_BLOCK, experts: Optional[tuple] = None) -> jnp.ndarray:
+            head_block: int = HEAD_BLOCK) -> jnp.ndarray:
     """(B, L + 1) int32 — ids, then each row's length — to (B, L - 1 + E + 2)
     float32: next-token log-probabilities (0 from the row's last real token
-    on), the row's real tokens routed to each expert over all layers, and in
-    row 0 the batch's ``[visited, aligned]`` tiles of the experts' kernel;
-    with an indexer four columns more (:func:`count_columns`: the keys
-    attended, the causal keys)."""
+    on), the row's real tokens routed to each of the ``E`` experts the router
+    scores over all layers, and in row 0 the batch's ``[visited, aligned]``
+    tiles of the experts' kernel; with an indexer four columns more
+    (:func:`count_columns`: the keys attended, the causal keys). Where the
+    configuration is a share, the experts of ``expert_range`` alone add to
+    the stream and ids and log-probabilities are over ``vocab_range``."""
     ids, lengths = packed[:, :-1], packed[:, -1]
     rows, length = ids.shape
-    num_experts = config["num_experts"]
+    num_experts = router_width(config)
+    experts = held_range(config, "expert_range")
     eps = norm_eps(config)
     real = jnp.arange(length)[None, :] < lengths[:, None]
     kept = 0.0
+    if "vocab_range" in config:  # the stage refuses an id outside the slice; batch padding is 0
+        ids = jnp.maximum(ids - held_range(config, "vocab_range")[0], 0)
     with jax.named_scope("lm.embed"):
         x = variables["embed"][ids].astype(jnp.float32)
     load = jnp.zeros((rows, num_experts), jnp.float32)
@@ -336,6 +446,8 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
         u = rmsnorm(x, w["norm_op"], eps)
         if mixer == "conv":
             y = conv_mixer(w, u)
+        elif latent(config):
+            y = latent_attn_mixer(w, u, config, lengths)
         elif selects_keys(config):
             y, n = sparse_attn_mixer(w, u, config, q_block, real)
             kept = kept + count_columns(n)
@@ -427,8 +539,11 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
         inner = self._build()
         buckets = self._buckets()
         edges = np.array([length for length, _ in buckets])
-        num_experts = int(self.get_or_fail("config")["num_experts"])
-        counts_keys = selects_keys(self.get_or_fail("config"))
+        config = self.get_or_fail("config")
+        num_experts = router_width(config)
+        held = held_range(config, "expert_range")
+        ids_lo, ids_hi = held_range(config, "vocab_range") or (0, None)
+        counts_keys = selects_keys(config)
 
         def fn(p: Partition) -> Partition:
             rows = [np.asarray(r, np.int32) for r in p[ic]]
@@ -437,6 +552,10 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                 raise ValueError(
                     f"CausalLMScorer: rows of {lens.min()}..{lens.max()} tokens; a row needs "
                     f"2 tokens at least and {edges[-1]} (the longest bucket) at most")
+            if ids_hi is not None and any(
+                    len(r) and (r.min() < ids_lo or r.max() >= ids_hi) for r in rows):
+                raise ValueError(f"CausalLMScorer: this share holds the ids [{ids_lo}, {ids_hi}) "
+                                 "of the vocabulary; a row has an id outside them")
             bucket_of = np.searchsorted(edges, lens, side="left")
             out = np.empty(len(rows), dtype=object)
             routed = np.zeros(num_experts, np.float64)
@@ -449,7 +568,7 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                     at = np.nonzero(bucket_of == b)[0]
                     if not len(at):
                         continue
-                    packed = np.zeros((len(at), length + 1), np.int32)
+                    packed = np.full((len(at), length + 1), ids_lo, np.int32)  # the pad id
                     for j, i in enumerate(at):
                         packed[j, :lens[i]] = rows[i]
                     packed[:, -1] = lens[at]
@@ -470,6 +589,9 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                 sp.set_attr("tokens_padded", padded)
                 sp.set_attr("gmm_tiles_visited", int(tiles[0]))
                 sp.set_attr("gmm_tiles_aligned", int(tiles[1]))
+                if held is not None:
+                    sp.set_attr("moe_pairs_held", int(routed[held[0]:held[1]].sum()))
+                    sp.set_attr("moe_pairs_routed", int(routed.sum()))
                 if counts_keys:
                     selected, causal = (int(hi) * (1 << COUNT_BITS) + int(lo)
                                         for hi, lo in keys.reshape(2, 2))

@@ -238,3 +238,63 @@ def test_sparse_attention_compiles_for_one_v5e_chip_at_published_widths(v5e, row
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and "sparse_attend" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_held_expert_layer_compiles_for_one_v5e_chip_at_published_widths(v5e, monkeypatch):
+    """One chip's share of the ``deepseek_v2`` expert layer — the
+    group-limited router over 160, 20 experts of 5120 x 1536 held, a batch of
+    16,384 tokens — compiles for one chip: the products are two calls of
+    ``expert_gmm`` inside the share's device loop, over blocks of 24,576
+    gathered rows and not the 98,304 pairs there are (``tiling`` narrows the
+    up-call's block: its two matrices are 31.5 MB), and the layer's
+    temporaries stay under 2 GB."""
+    from jax.sharding import SingleDeviceSharding
+
+    from mmlspark_tpu.ops import moe
+
+    monkeypatch.setattr(H, "_target_device", lambda mesh=None: v5e[0])
+    one = SingleDeviceSharding(v5e[0])
+    tokens, h, f, experts, held, k = 16_384, 5120, 1536, 160, 20, 6
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def layer(u, gate, w1, w3, w2):
+        idx, weights = moe.route_group_limited(u, gate, k, 8, 3, False, 16.0)
+        return moe.expert_ffn(u, idx, weights, w1, w3, w2, experts, (0, held))
+
+    compiled = jax.jit(layer).lower(
+        spec((tokens, h), jnp.bfloat16), spec((h, experts), jnp.float32),
+        spec((held, h, f), jnp.bfloat16), spec((held, h, f), jnp.bfloat16),
+        spec((held, f, h), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 2 and all("lm.moe.experts" in ln and "expert_gmm" in ln for ln in calls)
+    assert all("bf16[24576," in ln for ln in calls) and "ragged-dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("rows,keys", [(1, 16_384), (8, 2_048)], ids=["one_row", "eight_rows"])
+def test_latent_attention_compiles_for_one_v5e_chip_at_published_widths(v5e, rows, keys):
+    """A batch of the ``deepseek_v2`` cell — 128 heads of 128 + 64 score and
+    128 value dimensions, one row of 16,384 or eight of 2,048 — compiles for
+    one chip: the causal pairs are one Mosaic kernel that takes the heads' own
+    keys and the shared rotated key as separate operands, and no score
+    tensor exists as an array (the temporaries are the one copy the compiler
+    makes of a 64-wide operand into its padded layout)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from mmlspark_tpu.ops import latent_attention as la
+
+    one = SingleDeviceSharding(v5e[0])
+    call = H._pallas_call_kwargs(v5e[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = jax.jit(lambda *a: la.attend_kernel(*a, **call)).lower(
+        spec((rows, 128, keys, 128)), spec((rows, 128, keys, 64)), spec((rows, 128, keys, 128)),
+        spec((rows, keys, 64)), spec((rows, 128, keys, 128)), spec((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "latent_attend" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
