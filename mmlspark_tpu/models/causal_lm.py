@@ -43,8 +43,11 @@ up to its end — a Python loop over the blocks of rows of a few thousand
 tokens, a device loop where an indexer selects the keys and rows run to
 32,768, a flash kernel over the causal tiles for latent attention's 128
 heads — never a whole ``L x L`` score matrix) and the head folds the
-vocabulary into a log-sum-exp a block of tokens at a time, never the whole
-``tokens x vocabulary`` logits.
+vocabulary into a log-sum-exp a tile of tokens at a time, never the whole
+``tokens x vocabulary`` logits: on a TPU one Pallas kernel in which a tile's
+logits live and die in VMEM and token tiles of right padding are skipped
+(:mod:`mmlspark_tpu.ops.vocab_head`), elsewhere a block of tokens' logits
+in float32 at a time.
 
 :class:`CausalLMScorer` sorts a partition's rows into the length buckets it
 was given, pads on the right and drives ``XLAModel.apply_batch`` once per
@@ -77,7 +80,7 @@ from mmlspark_tpu.core.dataframe import DataFrame, Partition
 from mmlspark_tpu.core.params import ComplexParam, HasInputCol, HasOutputCol
 from mmlspark_tpu.core.pipeline import Model
 from mmlspark_tpu.models.xla_model import XLAModel
-from mmlspark_tpu.ops import latent_attention, moe, sparse_attention
+from mmlspark_tpu.ops import histogram, latent_attention, moe, sparse_attention, vocab_head
 
 _M_TOKENS = obs.counter(
     "mmlspark_lm_tokens_total",
@@ -101,6 +104,15 @@ _M_GMM_TILES = obs.counter(
     "ragged_dot; carried out of the program with each batch's output",
     labels=("kind",),
 )
+_M_HEAD_TILES = obs.counter(
+    "mmlspark_lm_head_tiles_total",
+    "Token tiles of the head's kernel (ops/vocab_head.py): kind=visited are "
+    "the tiles it multiplied by the vocabulary, kind=skipped those it neither "
+    "fetched nor multiplied because no position of theirs has a next token "
+    "(right padding); 0 where the head ran as XLA operations; counted from "
+    "the rows' lengths by the kernel's own tiling rule",
+    labels=("kind",),
+)
 _M_ATTN_KEYS = obs.counter(
     "mmlspark_lm_attn_keys_total",
     "Keys the real positions of a learned sparse attention met, summed over "
@@ -117,8 +129,9 @@ _M_ATTN_KEYS = obs.counter(
 # (an indexer's products, batch x its heads x Q_BLOCK x keys, are half that
 # at 16 heads); latent attention, 128 heads, never holds such a tensor on a
 # TPU and sizes its own block elsewhere (ops/latent_attention.py); and tokens
-# per block of the head's log-sum-exp (0.5 GB of logits at 65,536
-# ids, 1.2 GB at 151,936)
+# per block of the head's log-sum-exp in its XLA form, which holds a block's
+# logits in HBM (0.5 GB at 65,536 ids, 1.2 GB at 151,936; the kernel a TPU
+# runs holds none and takes its tiles from ops/vocab_head.tiling)
 Q_BLOCK = 256
 HEAD_BLOCK = 2048
 # the four trailing columns of a batch's output where an indexer selects keys
@@ -154,6 +167,12 @@ def held_range(config: dict, key: str) -> Optional[tuple]:
 def norm_eps(config: dict) -> float:
     """``norm_eps`` (lfm2_moe's name) or ``rms_norm_eps`` (qwen3_moe's)."""
     return config["norm_eps"] if "norm_eps" in config else config["rms_norm_eps"]
+
+
+def head_matrix(variables: dict, config: dict) -> Any:
+    """The head's (V, h) matrix: the embedding unless ``tie_word_embeddings``
+    is false."""
+    return variables["embed" if config.get("tie_word_embeddings", True) else "head"]
 
 
 def selects_keys(config: dict) -> bool:
@@ -390,12 +409,20 @@ def moe_ffn(w: dict, u: jnp.ndarray, config: dict, experts: Optional[tuple]) -> 
 
 
 def head_logprobs(embed: jnp.ndarray, u: jnp.ndarray, targets: jnp.ndarray,
-                  block: int) -> jnp.ndarray:
+                  block: int, work: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """(T, h) normed final states, the head's (V, h) matrix (the embedding
     where they are tied) and (T,) target ids -> (T,) float32
-    ``log softmax(u E^T)[target]``, ``block`` tokens at a time: the logits of
-    a block in float32, their log-sum-exp, the target's logit, and on."""
+    ``log softmax(u E^T)[target]``. On a TPU, where the shapes can be tiled
+    (:func:`mmlspark_tpu.ops.vocab_head.plan`), one kernel that keeps a
+    tile's logits in VMEM and skips the token tiles none of whose positions
+    is marked in ``work`` (T,) bool (they get 0; default: all have work).
+    Elsewhere ``block`` tokens at a time: the logits of a block in float32,
+    their log-sum-exp, the target's logit, and on (every position)."""
     tokens = u.shape[0]
+    tiles = vocab_head.plan(tokens, u.shape[1], embed.shape[0])
+    if tiles is not None:
+        return vocab_head.head_kernel(embed, u, targets, work, tiles=tiles,
+                                      **histogram._pallas_call_kwargs())
     block = min(block, tokens)
     if tokens % block:
         raise ValueError(f"{tokens} tokens are no multiple of the head's block {block}")
@@ -466,10 +493,10 @@ def forward(variables: dict, packed: jnp.ndarray, config: dict, q_block: int = Q
     with jax.named_scope("lm.head"):
         u = rmsnorm(x, variables["norm"], eps).reshape(rows * length, -1)
         targets = jnp.concatenate([ids[:, 1:], jnp.zeros((rows, 1), ids.dtype)], axis=1)
-        head = variables["embed" if config.get("tie_word_embeddings", True) else "head"]
-        logp = head_logprobs(head, u, targets.reshape(-1), head_block)
-        logp = logp.reshape(rows, length)[:, :-1]
-        logp = jnp.where(real[:, 1:], logp, 0.0)
+        work = vocab_head.has_next(lengths, length)  # the rest read as 0
+        logp = head_logprobs(head_matrix(variables, config), u, targets.reshape(-1),
+                             head_block, work.reshape(-1))
+        logp = jnp.where(work, logp.reshape(rows, length), 0.0)[:, :-1]
     # a count of the batch: its first row carries it (every batch has a real one)
     tiles = jnp.zeros((rows, 2), jnp.float32).at[0].set(tiles.astype(jnp.float32))
     if not selects_keys(config):
@@ -544,6 +571,9 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
         held = held_range(config, "expert_range")
         ids_lo, ids_hi = held_range(config, "vocab_range") or (0, None)
         counts_keys = selects_keys(config)
+        vocab, width = head_matrix(self.get_or_fail("variables"), config).shape
+        # what the head's kernel visits follows from a batch's shape and its rows' lengths
+        head_plans = [vocab_head.plan(batch * length, width, vocab) for length, batch in buckets]
 
         def fn(p: Partition) -> Partition:
             rows = [np.asarray(r, np.int32) for r in p[ic]]
@@ -561,6 +591,7 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
             routed = np.zeros(num_experts, np.float64)
             tiles = np.zeros(2, np.float64)  # the experts' kernel: visited, aligned
             keys = np.zeros(4, np.float64)  # selected and causal, each (high, low)
+            head_tiles = np.zeros(2, np.int64)  # the head's kernel: visited, skipped
             real = padded = 0
             # one trace per partition; every bucket's apply_batch is a child
             with obs.span("lm.score", attrs={"rows": len(rows)}) as sp:
@@ -585,10 +616,16 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                         keys += counts[num_experts + 2:]
                     real += int(lens[at].sum())
                     padded += batches * batch * length - int(lens[at].sum())
+                    if head_plans[b] is not None:
+                        sent = np.zeros(batches * batch, np.int64)  # batch padding: no tokens
+                        sent[:len(at)] = lens[at]
+                        head_tiles += vocab_head.count_tiles(sent, length, head_plans[b][0])
                 sp.set_attr("tokens_real", real)
                 sp.set_attr("tokens_padded", padded)
                 sp.set_attr("gmm_tiles_visited", int(tiles[0]))
                 sp.set_attr("gmm_tiles_aligned", int(tiles[1]))
+                sp.set_attr("head_tiles", int(head_tiles[0]))
+                sp.set_attr("head_tiles_skipped", int(head_tiles[1]))
                 if held is not None:
                     sp.set_attr("moe_pairs_held", int(routed[held[0]:held[1]].sum()))
                     sp.set_attr("moe_pairs_routed", int(routed.sum()))
@@ -603,6 +640,8 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
                 _M_ROUTED.labels(expert=str(e)).inc(float(n))
             _M_GMM_TILES.labels(kind="visited").inc(int(tiles[0]))
             _M_GMM_TILES.labels(kind="aligned").inc(int(tiles[1]))
+            _M_HEAD_TILES.labels(kind="visited").inc(int(head_tiles[0]))
+            _M_HEAD_TILES.labels(kind="skipped").inc(int(head_tiles[1]))
             if counts_keys:
                 _M_ATTN_KEYS.labels(kind="selected").inc(selected)
                 _M_ATTN_KEYS.labels(kind="causal").inc(causal)

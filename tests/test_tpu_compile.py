@@ -298,3 +298,39 @@ def test_latent_attention_compiles_for_one_v5e_chip_at_published_widths(v5e, row
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and "latent_attend" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+@pytest.mark.parametrize("tokens,h,vocab", [
+    (32_768, 2048, 65_536), (32_768, 2048, 151_936), (16_384, 5120, 12_800)],
+    ids=["lfm2_8b_a1b", "keye_vl2_30b_a3b", "deepseek_v2"])
+def test_head_compiles_for_one_v5e_chip_at_published_widths(v5e, monkeypatch, tokens, h, vocab):
+    """The head of the three language-model cells — a batch's 32,768 (16,384)
+    final states against 65,536 tied ids, 151,936 untied ones (1,187 x 128:
+    a partial last tile) and the held slice of 12,800 at a width of 5,120 —
+    compiles for one chip as the one Mosaic kernel ``head_logprobs`` under
+    ``lm.head``: it reads the matrix where it lies (no padded or transposed
+    copy: the program's temporaries are a few kilobytes) and no
+    ``tokens x vocabulary`` logits exist as an array."""
+    from jax.sharding import SingleDeviceSharding
+
+    from mmlspark_tpu.models import causal_lm as lm
+
+    monkeypatch.setattr(H, "_target_device", lambda mesh=None: v5e[0])
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def head(matrix, u, targets, work):
+        with jax.named_scope("lm.head"):
+            return lm.head_logprobs(matrix, u, targets, lm.HEAD_BLOCK, work)
+
+    compiled = jax.jit(head).lower(
+        spec((vocab, h), jnp.bfloat16), spec((tokens, h), jnp.bfloat16),
+        spec((tokens,), jnp.int32), spec((tokens,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and "lm.head" in calls[0] and "head_logprobs" in calls[0]
+    assert f"bf16[{vocab},{h}]" in calls[0]           # the matrix as it was handed in
+    assert f",{vocab}]" not in text.replace(f"bf16[{vocab},{h}]", "")    # no logits
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
