@@ -12,17 +12,22 @@ OpenCV); here those engines are rebuilt TPU-first (JAX/XLA/Pallas) with a
 lightweight partitioned-columnar DataFrame as the dataflow substrate.
 """
 
-from mmlspark_tpu.version import __version__
+# obs is stdlib-only and comes first: the span times the imports below, and
+# a package imported inside it (models, models.gbdt) nests its own span
+from mmlspark_tpu import obs
 
-from mmlspark_tpu.core.dataframe import DataFrame, Row
-from mmlspark_tpu.core.pipeline import (
-    Estimator,
-    Model,
-    Pipeline,
-    PipelineModel,
-    Transformer,
-    load_stage,
-)
+with obs.span("mmlspark.import", attrs={"module": __name__}):
+    from mmlspark_tpu.version import __version__
+
+    from mmlspark_tpu.core.dataframe import DataFrame, Row
+    from mmlspark_tpu.core.pipeline import (
+        Estimator,
+        Model,
+        Pipeline,
+        PipelineModel,
+        Transformer,
+        load_stage,
+    )
 
 __all__ = [
     "__version__",

@@ -4,15 +4,16 @@ the Timer pipeline stage; the TPU equivalent adds device-level tracing).
 - :func:`trace` captures the XLA/TPU device timeline (TensorBoard/
   Perfetto) with the profiler's host tracers off, and writes the ``obs``
   spans of the capture beside it on the same (epoch) clock.
-- :func:`annotate` marks host spans so stage boundaries show up inside the
-  device trace (the log-per-stage analogue of stages/Timer.scala:57-92).
+- :func:`annotate` marks a host span in the profiler's own host trace,
+  for callers who run their own capture with the host tracer on (the
+  log-per-stage analogue of stages/Timer.scala:57-92).
 - :class:`ProfiledRun` collects per-stage wall times for a pipeline the
   way VW's TrainingStats DataFrame reports per-partition timings. Stage
   timings ride the obs span API (``mmlspark_tpu.obs``), so each stage
   lands in the process metrics registry as
-  ``mmlspark_trace_span_seconds{span="pipeline.<Stage>"}`` AND nests into
-  any active ``jax.profiler`` capture — the same numbers show up on
-  ``/metrics`` and in Perfetto.
+  ``mmlspark_trace_span_seconds{span="pipeline.<Stage>"}`` AND lands in
+  ``obs_spans.json`` beside a :func:`trace` capture — the same numbers show
+  up on ``/metrics`` and next to the device timeline.
 """
 
 from __future__ import annotations
@@ -62,7 +63,8 @@ def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
 
 
 def annotate(name: str) -> Any:
-    """Named host span that nests into the profiler timeline."""
+    """Named host span in the profiler's host trace: shows only in a capture
+    whose host tracer is on (not :func:`trace`'s, see there)."""
     return jax.profiler.TraceAnnotation(name)
 
 

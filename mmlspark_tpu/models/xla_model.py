@@ -220,9 +220,16 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
                 dt = self.get("input_dtype")
                 x = np.asarray(x, dtype=dt) if dt else np.asarray(x)
                 padded, n = pad_batch(x, bs)
-                fn = self._compiled(padded[:bs].shape, mesh)
+                shape = padded[:bs].shape
+                program_new = (shape, id(mesh)) not in self._jit_cache
+                fn = self._compiled(shape, mesh)
             sp.set_attr("rows", int(n))
             sp.set_attr("batches", padded.shape[0] // bs)
+            if program_new:
+                # this call builds the shape's program: the xla.trace,
+                # xla.lower and xla.compile spans under its first dispatch
+                sp.set_attr("program_new", True)
+                sp.set_attr("shape", list(shape))
             mine: list = []
             with obs.span("xla_model.turn"):
                 feed.turns.acquire()

@@ -3,8 +3,7 @@
 The reference ships per-stage StopWatch timers and VW TrainingStats
 DataFrames; production visibility there came from Spark's own metrics
 system. This package is the TPU rebuild's equivalent substrate — a
-dependency-free (stdlib-only; jax is touched lazily and optionally)
-telemetry layer every subsystem reports into:
+dependency-free (stdlib-only: it never imports jax) telemetry layer every subsystem reports into:
 
 - :class:`MetricsRegistry` — process-wide counters, gauges and
   fixed-bucket histograms with labels; thread-safe; snapshot +
@@ -12,9 +11,10 @@ telemetry layer every subsystem reports into:
   :func:`parse_text` for the fleet aggregator.
 - :func:`span` / :func:`record_span` — host-side tracing with trace-id
   propagation (the gateway stamps :data:`TRACE_HEADER` into forwarded
-  requests; workers continue the trace). Spans export both to the
-  registry (``mmlspark_trace_span_seconds`` latency histograms) and to
-  ``jax.profiler.TraceAnnotation`` so host spans nest into device traces.
+  requests; workers continue the trace). Spans export to the registry
+  (``mmlspark_trace_span_seconds`` latency histograms) and to the process
+  span buffer (``GET /traces``); ``core.profiling.trace`` writes the
+  buffered spans beside a device capture, on the capture's epoch clock.
 
 Metric names follow ``mmlspark_<subsystem>_<name>_<unit>`` — enforced by
 ``tools/lint_metric_names.py``. Catalogue: docs/observability.md.

@@ -1,5 +1,4 @@
-"""Span tracing with trace-id propagation, a scrape-able span buffer,
-and device-trace nesting.
+"""Span tracing with trace-id propagation and a scrape-able span buffer.
 
 A :class:`Span` is a named host-side interval tied to a trace id. The
 gateway mints a trace id per ingress request and stamps it into the
@@ -10,18 +9,21 @@ HTTP hop). :data:`PARENT_HEADER` carries the sender's span id the same
 way, so a worker's spans parent under the gateway's forward span and the
 trace collector (obs/traces.py) can assemble a true cross-process tree.
 
-Spans land in three places:
+Spans land in two places:
 
 - the default metrics registry, as the ``mmlspark_trace_span_seconds``
   histogram labeled by span name — so every span family gets a latency
   distribution for free on ``/metrics``;
 - the process :class:`SpanBuffer` (:data:`BUFFER`) — a bounded ring of
   finished spans, with attrs, served as JSON on ``GET /traces`` by every
-  instrumented server; the trace collector scrapes and joins these;
-- ``jax.profiler.TraceAnnotation`` (lazily imported, optional) — inside a
-  ``jax.profiler.trace`` capture the host span nests into the device
-  timeline, which is how "queue wait vs. TPU dispatch" becomes visible in
-  one Perfetto view.
+  instrumented server; the trace collector scrapes and joins these.
+
+``Span.wall_ns`` is epoch nanoseconds, the clock of a profiler trace's
+``profile_start_time``: ``core.profiling.trace`` and the benchmark write the
+buffered spans beside a device capture and lay them on its timeline as they
+are, which is how "queue wait vs. TPU dispatch" becomes visible. (Spans
+enter no ``jax.profiler`` annotation: both captures keep the profiler's
+host tracer off, so none would reach a trace.)
 
 :func:`recent_spans` is the test/debug view of the same buffer.
 """
@@ -77,23 +79,6 @@ def _span_child(name: str) -> Any:
     if ch is None:
         ch = _span_children[name] = _SPAN_SECONDS.labels(span=name)
     return ch
-
-# jax.profiler.TraceAnnotation, resolved lazily once: None = not yet
-# tried, False = unavailable (obs stays importable without jax)
-_TA: Any = None
-
-
-def _trace_annotation() -> Any:
-    global _TA
-    if _TA is None:
-        try:
-            from jax.profiler import TraceAnnotation
-
-            _TA = TraceAnnotation
-        except Exception:  # noqa: BLE001 — jax absent or too old
-            _TA = False
-    return _TA
-
 
 # id generation: uniqueness, not cryptography. uuid4 reads the OS entropy
 # pool per call (~14 µs in sandboxed containers) — far too slow for a
@@ -310,7 +295,7 @@ class _SpanContext:
     generator protocol costs ~2 µs per use, and spans wrap every
     dispatched serving batch)."""
 
-    __slots__ = ("_name", "_trace_id", "_parent_id", "_attrs", "_sp", "_ann")
+    __slots__ = ("_name", "_trace_id", "_parent_id", "_attrs", "_sp")
 
     def __init__(self, name: str, trace_id: Optional[str],
                  attrs: Optional[dict], parent_id: Optional[str] = None):
@@ -330,19 +315,13 @@ class _SpanContext:
             or (parent.span_id if parent else None),
             attrs=self._attrs,
         )
-        ta_cls = _trace_annotation()
-        self._ann = ta_cls(self._name) if ta_cls else None
         stack.append(sp)
         self._sp = sp
         sp.wall_ns = time.time_ns()
         sp.start_ns = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__enter__()
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
         sp = self._sp
         sp.end_ns = time.perf_counter_ns()
         _stack().pop()
@@ -361,10 +340,8 @@ def span(
     Trace id resolution: explicit argument > enclosing span on this
     thread > freshly minted. Parent resolution: explicit ``parent_id``
     (e.g. a received :data:`PARENT_HEADER` value) > enclosing span on
-    this thread. The span enters a ``jax.profiler.TraceAnnotation`` of
-    the same name (a no-op outside an active profiler capture), so host
-    stages show up nested in device traces. The span is recorded on BOTH
-    clean and exceptional exit."""
+    this thread. The span is recorded on BOTH clean and exceptional
+    exit."""
     return _SpanContext(name, trace_id, attrs, parent_id)
 
 

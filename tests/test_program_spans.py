@@ -278,3 +278,196 @@ def test_profiling_trace_keeps_the_host_tracers_off_and_writes_the_spans(
     assert inside["wall_ns"] >= written["capture_start_ns"] and inside["attrs"] == {"k": 1}
     assert any(fn.endswith(".xplane.pb") for _d, _s, files in os.walk(str(tmp_path))
                for fn in files)
+
+
+# -- the cold path: a program's first call (PR 35) ---------------------------------
+
+def _salt():
+    return float(time.time_ns() % 1_000_003)   # a program no cache has seen
+
+
+def _union_ns(spans):
+    total, at = 0, None
+    for s, e in sorted((sp.start_ns, sp.end_ns) for sp in spans):
+        if at is None or s > at:
+            total += e - s
+            at = e
+        elif e > at:
+            total += e - at
+            at = e
+    return total
+
+
+@pytest.mark.parametrize("kind,fun", [("xla.trace", "first_call"),
+                                      ("xla.lower", "jit(first_call)"),
+                                      ("xla.compile", "jit(first_call)")])
+def test_a_first_call_under_a_span_records_its_stage_as_a_child(
+        monkeypatch, fresh_obs, kind, fun):
+    from mmlspark_tpu.core import compile_cache
+
+    # jnp's own traces inside first_call's stay out however slow the machine
+    monkeypatch.setattr(compile_cache, "_NESTED_TRACE_FLOOR_S", 3600.0)
+    salt = _salt()
+
+    def first_call(v):
+        return jnp.sin(v) * salt + jnp.cos(v - salt)
+
+    fn = jax.jit(first_call)
+    x = jnp.arange(17.0)
+    x.block_until_ready()
+    obs.clear_recent_spans()
+    with obs.span("some.caller") as caller:
+        t0 = time.time_ns()
+        fn(x).block_until_ready()
+        t1 = time.time_ns()
+    mine = [s for s in obs.recent_spans(kind) if s.parent_id == caller.span_id]
+    assert len(mine) == 1, [(s.name, s.attrs) for s in obs.recent_spans()]
+    sp = mine[0]
+    assert sp.attrs["fun"] == fun and sp.trace_id == caller.trace_id
+    assert t0 <= sp.wall_ns and sp.wall_ns + sp.duration_ns <= t1
+    assert caller.start_ns <= sp.start_ns and sp.end_ns <= caller.end_ns
+    # the three stages of one call follow each other
+    order = [s.name for s in sorted(obs.recent_spans(), key=lambda s: s.start_ns)
+             if s.parent_id == caller.span_id]
+    assert order == ["xla.trace", "xla.lower", "xla.compile"]
+    with obs.span("some.caller"):
+        fn(x).block_until_ready()   # built: no further stage
+    assert len(obs.recent_spans(kind)) == 1
+
+
+@pytest.mark.parametrize("floor,nested", [(0.0, True), (3600.0, False)])
+def test_a_jit_inside_a_jit_nests_its_trace_and_short_ones_are_left_out(
+        monkeypatch, fresh_obs, floor, nested):
+    from mmlspark_tpu.core import compile_cache
+
+    # (the floor itself is 5 ms: a loaded machine traces slower than that)
+    monkeypatch.setattr(compile_cache, "_NESTED_TRACE_FLOOR_S", floor)
+    salt = _salt()
+
+    @jax.jit
+    def inner(v):
+        return jnp.tanh(v) + salt
+
+    def outer(v):
+        for _ in range(3):
+            v = inner(v) * jnp.cos(v)
+        return v
+
+    x = jnp.arange(9.0)
+    x.block_until_ready()
+    obs.clear_recent_spans()
+    jax.jit(outer)(x).block_until_ready()
+    traces = obs.recent_spans("xla.trace")
+    funs = [s.attrs["fun"] for s in traces]
+    assert funs.count("outer") == 1
+    whole = [s for s in traces if s.attrs["fun"] == "outer"][0]
+    if not nested:
+        # jnp's own jitted functions and the inner jit fired events inside
+        # outer's trace, all under the floor: none is a span, and outer's
+        # own, as short, is one because nothing contained it
+        assert funs == ["outer"]
+        return
+    assert "inner" in funs and len(traces) >= 3
+    for s in traces:
+        assert whole.start_ns <= s.start_ns and s.end_ns <= whole.end_ns
+    assert _union_ns(traces) == whole.duration_ns < sum(s.duration_ns for s in traces)
+
+
+def test_program_new_marks_the_call_that_built_a_shapes_program(fresh_obs):
+    stage = _featurizer(batch=8)
+    df = DataFrame.from_dict({"image": _images(11)})
+    for first in (True, False):
+        obs.clear_recent_spans()
+        stage.transform(df)["features"]
+        (call,) = obs.recent_spans("xla_model.apply_batch")
+        if first:
+            assert call.attrs["program_new"] is True and call.attrs["shape"] == [8, 8, 8, 3]
+            built = [s for s in obs.recent_spans("xla.lower") if s.trace_id == call.trace_id]
+            assert [s.attrs["fun"] for s in built] == ["jit(run)"]
+        else:
+            assert "program_new" not in call.attrs and "shape" not in call.attrs
+            assert not obs.recent_spans("xla.lower")
+
+
+_COLD_PROCESS = """
+import json, sys
+import jax, jax.numpy as jnp
+from mmlspark_tpu import obs
+with obs.span("an.importer") as importer:
+    import mmlspark_tpu.models.gbdt
+from mmlspark_tpu.core.compile_cache import enable_compile_cache
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+x = jnp.arange(19.0)
+x.block_until_ready()
+
+def f(v):
+    return jnp.sin(v) * 3 - jnp.cos(v)
+
+def call():   # one call site: the cache key holds the caller's source line
+    with obs.span("a.caller"):
+        jax.jit(f)(x).block_until_ready()
+
+starts = []
+for _ in range(2):
+    jax.clear_caches()   # a new process, as far as jit can tell
+    starts.append(len(obs.recent_spans()))
+    call()
+rows = [dict(s.to_dict(), start_ns=s.start_ns, end_ns=s.end_ns) for s in obs.recent_spans()]
+print(json.dumps({"spans": rows, "starts": starts, "importer": importer.span_id}))
+"""
+
+
+@pytest.fixture(scope="module")
+def cold_process(tmp_path_factory):
+    """A fresh interpreter with a cache directory of its own: imports the
+    packages, then calls a new ``jit`` of one function twice."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path_factory.mktemp("xla_cache")))
+    out = subprocess.run([sys.executable, "-c", _COLD_PROCESS], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("module", ["mmlspark_tpu", "mmlspark_tpu.models",
+                                    "mmlspark_tpu.models.gbdt"])
+def test_each_package_records_one_import_span(cold_process, module):
+    spans = [s for s in cold_process["spans"] if s["name"] == "mmlspark.import"]
+    assert sorted(s["attrs"]["module"] for s in spans) == [
+        "mmlspark_tpu", "mmlspark_tpu.models", "mmlspark_tpu.models.gbdt"]
+    (sp,) = [s for s in spans if s["attrs"]["module"] == module]
+    assert sp["duration_ms"] > 0
+    if module == "mmlspark_tpu":
+        # ``from mmlspark_tpu import obs`` ran it before any span was open
+        assert sp["parent_id"] is None
+    else:
+        # imported under an open span, it nests through the thread's stack
+        assert sp["parent_id"] == cold_process["importer"]
+
+
+def test_a_cache_hit_records_its_retrieval_as_a_child_and_what_it_saved(cold_process):
+    spans, (first, second) = cold_process["spans"], cold_process["starts"]
+
+    def request(rows):
+        (caller,) = [s for s in rows if s["name"] == "a.caller"]
+        kids = [s for s in rows if s["parent_id"] == caller["span_id"]]
+        (compile_,) = [s for s in kids if s["name"] == "xla.compile"]
+        assert {s["name"] for s in kids} == {"xla.trace", "xla.lower", "xla.compile"}
+        return compile_, [s for s in rows if s["name"] == "xla.retrieve"]
+
+    miss, none = request(spans[first:second])
+    assert miss["attrs"]["cache"] == "miss" and none == []
+    assert "saved_s" not in miss["attrs"] and "retrieval_s" not in miss["attrs"]
+    hit, (got,) = request(spans[second:])
+    assert hit["attrs"]["cache"] == "hit" and hit["attrs"]["fun"] == "jit(f)"
+    assert got["parent_id"] == hit["span_id"] and got["trace_id"] == hit["trace_id"]
+    assert hit["start_ns"] <= got["start_ns"] and got["end_ns"] <= hit["end_ns"]
+    assert hit["attrs"]["retrieval_s"] * 1e9 == pytest.approx(
+        got["end_ns"] - got["start_ns"], abs=2)
+    assert isinstance(hit["attrs"]["saved_s"], float)

@@ -47,7 +47,9 @@ def test_a_batch_size_per_call_is_a_compiled_shape_per_bucket(rng, length, batch
     mesh = get_mesh()
     assert set(m._jit_cache) == {((batch, length + 1), id(mesh))}
     span = [s for s in obs.recent_spans() if s.name == "xla_model.apply_batch"][-1]
-    assert span.attrs == {"rows": 2 * batch + 3, "batches": 3, "overlapped": False}
+    # the call that built the bucket's program says so, and which shape
+    assert span.attrs == {"rows": 2 * batch + 3, "batches": 3, "overlapped": False,
+                          "program_new": True, "shape": [batch, length + 1]}
     # the stage's own batch size still serves a call that names none
     np.testing.assert_array_equal(m.apply_batch(packed), want)
     assert set(m._jit_cache) == {((batch, length + 1), id(mesh)), ((16, length + 1), id(mesh))}
