@@ -539,11 +539,11 @@ class CausalLMScorer(Model, HasInputCol, HasOutputCol):
             def apply_fn(vs: Any, packed: Any) -> Any:
                 return forward(vs, packed, config)
 
-            self._inner = XLAModel(
-                input_col="__tokens__", output_col=self.get_or_fail("output_col"),
-                input_dtype=None,  # int32 ids stay int32
-            )
+            self._inner = XLAModel(input_col="__tokens__", output_col=self.get_or_fail("output_col"),
+                                   input_dtype=None)  # int32 ids stay int32
             self._inner.set(apply_fn=apply_fn, variables=self.get_or_fail("variables"))
+            # forward and the config it reads: a warm start loads each bucket's program untraced
+            self._inner.program_identity = ("mmlspark_tpu.models.causal_lm.forward", config)
         return self._inner
 
     def _buckets(self) -> list:

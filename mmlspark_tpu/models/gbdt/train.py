@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mmlspark_tpu import obs
-from mmlspark_tpu.core import faults
+from mmlspark_tpu.core import compile_cache, faults
 from mmlspark_tpu.models.gbdt import objectives
 from mmlspark_tpu.parallel.mesh import DATA_AXIS as _DATA_AXIS
 from mmlspark_tpu.models.gbdt.binning import BinMapper
@@ -588,8 +588,8 @@ _PACK_FIELDS = (
 )
 
 
-@functools.partial(
-    jax.jit,
+@compile_cache.stored_jit(  # a warm start loads it from the program store, untraced
+    name="mmlspark_tpu.models.gbdt.train._scan_chunk",
     static_argnames=(
         "objective", "k", "grad_pre", "is_goss", "has_cat",
         "num_leaves", "max_depth", "min_data_in_leaf", "top_k", "grower",

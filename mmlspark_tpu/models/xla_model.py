@@ -174,7 +174,7 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
                 return out
 
             # two callers that miss at once keep one program
-            fn = self._jit_cache.setdefault(key, jax.jit(run))
+            fn = self._jit_cache.setdefault(key, self._program(run, node))
         return fn
 
     # how many minibatches of this model may be in flight on device at once,
@@ -285,3 +285,17 @@ class XLAModel(Model, HasInputCol, HasOutputCol, HasBatchSize):
         # call overlap comes from async dispatch inside JAX, and between
         # calls from apply_batch's turns, for a caller that has two to make
         return df.map_partitions(fn, parallel=False)
+
+    # (name, data) of a program the package can name — the function apply_fn
+    # is and the plain data its closure reads: its executables live in the
+    # program store (core/compile_cache.py). A user's apply_fn has none and
+    # stays on jit
+    program_identity: Optional[tuple] = None
+
+    def _program(self, run: Callable, node: Optional[str]) -> Callable:
+        from mmlspark_tpu.core.compile_cache import stored_jit
+
+        if self.program_identity is None:
+            return jax.jit(run)
+        name, data = self.program_identity
+        return stored_jit(run, name=name, data=(data, node))

@@ -84,8 +84,16 @@ def _span_child(name: str) -> Any:
 # pool per call (~14 µs in sandboxed containers) — far too slow for a
 # per-request hot path. pid + process-start nanos make ids unique across
 # processes; the C-level counter makes them unique (and thread-safe)
-# within one.
-_ID_BASE = f"{os.getpid():08x}{time.time_ns() & 0xFFFFFFFFFFFF:012x}"
+# within one. Set again in a forked child, which must not mint its parent's
+# ids (and being rebound, the base is process state, not a constant of the
+# code: core/compile_cache.py's digest of the package leaves it out).
+def _seed_ids() -> None:
+    global _ID_BASE
+    _ID_BASE = f"{os.getpid():08x}{time.time_ns() & 0xFFFFFFFFFFFF:012x}"
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)
 _ID_SEQ = itertools.count()
 
 

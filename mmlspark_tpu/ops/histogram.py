@@ -45,10 +45,9 @@ rehearsals, the tests); block sizes and the kernels' variants are
 constants or derived from the call (:func:`_use_split`,
 :func:`_hist_vmem_mb`). A call with no mesh is a ONE-DEVICE call: it takes
 the kernel on any TPU host, however many chips the host has; a caller
-whose rows are sharded passes its mesh. Every choice is counted at trace
-time in ``mmlspark_gbdt_hist_lowerings_total{op,lowering}``. Which GROWER
-a fit gets from a lowering is ``models/gbdt/treegrow.choose_grower``'s to
-say, nobody else's.
+whose rows are sharded passes its mesh. Every choice is counted in
+``mmlspark_gbdt_hist_lowerings_total{op,lowering}`` at trace time and on each load of the
+program from the program store; which GROWER a fit gets is ``treegrow.choose_grower``'s to say.
 """
 
 from __future__ import annotations
@@ -61,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mmlspark_tpu import obs
+from mmlspark_tpu.core.compile_cache import count_traced
 
 # The host-kernel pure_callbacks deadlock against XLA:CPU's async
 # dispatch: the callback thread's operand conversion (np.asarray on a
@@ -85,7 +85,7 @@ _M_LOWERINGS = obs.counter(
 
 
 def _count_lowering(op: str, lowering: str) -> None:
-    _M_LOWERINGS.labels(op=op, lowering=lowering).inc()
+    count_traced(_M_LOWERINGS, op=op, lowering=lowering)
 
 
 NUM_BINS = 256
